@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils import jax_compat
-
 
 def pipeline_apply(
     stage_fn: Callable,
@@ -63,7 +61,7 @@ def pipeline_apply(
         my_params = jax.tree.map(lambda a: a[0], params)
         # carries become device-varying (axis_index use) — mark them varying
         # up front so scan/where types agree (same dance as ring attention)
-        varying = lambda a: jax_compat.pcast(a, (axis,), to="varying")  # noqa: E731
+        varying = lambda a: jax.lax.pcast(a, (axis,), to="varying")  # noqa: E731
         buf = varying(jnp.zeros_like(xs[0]))
         out = varying(jnp.zeros_like(xs))
         fwd = [(i, (i + 1) % S) for i in range(S)]
@@ -98,12 +96,11 @@ def pipeline_apply(
         return out
 
     pspec = jax.tree.map(lambda _: P(axis), stage_params)
-    return jax_compat.shard_map(
+    return jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),
-        check_rep=jax_compat.CHECK_REP,
     )(stage_params, x)
 
 

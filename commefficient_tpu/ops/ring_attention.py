@@ -33,15 +33,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils import jax_compat
-
 _NEG = -1e30
 
 
 def _context_mesh(axis: str):
     """The mesh installed via jax.set_mesh, if it shards the ring axis."""
-    m = jax_compat.get_abstract_mesh()
-    if m is not None and axis in m.axis_names and m.shape[axis] > 1:
+    m = jax.sharding.get_abstract_mesh()
+    if axis in m.axis_names and m.shape[axis] > 1:
         return m
     return None
 
@@ -64,7 +62,7 @@ def use_ring_mesh(mesh: Optional[Mesh], axis: str = "seq"):
             f"axis={axis!r} to ring_attention (or set GPT2Config.ring_axis) "
             "and use jax.set_mesh directly"
         )
-    with jax_compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         yield
 
 
@@ -108,7 +106,7 @@ def _ring_local(q, k, v, *, axis: str, ring_size: int):
 
     # the accumulators become device-varying inside the scan (axis_index use),
     # so mark the initial values varying over the ring axis up front
-    varying = lambda x: jax_compat.pcast(x, (axis,), to="varying")
+    varying = lambda x: jax.lax.pcast(x, (axis,), to="varying")
     m0 = varying(jnp.full((B, H, Tl), _NEG, dtype=jnp.float32))
     l0 = varying(jnp.zeros((B, H, Tl), dtype=jnp.float32))
     acc0 = varying(jnp.zeros((B, H, Tl, D), dtype=jnp.float32))
@@ -135,7 +133,6 @@ def ring_attention(q, k, v, causal: bool = True, mesh=None, axis: str = "seq"):
     ring_size = mesh.shape[axis]
     body = functools.partial(_ring_local, axis=axis, ring_size=ring_size)
     spec = P(None, axis, None, None)
-    return jax_compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=jax_compat.CHECK_REP,
     )(q, k, v)

@@ -137,17 +137,17 @@ def _pad_to_slabs(spec: CSVecSpec, v: jnp.ndarray) -> jnp.ndarray:
     return jnp.pad(v, (0, spec.num_slabs * spec.c - spec.d)).reshape(spec.num_slabs, spec.c)
 
 
-def _use_pallas(spec: CSVecSpec) -> bool:
-    """Use the Pallas kernels on TPU-backed platforms for supported layouts.
+def sketch_impl(spec: CSVecSpec) -> tuple[str, str | None]:
+    """Which implementation `sketch_vec` / `query_all` run for this spec on
+    the current backend, and why when it is not the kernels:
+    ("pallas", None) | ("pallas-interpret", None) | ("oracle", reason).
 
-    Gated by `pallas_kernels.probe(c, r)` — a try-once-per-layout smoke
-    compile+run at the caller's real (c, r) (so spec-scale VMEM exhaustion on
-    small-VMEM chips is caught, not just toolchain breakage) whose failure
-    (full traceback cached and logged) downgrades every caller to the
-    pure-JAX oracle: a Mosaic regression can never crash a training run.
-    vmap is safe without a guard here — the kernels are sequential_vmap-
-    wrapped (pallas_call's own batching rule hangs Mosaic compiles on
-    current toolchains), though the engine never needs it: sketching is
+    The Pallas kernels run on a TPU backend for supported layouts
+    (pallas_kernels.unsupported_reason). The first use per (c, r) compiles
+    and runs them once (pallas_kernels.probe); a failure there RAISES — the
+    oracle is only ever taken for a stated reason, never as a silent
+    downgrade. vmap is safe without a guard here — the kernels are
+    sequential_vmap-wrapped — though the engine never needs it: sketching is
     linear, so the round step sketches the client-aggregated update once.
     COMMEFFICIENT_NO_PALLAS=1 forces the pure-JAX oracle (debugging).
     COMMEFFICIENT_PALLAS_INTERPRET=1 routes supported layouts through the
@@ -156,12 +156,27 @@ def _use_pallas(spec: CSVecSpec) -> bool:
     import os
 
     if os.environ.get("COMMEFFICIENT_NO_PALLAS"):
-        return False
+        return "oracle", "COMMEFFICIENT_NO_PALLAS is set"
     from . import pallas_kernels
 
-    if os.environ.get("COMMEFFICIENT_PALLAS_INTERPRET"):
-        return pallas_kernels.supported(spec)
-    return pallas_kernels.eligible(spec)
+    why = pallas_kernels.unsupported_reason(spec)
+    if why is not None:
+        return "oracle", why
+    if _pallas_interpret():
+        return "pallas-interpret", None
+    if pallas_kernels.eligible(spec):
+        return "pallas", None
+    return "oracle", f"backend {jax.default_backend()!r} is not a TPU"
+
+
+def describe_impl(spec: CSVecSpec) -> str:
+    """`sketch_impl` as the CLIs' startup `sketch:` line shows it."""
+    impl, why = sketch_impl(spec)
+    return impl if why is None else f"{impl} ({why})"
+
+
+def _use_pallas(spec: CSVecSpec) -> bool:
+    return sketch_impl(spec)[0] != "oracle"
 
 
 def _pallas_interpret() -> bool:
@@ -218,6 +233,14 @@ def _query_slab_rotation(spec: CSVecSpec, table: jnp.ndarray, slab: jnp.ndarray)
 
     per_row = jax.vmap(row_est)(table, ks, shifts[:, slab])  # [r, c]
     return jnp.sort(per_row, axis=0)[(spec.r - 1) // 2]
+
+
+def _query_all_rotation(spec: CSVecSpec, table: jnp.ndarray) -> jnp.ndarray:
+    """Dense query, rotation family — the pure-JAX oracle of the Pallas query
+    kernel (as `_sketch_vec_rotation` is of the accumulate kernel)."""
+    slabs = jnp.arange(spec.num_slabs, dtype=jnp.int32)
+    ests = jax.lax.map(lambda b: _query_slab_rotation(spec, table, b), slabs)
+    return ests.reshape(-1)[: spec.d]
 
 
 def _accumulate(
@@ -345,9 +368,7 @@ def query_all(spec: CSVecSpec, table: jnp.ndarray) -> jnp.ndarray:
             from . import pallas_kernels
 
             return pallas_kernels.query_all(spec, table, interpret=_pallas_interpret())
-        slabs = jnp.arange(spec.num_slabs, dtype=jnp.int32)
-        ests = jax.lax.map(lambda b: _query_slab_rotation(spec, table, b), slabs)
-        return ests.reshape(-1)[: spec.d]
+        return _query_all_rotation(spec, table)
     if spec.num_blocks == 1:
         return query(spec, table, jnp.arange(spec.d, dtype=jnp.int32))
 

@@ -32,6 +32,7 @@ from commefficient_tpu.parallel import mesh as meshlib
 from commefficient_tpu.resilience import FaultPlan, RetryPolicy
 from commefficient_tpu.runner import RunnerConfig, run_loop
 from commefficient_tpu.serve.service import service_from_args
+from commefficient_tpu.sketch import csvec
 from commefficient_tpu.utils import checkpoint as ckpt
 from commefficient_tpu.utils.config import make_parser, mode_config_from_args, resolve_defaults
 from commefficient_tpu.utils.logging import TableLogger
@@ -80,6 +81,11 @@ def build(args, fault_plan=None, retry_policy=None):
         from commefficient_tpu.parallel.distributed import mesh_info
 
         print(f"mesh: {mesh_info(mesh)}", flush=True)
+    if mode_cfg.mode == "sketch":
+        # resolved here, outside any trace: a kernel that does not compile
+        # on this TPU raises at start-up, and an oracle run says why
+        print(f"sketch: {csvec.describe_impl(mode_cfg.sketch_spec)}",
+              flush=True)
     session = FederatedSession(
         train_loss_fn=make_classification_loss(model, train=True),
         eval_loss_fn=make_classification_loss(model, train=False),
@@ -265,4 +271,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from commefficient_tpu.utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     main(sys.argv[1:])

@@ -32,6 +32,7 @@ from commefficient_tpu.parallel import mesh as meshlib, tp
 from commefficient_tpu.resilience import FaultPlan, RetryPolicy
 from commefficient_tpu.runner import RunnerConfig, run_loop
 from commefficient_tpu.serve.service import service_from_args
+from commefficient_tpu.sketch import csvec
 from commefficient_tpu.utils import checkpoint as ckpt
 from commefficient_tpu.utils.config import make_parser, mode_config_from_args, resolve_defaults
 from commefficient_tpu.utils.logging import TableLogger
@@ -152,6 +153,11 @@ def build(args, fault_plan=None, retry_policy=None):
         train_loss = make_lm_loss(model, train=True, moe_aux_coef=aux)
         eval_loss = make_lm_loss(model, train=False, moe_aux_coef=aux)
     mode_cfg = mode_config_from_args(args, d)
+    if mode_cfg.mode == "sketch":
+        # resolved here, outside any trace: a kernel that does not compile
+        # on this TPU raises at start-up, and an oracle run says why
+        print(f"sketch: {csvec.describe_impl(mode_cfg.sketch_spec)}",
+              flush=True)
     session = FederatedSession(
         train_loss_fn=train_loss,
         eval_loss_fn=eval_loss,
@@ -403,4 +409,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from commefficient_tpu.utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     main(sys.argv[1:])

@@ -10,14 +10,14 @@
 # byte-identical at any job count — baseline matching and the final sort
 # happen in the parent). ruff/mypy are pinned in pyproject's `lint` extra
 # (pip install -e '.[lint]'); when they are not installed (bare
-# containers, including the TPU-window image — neither tool ships there,
+# containers, including the chip machine — neither tool ships there,
 # so their burn-down happens wherever the extra IS installed) they are
 # SKIPPED WITH A NOTICE, not failed — the project-specific contracts
 # (G001–G020) are the part no generic tool covers, so that is the part
 # that must never be skippable by accident.
 #
 # The machine-readable report is archived next to the bench JSONs
-# (GRAFTLINT.json at the repo root) so CI and the TPU-window driver can
+# (GRAFTLINT.json at the repo root) so CI and the driver can
 # diff rule counts across PRs the same way they diff bench numbers.
 set -uo pipefail
 cd "$(dirname "$0")/.."

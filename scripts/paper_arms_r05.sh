@@ -2,7 +2,7 @@
 # Round-5 accuracy-vs-communication frontier AT PAPER SCALE (BASELINE
 # config #2): 10,000 sort-by-label clients, W=100 (~1% participation),
 # 24 epochs = 2,400 rounds, 50k synthetic images (5/client), the exact
-# flag set of tpu_window_r05.sh phase G — which already ran the SKETCH
+# flag set of the round-5 paper-scale sketch run — which already ran the SKETCH
 # arm (results/paper_scale_r05.jsonl, test 0.6545). This script runs the
 # other four arms so the frontier table compares modes at the
 # reference's own cohort scale, where the W=16 study's two failure

@@ -1,8 +1,7 @@
 # Single source of truth for the round-5 tradeoff-study arm
 # hyperparameters. BOTH writers of the shared checkpoints/JSONLs —
-# scripts/tradeoff_r05.sh (TPU phase B) and scripts/cpu_slicer_r05.sh
-# (CPU fallback) — source this file, so an arm's flags can never diverge
-# mid-study between the two (a resumed checkpoint with silently different
+# scripts/tradeoff_r05.sh and any slicing driver — source this file, so an
+# arm's flags can never diverge mid-study between the two (a resumed checkpoint with silently different
 # hyperparameters would corrupt the 600-round curve).
 #
 # Usage: arm_flags <name> -> echoes the extra cv_train flags for that arm.

@@ -89,32 +89,43 @@ def test_even_rows_lower_median():
     )
 
 
-def test_probe_failure_falls_back_to_oracle(monkeypatch):
-    """The library-level gate: when the per-layout probe reports a Mosaic
-    failure on a TPU backend, sketch_vec/query_all silently use the pure-JAX
-    oracle instead of crashing — and the status surfaces the traceback."""
+def test_probe_failure_raises_on_tpu_backend(monkeypatch):
+    """The library-level gate: on a TPU backend with a supported layout, a
+    kernel that fails to compile RAISES with the compiler's message — the
+    process is never downgraded to the pure-JAX oracle. The oracle is taken
+    only for a stated reason (unsupported layout, COMMEFFICIENT_NO_PALLAS)."""
     spec = CSVecSpec(d=3000, c=1024, r=3, seed=13, family="rotation")
     v = _v(7, spec.d)
-    want = csvec._sketch_vec_rotation(spec, v)
+
+    def boom(*a, **k):
+        raise RuntimeError("MosaicError: simulated")
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(
-        pk, "probe", lambda c, r: (False, "MosaicError: simulated\n<traceback>")
-    )
-    assert not csvec._use_pallas(spec)
-    got = csvec.sketch_vec(spec, v)  # must route to the oracle, not raise
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6)
+    monkeypatch.setattr(pk, "_accumulate_call", boom)
+    monkeypatch.setattr(pk, "_PROBED", set())
+    with pytest.raises(RuntimeError, match="MosaicError: simulated"):
+        csvec.sketch_vec(spec, v)
+    assert (spec.c, spec.r) not in pk._PROBED  # a failure is never cached
+    with pytest.raises(RuntimeError, match="c=1024 r=3"):
+        csvec.sketch_impl(spec)
+
+    # the two stated reasons still take the oracle, and say so
+    odd = CSVecSpec(d=3000, c=1000, r=3, seed=13, family="rotation")
+    assert csvec.sketch_impl(odd) == ("oracle", "num_cols 1000 % 1024 != 0")
+    monkeypatch.setenv("COMMEFFICIENT_NO_PALLAS", "1")
+    assert csvec.sketch_impl(spec)[0] == "oracle"
+    np.testing.assert_allclose(
+        np.asarray(csvec.sketch_vec(spec, v)),
+        np.asarray(csvec._sketch_vec_rotation(spec, v)), rtol=1e-6)
 
 
-def test_probe_status_reports_errors():
-    pk._PROBE.clear()
-    assert pk.probe_status() == {"probed": False}
-    pk._PROBE[(1024, 3)] = (True, None)
-    pk._PROBE[(2048, 5)] = (False, "tb")
-    st = pk.probe_status()
-    assert st["probed"] and not st["ok"]
-    assert st["errors"] == {"c=2048,r=5": "tb"}
-    pk._PROBE.clear()
+def test_probe_status_reports_layouts(monkeypatch):
+    monkeypatch.setattr(pk, "_PROBED", set())
+    assert pk.probe_status() == {"probed": False, "layouts": []}
+    pk._PROBED.update({(2048, 5), (1024, 3)})
+    assert pk.probe_status() == {
+        "probed": True, "layouts": ["c=1024,r=3", "c=2048,r=5"]}
+
 
 def test_engine_round_step_with_pallas_kernels(monkeypatch):
     """The EXACT composition that runs on hardware: the full federated round
@@ -123,8 +134,9 @@ def test_engine_round_step_with_pallas_kernels(monkeypatch):
     against the oracle-engine result. COMMEFFICIENT_PALLAS_INTERPRET=1 runs
     the kernels in the Pallas interpreter, so this passes on the CPU mesh —
     it proves the composition traces, jits, and is numerically equal; only
-    the Mosaic/native compile of the same module remains hardware-only
-    (scripts/tpu_round3.sh step 5)."""
+    the Mosaic/native compile of the same module needs the TPU compiler
+    (tests/test_tpu_compile.py compiles it for a described v5e;
+    chip_smoke.py runs it on the chip)."""
     from jax.flatten_util import ravel_pytree
 
     from commefficient_tpu.federated import engine

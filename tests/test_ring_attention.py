@@ -64,7 +64,6 @@ def test_ring_inside_federated_round_matches_dense():
     from commefficient_tpu.models.losses import make_lm_loss
     from commefficient_tpu.modes.config import ModeConfig
     from commefficient_tpu.parallel import mesh as meshlib
-    from commefficient_tpu.utils import jax_compat
 
     T, W, B = 32, 2, 2
     mesh = meshlib.make_mesh(8, seq_parallel=4)
@@ -88,7 +87,7 @@ def test_ring_inside_federated_round_matches_dense():
         step = jax.jit(engine.make_round_step(make_lm_loss(model, train=True), ecfg))
         if use_mesh:
             b = jax.device_put(batch, meshlib.client_sharding(mesh))
-            with jax_compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 new, _, _ = step(state, b, {}, jnp.float32(0.1), jax.random.PRNGKey(2))
         else:
             new, _, _ = step(state, batch, {}, jnp.float32(0.1), jax.random.PRNGKey(2))

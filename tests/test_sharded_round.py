@@ -418,7 +418,7 @@ def test_auto_inflight_policy():
 
     # local backend: sub-ms RTT stays at the floor
     assert auto_inflight(0.1, 50.0) == 2
-    # tunnelled TPU: 70 ms RTT over a 50 ms round wants a deep chain
+    # slow host link: 70 ms RTT over a 50 ms round wants a deep chain
     assert auto_inflight(70.0, 50.0) == 14
     # clamped at the preemption-grace ceiling
     assert auto_inflight(500.0, 1.0) == 16
