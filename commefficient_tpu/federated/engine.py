@@ -32,6 +32,25 @@ from jax.flatten_util import ravel_pytree
 from ..modes import modes
 from ..modes.config import ModeConfig
 
+# The phases of the compiled round, as `jax.named_scope` names them in every
+# round-step factory below, in modes.server_step_sparse / apply_delta and in
+# csvec.unsketch_topk: metadata on the compiled operations (no arithmetic
+# and no instruction name changes), which is how a profiler capture says
+# where a round's device time goes after a refactor has renamed every
+# fusion. Scopes nest (the query and the top-k inside the server algebra,
+# the ravel of a client's gradient inside client_grad); the innermost names
+# the operation (obs/profiler.py phase_of). ONE tuple, shared by the
+# program, the capture's summary and the tests.
+ROUND_PHASES = (
+    "client_grad",     # per-client forward and backward (the vmap)
+    "cohort_reduce",   # ravel, screen/clip, weighted sum over clients, finalize
+    "compress",        # sketch accumulate (or top-k / nothing, by mode), merge
+    "server_algebra",  # momentum, error feedback, the masking tail
+    "server_query",    # the estimate of all d coordinates
+    "server_topk",     # exact or approximate top-k
+    "apply",           # sparse or dense apply, unravel
+)
+
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -1031,6 +1050,7 @@ def _advance_quarantine_full(cfg: EngineConfig, qstate: dict, norms, lnorms,
     return new_q
 
 
+@jax.named_scope("cohort_reduce")
 def _merge_net_state(nstates, net_state, part) -> Any:
     """Mutable model collections (BN stats): average the SURVIVING clients'
     results; with no survivors, keep the previous stats. mask_rows keeps a
@@ -1044,6 +1064,7 @@ def _merge_net_state(nstates, net_state, part) -> Any:
     )
 
 
+@jax.named_scope("cohort_reduce")
 def _survivor_metrics(metrics, part) -> dict:
     """Metric sums over the surviving cohort + the participants count that
     run_round uses to scale the measured uplink (NaN-safe: a masked client's
@@ -1084,10 +1105,8 @@ def _weighted_client_reduce(
     data."""
     nan_safe = nan_safe or cfg.client_update_clip > 0
 
-    def chunk(cb, crngs, cpart):
-        updates, nstates, metrics = jax.vmap(
-            lambda b, r: grad_client(params, pflat, net_state, b, r)
-        )(cb, crngs)
+    @jax.named_scope("cohort_reduce")
+    def reduce(updates, nstates, metrics, cpart):
         norms_c = lnorms_c = None
         if cfg.client_update_clip > 0:
             norms_c = _client_norms(updates)
@@ -1111,6 +1130,13 @@ def _weighted_client_reduce(
                 lambda m: jnp.sum(m * modes.bcast(cpart, m), axis=0), metrics)
         return wsum, ns_sum, m_sum, cpart, norms_c, lnorms_c
 
+    def chunk(cb, crngs, cpart):
+        with jax.named_scope("client_grad"):
+            updates, nstates, metrics = jax.vmap(
+                lambda b, r: grad_client(params, pflat, net_state, b, r)
+            )(cb, crngs)
+        return reduce(updates, nstates, metrics, cpart)
+
     W = part.shape[0]
     C = cfg.client_chunk
     if not C or C >= W:
@@ -1128,7 +1154,8 @@ def _weighted_client_reduce(
 
     def body(carry, x):
         wsum, ns_sum, m_sum, cpart_eff, norms_c, lnorms_c = chunk(*x)
-        carry = jax.tree.map(jnp.add, carry, (wsum, ns_sum, m_sum))
+        with jax.named_scope("cohort_reduce"):
+            carry = jax.tree.map(jnp.add, carry, (wsum, ns_sum, m_sum))
         return carry, (cpart_eff, norms_c, lnorms_c)
 
     acc, (pe, norms, lnorms) = jax.lax.scan(body, init, xs)
@@ -1187,10 +1214,8 @@ def _weighted_client_reduce_tree(
     del segments  # the pytree carries its own leaf boundaries
     nan_safe = nan_safe or cfg.client_update_clip > 0
 
-    def chunk(cb, crngs, cpart):
-        updates, nstates, metrics = jax.vmap(
-            lambda b, r: grad_client_tree(params, net_state, b, r)
-        )(cb, crngs)
+    @jax.named_scope("cohort_reduce")
+    def reduce(updates, nstates, metrics, cpart):
         norms_c = lnorms_c = None
         if cfg.client_update_clip > 0:
             norms_c = _client_norms_tree(updates)
@@ -1216,6 +1241,13 @@ def _weighted_client_reduce_tree(
                 lambda m: jnp.sum(m * modes.bcast(cpart, m), axis=0), metrics)
         return wsum, ns_sum, m_sum, cpart, norms_c, lnorms_c
 
+    def chunk(cb, crngs, cpart):
+        with jax.named_scope("client_grad"):
+            updates, nstates, metrics = jax.vmap(
+                lambda b, r: grad_client_tree(params, net_state, b, r)
+            )(cb, crngs)
+        return reduce(updates, nstates, metrics, cpart)
+
     W = part.shape[0]
     C = cfg.client_chunk
     if not C or C >= W:
@@ -1233,7 +1265,8 @@ def _weighted_client_reduce_tree(
 
     def body(carry, x):
         wsum, ns_sum, m_sum, cpart_eff, norms_c, lnorms_c = chunk(*x)
-        carry = jax.tree.map(jnp.add, carry, (wsum, ns_sum, m_sum))
+        with jax.named_scope("cohort_reduce"):
+            carry = jax.tree.map(jnp.add, carry, (wsum, ns_sum, m_sum))
         return carry, (cpart_eff, norms_c, lnorms_c)
 
     acc, (pe, norms, lnorms) = jax.lax.scan(body, init, xs)
@@ -1245,6 +1278,7 @@ def _weighted_client_reduce_tree(
     return acc + (part_eff, norms, lnorms)
 
 
+@jax.named_scope("cohort_reduce")
 def _finalize_client_reduce(mcfg: ModeConfig, wsum, ns_sum, m_sum, net_state, part):
     """Normalize the weighted SUMS from `_weighted_client_reduce`: the reduced
     update (survivor mean unless agg_op=sum), the survivor-mean mutable
@@ -1262,6 +1296,7 @@ def _finalize_client_reduce(mcfg: ModeConfig, wsum, ns_sum, m_sum, net_state, pa
     return weighted, new_net_state, out_metrics
 
 
+@jax.named_scope("compress")
 def _compress_reduced(mcfg: ModeConfig, weighted) -> dict:
     """Compress the reduced update once and lift it to the aggregate wire —
     the linearity shortcut's server-side entry point."""
@@ -1290,8 +1325,11 @@ def _make_grad_client(loss_fn: Callable, cfg: EngineConfig) -> Callable:
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, net_state, cbatch, rng
         )
-        gflat, _ = ravel_pytree(grads)
-        gflat = gflat + cfg.weight_decay * pflat
+        # the ravel is where the [W, d] stack of per-client gradients is
+        # written: the cohort reduce's cost, not the backward pass's
+        with jax.named_scope("cohort_reduce"):
+            gflat, _ = ravel_pytree(grads)
+            gflat = gflat + cfg.weight_decay * pflat
         return gflat, aux["net_state"], aux["metrics"]
 
     return grad_client
@@ -1315,6 +1353,7 @@ def _make_grad_client_tree(loss_fn: Callable, cfg: EngineConfig) -> Callable:
     return grad_client
 
 
+@jax.named_scope("cohort_reduce")
 def _layerwise_normalize(mcfg: ModeConfig, wsum_tree, n_live):
     """Survivor normalization of the per-leaf weighted sums — the tree
     mirror of `_finalize_client_reduce`'s `wsum / n_live` (elementwise, so
@@ -1324,6 +1363,7 @@ def _layerwise_normalize(mcfg: ModeConfig, wsum_tree, n_live):
     return jax.tree.map(lambda l: l / n_live, wsum_tree)
 
 
+@jax.named_scope("compress")
 def _layerwise_compress(mcfg: ModeConfig, tree, plan) -> dict:
     """Fold a (normalized or partial) update pytree into the sketch wire —
     the layerwise counterpart of `_compress_reduced`/`client_compress` for
@@ -1340,10 +1380,17 @@ def _layerwise_plan(mcfg: ModeConfig, params):
     return sketch_layerwise.make_block_plan(mcfg.sketch_spec, params)
 
 
+@jax.named_scope("apply")
 def _layerwise_apply(params, delta: dict, plan):
     from ..sketch import layerwise as sketch_layerwise
 
     return sketch_layerwise.apply_delta_tree(params, delta, plan)
+
+
+@jax.named_scope("apply")
+def _flat_apply(pflat, unravel, delta: dict):
+    """The ravel path's apply: params - delta on the flat view, unraveled."""
+    return unravel(modes.apply_delta(pflat, delta))
 
 
 def make_round_step(
@@ -1465,50 +1512,54 @@ def make_round_step(
                 agg = _compress_reduced(mcfg, weighted)
             new_rows = client_rows
         else:
-            if mcfg.uses_weight_delta:
-                updates, nstates, metrics = jax.vmap(
-                    lambda cb, r: local_sgd_client(params, pflat, net_state, cb, r, lr)
-                )(batch, client_rngs)
-            else:
-                updates, nstates, metrics = jax.vmap(
-                    lambda cb, r: grad_client(params, pflat, net_state, cb, r)
-                )(batch, client_rngs)
+            with jax.named_scope("client_grad"):
+                if mcfg.uses_weight_delta:
+                    updates, nstates, metrics = jax.vmap(
+                        lambda cb, r: local_sgd_client(params, pflat, net_state, cb, r, lr)
+                    )(batch, client_rngs)
+                else:
+                    updates, nstates, metrics = jax.vmap(
+                        lambda cb, r: grad_client(params, pflat, net_state, cb, r)
+                    )(batch, client_rngs)
             part_eff = part
-            if cfg.client_update_clip > 0:
-                norms = _client_norms(updates)
-                bad = _quarantine_mask(cfg, norms, qmed)
-                if layer_q:
-                    lnorms = _client_layer_norms(updates, segments)
-                    bad = bad | _quarantine_layer_mask(cfg, lnorms, lmed)
-                part_eff = part * (1.0 - bad.astype(part.dtype))
-                # hard-zero the rejected updates so downstream per-client
-                # transforms (top-k, local error rows) never see the poison
-                updates = jnp.where(bad[:, None], jnp.zeros_like(updates),
-                                    updates)
-            updates = _clip_updates(cfg, updates)
-            n_live = jnp.maximum(part_eff.sum(), 1.0)
+            with jax.named_scope("cohort_reduce"):
+                if cfg.client_update_clip > 0:
+                    norms = _client_norms(updates)
+                    bad = _quarantine_mask(cfg, norms, qmed)
+                    if layer_q:
+                        lnorms = _client_layer_norms(updates, segments)
+                        bad = bad | _quarantine_layer_mask(cfg, lnorms, lmed)
+                    part_eff = part * (1.0 - bad.astype(part.dtype))
+                    # hard-zero the rejected updates so downstream per-client
+                    # transforms (top-k, local error rows) never see the poison
+                    updates = jnp.where(bad[:, None], jnp.zeros_like(updates),
+                                        updates)
+                updates = _clip_updates(cfg, updates)
+                n_live = jnp.maximum(part_eff.sum(), 1.0)
 
             if modes.is_linear(mcfg) and not mcfg.needs_local_state:
                 # weight-delta modes (fedavg/localSGD) on the shortcut: the
                 # local-iteration scan already holds per-client state, so no
                 # chunked reduce — just the survivor-weighted mean of deltas
-                weighted = modes.mask_rows(part_eff, updates).sum(axis=0)
-                if mcfg.agg_op != "sum":
-                    weighted = weighted / n_live
+                with jax.named_scope("cohort_reduce"):
+                    weighted = modes.mask_rows(part_eff, updates).sum(axis=0)
+                    if mcfg.agg_op != "sum":
+                        weighted = weighted / n_live
                 agg = _compress_reduced(mcfg, weighted)
                 new_rows = client_rows
             else:
-                wires, vrows = jax.vmap(lambda u, row: modes.client_compress(mcfg, u, row))(
-                    updates, client_rows
-                )
-                agg = modes.aggregate(mcfg, wires, weights=part_eff)
-                # dropped/quarantined clients never transmitted (usably):
-                # their persistent local state (error/momentum rows) stays
-                # exactly as it was
-                new_rows = jax.tree.map(
-                    lambda new, old: jnp.where(modes.bcast(part_eff, new) > 0, new, old),
-                    vrows, client_rows,
-                )
+                with jax.named_scope("compress"):
+                    wires, vrows = jax.vmap(lambda u, row: modes.client_compress(mcfg, u, row))(
+                        updates, client_rows
+                    )
+                    agg = modes.aggregate(mcfg, wires, weights=part_eff)
+                    # dropped/quarantined clients never transmitted (usably):
+                    # their persistent local state (error/momentum rows) stays
+                    # exactly as it was
+                    new_rows = jax.tree.map(
+                        lambda new, old: jnp.where(modes.bcast(part_eff, new) > 0, new, old),
+                        vrows, client_rows,
+                    )
             new_net_state = _merge_net_state(nstates, net_state, part_eff)
             out_metrics = _survivor_metrics(metrics, part_eff)
 
@@ -1539,7 +1590,7 @@ def make_round_step(
         delta, mode_state = modes.server_step_sparse(
             mcfg, agg, state["mode_state"], server_lr)
         new_params = (_layerwise_apply(params, delta, plan) if layerwise
-                      else unravel(modes.apply_delta(pflat, delta)))
+                      else _flat_apply(pflat, unravel, delta))
         new_state = {
             "params": new_params,
             "net_state": new_net_state,
@@ -1612,6 +1663,7 @@ def _cohort_streams(cfg: EngineConfig, rng, num_sampled: int):
     return client_rngs, part, noise_rng
 
 
+@jax.named_scope("cohort_reduce")
 def _merged_survivor_finalize(ns_sum, m_sum, part, net_state):
     """Survivor-mean mutable collections + metrics/participants from MERGED
     cross-shard sums — the sharded round's counterpart of
@@ -1627,6 +1679,7 @@ def _merged_survivor_finalize(ns_sum, m_sum, part, net_state):
     return new_net_state, out_metrics
 
 
+@jax.named_scope("compress")
 def _normalize_merged_wire(mcfg: ModeConfig, wire_sum: dict, n_live) -> dict:
     """Survivor normalization IN WIRE SPACE (compression is homogeneous only
     up to fp order, so every sharded path normalizes after the merge — one
@@ -1652,9 +1705,11 @@ def _merged_sharded_tail(
     pre-quarantine mask, for the quarantined count)."""
     mcfg = cfg.mode
     layerwise = cfg.sketch_path == "layerwise"
-    wire_sum = modes.merge_partial_wires(mcfg, stacked_wire)
-    ns_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_ns)
-    m_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_m)
+    with jax.named_scope("compress"):
+        wire_sum = modes.merge_partial_wires(mcfg, stacked_wire)
+    with jax.named_scope("cohort_reduce"):
+        ns_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_ns)
+        m_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_m)
     if not layerwise:
         pflat, unravel = _ravel_params(state["params"])
     agg = _normalize_merged_wire(mcfg, wire_sum,
@@ -1678,7 +1733,7 @@ def _merged_sharded_tail(
     new_params = (
         _layerwise_apply(state["params"], delta,
                          _layerwise_plan(mcfg, state["params"]))
-        if layerwise else unravel(modes.apply_delta(pflat, delta)))
+        if layerwise else _flat_apply(pflat, unravel, delta))
     new_state = {
         "params": new_params,
         "net_state": new_net_state,
@@ -1820,7 +1875,8 @@ def make_sharded_round_step(
                 part_l, qmed=qmed, nan_safe=valid_l is not None,
                 lmed=lmed, segments=segments,
             )
-            wire, _ = modes.client_compress(mcfg, wsum, {})
+            with jax.named_scope("compress"):
+                wire, _ = modes.client_compress(mcfg, wsum, {})
         if layer_q:
             return wire, ns_sum, m_sum, part_eff_l, part_l, norms_l, lnorms_l
         if quarantine:
@@ -2080,8 +2136,9 @@ def make_sharded_split_round_step(
         wpart, stacked_ns, stacked_m, pe_s = outs[:4]
         noise_rng, parts_ok = outs[-2], outs[-1]
         part_eff = pe_s.reshape(-1)
-        ns_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_ns)
-        m_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_m)
+        with jax.named_scope("cohort_reduce"):
+            ns_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_ns)
+            m_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_m)
         new_net_state, out_metrics = _merged_survivor_finalize(
             ns_sum, m_sum, part_eff, state["net_state"])
         if quarantine:
@@ -2095,6 +2152,7 @@ def make_sharded_split_round_step(
             out_metrics = _skip_metrics(ok, out_metrics)
         return wpart, new_net_state, out_metrics, noise_rng
 
+    @jax.named_scope("compress")
     def server_body(wpart_l):
         wire_l, _ = modes.client_compress(mcfg, wpart_l[0], {})
         stacked_wire = jax.tree.map(
@@ -2120,7 +2178,8 @@ def make_sharded_split_round_step(
         else:
             stacked_wire, parts_ok = server_mapped(wpart)
             pflat, unravel = _ravel_params(state["params"])
-        wire_sum = modes.merge_partial_wires(mcfg, stacked_wire)
+        with jax.named_scope("compress"):
+            wire_sum = modes.merge_partial_wires(mcfg, stacked_wire)
         agg = _normalize_merged_wire(
             mcfg, wire_sum, jnp.maximum(participants, 1.0))
         if cfg.on_nonfinite == "skip":
@@ -2141,7 +2200,7 @@ def make_sharded_split_round_step(
         new_params = (
             _layerwise_apply(state["params"], delta,
                              _layerwise_plan(mcfg, state["params"]))
-            if layerwise else unravel(modes.apply_delta(pflat, delta)))
+            if layerwise else _flat_apply(pflat, unravel, delta))
         new_state = {
             "params": new_params,
             "net_state": new_net_state,
@@ -2281,7 +2340,7 @@ def make_split_round_step(
         new_params = (
             _layerwise_apply(state["params"], delta,
                              _layerwise_plan(mcfg, state["params"]))
-            if layerwise else unravel(modes.apply_delta(pflat, delta)))
+            if layerwise else _flat_apply(pflat, unravel, delta))
         new_state = {
             "params": new_params,
             "net_state": new_net_state,
@@ -2579,16 +2638,19 @@ def make_payload_round_steps(
         client_compress — the exact table a real client would transmit).
         Layer scope appends the [*, L] per-leaf update norms (pre-clip,
         like the scalar screen's norms) for the merge's per-leaf rings."""
-        updates, nstates, metrics = jax.vmap(
-            lambda b, r: grad_client(params, pflat, net_state, b, r)
-        )(cb, crngs)
+        with jax.named_scope("client_grad"):
+            updates, nstates, metrics = jax.vmap(
+                lambda b, r: grad_client(params, pflat, net_state, b, r)
+            )(cb, crngs)
         lnorms = None
-        if layer_q:
-            lnorms = _client_layer_norms(updates, _leaf_segments(params))
-        updates = _clip_updates(cfg, updates)
-        tables = jax.vmap(
-            lambda u: modes.client_compress(mcfg, u, {})[0]["table"]
-        )(updates)
+        with jax.named_scope("cohort_reduce"):
+            if layer_q:
+                lnorms = _client_layer_norms(updates, _leaf_segments(params))
+            updates = _clip_updates(cfg, updates)
+        with jax.named_scope("compress"):
+            tables = jax.vmap(
+                lambda u: modes.client_compress(mcfg, u, {})[0]["table"]
+            )(updates)
         if layer_q:
             return tables, nstates, metrics, lnorms
         return tables, nstates, metrics
@@ -2766,8 +2828,10 @@ def make_payload_round_steps(
                 wire_sum = {"table": modes.edge_grouped_sum(
                     tables, part_eff, edge_assign, n_edges)}
             else:
-                masked = modes.mask_rows(part_eff, tables)
-                wire_sum = modes.merge_partial_wires(mcfg, {"table": masked})
+                with jax.named_scope("compress"):
+                    masked = modes.mask_rows(part_eff, tables)
+                    wire_sum = modes.merge_partial_wires(
+                        mcfg, {"table": masked})
             total_w = part_eff.sum()
             if stale_slots:
                 # buffered-async: the late tables' ordered weighted fold
@@ -2854,7 +2918,7 @@ def make_payload_round_steps(
             mcfg, agg, mode_state_in, lr)
         pflat, unravel = _ravel_params(state["params"])
         new_state = {
-            "params": unravel(modes.apply_delta(pflat, delta)),
+            "params": _flat_apply(pflat, unravel, delta),
             "net_state": new_net_state,
             "mode_state": mode_state,
             "round": state["round"] + 1,

@@ -500,6 +500,7 @@ def merge_edge_partials(partials: jnp.ndarray) -> jnp.ndarray:
 # ------------------------------------------------------------- server side
 
 
+@jax.named_scope("server_algebra")
 def server_step_sparse(
     cfg: ModeConfig, agg: dict, sstate: dict, lr: jnp.ndarray
 ) -> tuple[dict, dict]:
@@ -568,7 +569,8 @@ def server_step_sparse(
         V = rho * sstate["Vvelocity"] + g
         use_error = cfg.error_type != "none"
         E = sstate["Verror"] + lr * V if use_error else lr * V
-        idx, vals = topk_dense(E, cfg.k, cfg.topk_impl, cfg.topk_recall)
+        with jax.named_scope("server_topk"):
+            idx, vals = topk_dense(E, cfg.k, cfg.topk_impl, cfg.topk_recall)
         # mask from the selected indices, not delta's values: a transmitted
         # coordinate whose value happens to be 0 must still be masked.
         E = E.at[idx].add(-vals) if use_error else sstate["Verror"]
@@ -585,7 +587,9 @@ def server_step_sparse(
         V = rho * sstate["Vvelocity"] + g
         if cfg.error_type == "virtual":
             E = sstate["Verror"] + lr * V
-            idx, vals = topk_dense(E, cfg.k, cfg.topk_impl, cfg.topk_recall)
+            with jax.named_scope("server_topk"):
+                idx, vals = topk_dense(E, cfg.k, cfg.topk_impl,
+                                       cfg.topk_recall)
             return {"idx": idx, "vals": vals}, {
                 "Vvelocity": V.at[idx].set(0.0),
                 "Verror": E.at[idx].add(-vals),
@@ -603,6 +607,7 @@ def server_step_sparse(
     return {"dense": lr * V}, {"Vvelocity": V, "Verror": sstate["Verror"]}
 
 
+@jax.named_scope("apply")
 def apply_delta(pflat: jnp.ndarray, delta: dict) -> jnp.ndarray:
     """params - delta for a wire-form delta (see server_step_sparse).
     Honors idx = -1 padding (zero contribution) like every other sparse

@@ -6,16 +6,19 @@ Three pieces, one contract (host-only, sync-free, bit-transparent):
   (runner, device, writer, serve-ingest, assembler, federated,
   resilience), exported as Chrome-trace/Perfetto JSON (``--trace PATH``)
   and/or a line-buffered JSONL event stream (``--trace_events PATH``).
-  Device-phase durations are DEFERRED: recorded as host timestamps at
-  dispatch, resolved into spans at the runner's existing drain boundary —
-  tracing never adds a host sync to the round path, and a traced run is
-  pinned bit-identical to an untraced one.
+  Device-phase spans are DEFERRED: a dispatch records a host timestamp,
+  and the runner's existing drain writes the span from its ready stamps
+  (one per pending dispatch, taken as its metrics come back) — tracing
+  never adds a host sync to the round path, and a traced run is pinned
+  bit-identical to an untraced one. Inside a profiler capture the spans
+  are also `jax.profiler.TraceAnnotation`s, on the profiler's clock.
 - ``obs.registry`` — process-wide counter/gauge/histogram/meter registry;
   the single source of truth RunStats, serve's /metrics snapshot, and
   bench's resilience/serve/obs blocks read from.
 - ``obs.profiler`` — a ``jax.profiler`` capture window around whole rounds
   (``--profile_rounds START:END``), degrading to a loud no-op where the
-  profiler is unavailable.
+  profiler is unavailable; after the capture, its summary of device time
+  by the round program's named phases (gauges and one stderr line).
 
 The contract is machine-enforced: graftlint G009 bans obs API calls inside
 compiled scope (jit/shard_map bodies in the parity modules) — a span or a

@@ -4,12 +4,19 @@ Host-side spans (`span`, a context manager), point events (`instant`), and
 DEFERRED spans (`complete`, emitted after the fact with an explicit start
 timestamp) on named tracks — runner, device, writer, serve-ingest,
 assembler, federated, resilience. The runner uses `complete` for the
-device phase: a dispatch records only a host timestamp, and the span is
-emitted at the runner's existing `drain()` boundary when the in-flight
-rounds commit — tracing NEVER adds a host synchronization to the round
-path (graftlint G001 stays clean) and never touches RNG or device state,
-which is why a traced run is pinned bit-identical to an untraced one
-(tests/test_obs.py).
+device phase: a dispatch records only a host timestamp, and its span is
+emitted at the runner's existing `drain()` boundary, from the later of
+that timestamp and the previous dispatch's ready stamp to its own ready
+stamp (the moment the drain had read its metrics back) — tracing NEVER
+adds a host synchronization to the round path (graftlint G001 stays
+clean) and never touches RNG or device state, which is why a traced run
+is pinned bit-identical to an untraced one (tests/test_obs.py).
+
+While a `ProfileWindow` capture runs (`profiling(True)`), `span` also
+enters a `jax.profiler.TraceAnnotation("<track>/<name>", **args)`, armed
+or not, so the loop's phases land on the `/host:CPU` plane of the
+profiler's own trace, on the clock of the device's operations. `instant`
+and `complete` are not mirrored.
 
 Disabled (the default) the tracer is a near-zero-cost no-op: one attribute
 check per call site. `configure(trace_path=..., jsonl_path=...)` arms it —
@@ -60,7 +67,9 @@ class Tracer:
         self._jsonl = None
         self.max_events = max_events
         self.dropped_events = 0
-        self.enabled = False
+        self.enabled = False  # the buffer is armed (--trace / --trace_events)
+        self._profiling = False  # a ProfileWindow capture is running
+        self._live = False  # enabled or _profiling: the one flag span() reads
 
     # -- lifecycle -------------------------------------------------------------
 
@@ -85,6 +94,15 @@ class Tracer:
             if jsonl_path:
                 self._jsonl = open(jsonl_path, "a", buffering=1)
             self.enabled = bool(trace_path or jsonl_path)
+            self._live = self.enabled or self._profiling
+
+    def profiling(self, on: bool) -> None:
+        """A profiler capture started (or stopped): while one runs, spans
+        are mirrored into it as TraceAnnotations. Called by ProfileWindow
+        around start_trace/stop_trace — never from the dispatch path."""
+        with self._lock:
+            self._profiling = bool(on)
+            self._live = self.enabled or self._profiling
 
     def flush(self) -> str | None:
         """Write the buffered events as one Chrome-trace JSON file (the
@@ -123,6 +141,10 @@ class Tracer:
     def now_us(self) -> float:
         """Microseconds since configure() — the trace timebase."""
         return (time.perf_counter_ns() - self._t0_ns) / 1e3
+
+    def us_at(self, t_s: float) -> float:
+        """A `time.perf_counter()` reading on the trace timebase."""
+        return t_s * 1e6 - self._t0_ns / 1e3
 
     # -- emission --------------------------------------------------------------
 
@@ -170,22 +192,33 @@ class Tracer:
 
     @contextlib.contextmanager
     def span(self, track: str, name: str, **args):
-        """Host-side duration span. No-op (still yields) when disarmed."""
-        if not self.enabled:
+        """Host-side duration span. No-op (still yields) when disarmed and
+        no profiler capture is running."""
+        if not self._live:
             yield
             return
-        t0 = self.now_us()
-        try:
-            yield
-        finally:
-            now = self.now_us()
-            self._emit("X", track, name, t0, now - t0, args)
+        if self._profiling:
+            import jax
+
+            mirror = jax.profiler.TraceAnnotation(f"{track}/{name}", **args)
+        else:
+            mirror = contextlib.nullcontext()
+        with mirror:
+            if not self.enabled:
+                yield
+                return
+            t0 = self.now_us()
+            try:
+                yield
+            finally:
+                now = self.now_us()
+                self._emit("X", track, name, t0, now - t0, args)
 
     def complete(self, track: str, name: str, ts_us: float, dur_us: float,
                  **args) -> None:
         """Deferred span: emitted now, covering [ts_us, ts_us + dur_us] —
-        how device-phase durations resolve at the drain boundary without a
-        mid-round host sync."""
+        how the runner's device-phase spans are written at the drain, from
+        its ready stamps, without a mid-round host sync."""
         if not self.enabled:
             return
         self._emit("X", track, name, ts_us, max(dur_us, 0.0), args)

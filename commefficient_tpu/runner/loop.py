@@ -8,8 +8,9 @@ Overlap model (async, the default):
     main thread:      dispatch N, N+1, ...      (no per-dispatch host sync)
     device:           compute N, N+1, ...       (queued back-to-back)
     writer thread:    periodic checkpoint save  (staging + rename commit)
-    main thread @ boundary: ONE batched device_get of every pending round's
-        metrics -> commit in dispatch order -> eval / log / checkpoint
+    main thread @ boundary: device_get of each pending dispatch's metrics in
+        dispatch order, a ready stamp after each -> commit in dispatch
+        order -> eval / log / checkpoint
 
 What stays synchronous, deliberately:
 
@@ -50,6 +51,7 @@ import time
 import jax
 
 from ..federated.api import FederatedSession, FedOptimizer, plan_block
+from ..federated.engine import ROUND_PHASES
 from ..obs import registry as obreg
 from ..obs import trace as obtrace
 from ..obs.profiler import ProfileWindow
@@ -299,7 +301,8 @@ def run_loop(
     sketch_path = getattr(session.cfg, "sketch_path", "ravel")
     phase_hist = {ph: reg.histogram(f"runner_phase_{ph}_ms")
                   for ph in obreg.RUNNER_PHASES}
-    profile = ProfileWindow.parse(cfg.profile_rounds, cfg.profile_dir)
+    profile = ProfileWindow.parse(cfg.profile_rounds, cfg.profile_dir,
+                                  phases=ROUND_PHASES)
     if profile is not None and profile.start >= cfg.total_rounds:
         # same contract as FaultPlan.validate_rounds: a window the run can
         # never reach must be loud at launch, not a silently-missing
@@ -399,6 +402,27 @@ def run_loop(
     idle_gauge = reg.gauge("server_idle_ms")
     idle_mark: list = [None]  # [perf_counter at drain end] | [None]
     idle_acc = [0.0, 0, 0.0]  # sum_ms, n, max_ms
+    # the round's own clock (always on, like the phase histograms): the
+    # drain stamps each pending dispatch as its metrics come back. A
+    # dispatch queued behind its predecessor gives the device's own time
+    # for a round (chained); the first of a drain adds whatever the device
+    # waited across the drain (first); and the part of that wait in which
+    # the host had not yet handed the device its next round is bubble_host.
+    # No stamp is carried across run_loop calls.
+    chained_hist = reg.histogram("runner_round_interval_chained_ms")
+    first_hist = reg.histogram("runner_round_interval_first_ms")
+    bubble_host_hist = reg.histogram("runner_bubble_host_ms")
+    ready_mark: list = [None]  # [perf_counter of the last ready stamp]
+    host_mark: list = [None]  # the same, until the next dispatch returns
+
+    def note_dispatched():
+        """Called at each dispatch site once the dispatch has returned and
+        its `dispatch` phase is observed: closes the host's part of the wait
+        the last drain opened (first dispatch after a drain only)."""
+        if host_mark[0] is not None:
+            bubble_host_hist.observe(
+                (time.perf_counter() - host_mark[0]) * 1e3)
+            host_mark[0] = None
 
     def note_idle():
         """Called at each dispatch site BEFORE the dispatch: resolves the
@@ -413,9 +437,10 @@ def run_loop(
         idle_acc[0] += ms
         idle_acc[1] += 1
         idle_acc[2] = max(idle_acc[2], ms)
-    # per-dispatch (trace timestamp, first round, round count): the
-    # deferred device-phase spans — resolved at the drain that commits
-    # them, never by a mid-round sync (the deferred-metrics discipline)
+    # per-dispatch (trace timestamp, first round, round count), parallel to
+    # `pending`: the deferred device-phase spans — written at the drain
+    # that commits them, from its ready stamps, never by a mid-round sync
+    # (the deferred-metrics discipline)
     dispatch_marks: collections.deque = collections.deque()
     totals: collections.defaultdict = collections.defaultdict(float)
     last_m: dict | None = None
@@ -425,17 +450,19 @@ def run_loop(
     last_drain_t = time.perf_counter()
     first_drain = True
 
-    # graftlint: drain-point — THE drain point: one batched device_get for
-    # every pending round's metrics
+    # graftlint: drain-point — THE drain point: device_get of every pending
+    # dispatch's metrics, one dispatch at a time
     def drain(watch: bool = True):
-        """Commit every pending dispatch: ONE batched device_get for all
-        their metrics, then in-order publication + metric folding. In auto
-        mode the wall time between drains (boundary work included — an
-        overestimate only ever tunes the depth DOWN toward the safe floor)
-        feeds the next in-flight depth; the FIRST interval is discarded —
-        it carries the round step's jit compile (tens of seconds), which
-        would seed the EMA ~1000x high and pin the depth at the floor for
-        many drains."""
+        """Commit every pending dispatch: read each one's metrics back in
+        dispatch order, stamping each as it arrives (the host is parked
+        here for the device time of those rounds, so the last read returns
+        when one batched read would), then in-order publication + metric
+        folding. In auto mode the wall time between drains (boundary work
+        included — an overestimate only ever tunes the depth DOWN toward
+        the safe floor) feeds the next in-flight depth; the FIRST interval
+        is discarded — it carries the round step's jit compile (tens of
+        seconds), which would seed the EMA ~1000x high and pin the depth at
+        the floor for many drains."""
         nonlocal pending_rounds, last_m, nonfinite_total
         nonlocal eff_inflight, ema_round_ms, last_drain_t, first_drain
         if not pending:
@@ -446,24 +473,37 @@ def run_loop(
         # watchdog threshold scales by the round count and the recorded
         # time is normalized back to a per-round figure (true median)
         t_drain0 = time.perf_counter()
+        hosts, stamps = [], []
         with (watchdog.round(first, rounds=pending_rounds)
               if watch else contextlib.nullcontext()):
             with tracer.span("runner", "drain", round_first=first,
                              rounds=committed):
-                hosts = jax.device_get([fl.metrics for fl in pending])
-        phase_hist["drain"].observe((time.perf_counter() - t_drain0) * 1e3)
-        # deferred device-phase spans: each dispatch recorded only a host
-        # timestamp; the span closes HERE, where its rounds are known done.
-        # sketch_path names the compiled round variant (ravel | layerwise)
-        # so a trace shows which accumulation program the device time
-        # belongs to when A/B-ing the two arms.
-        end_us = tracer.now_us()
-        while dispatch_marks:
-            ts_us, d_first, d_n = dispatch_marks.popleft()
-            tracer.complete(
-                "device", f"rounds {d_first}..{d_first + d_n - 1}",
-                ts_us, end_us - ts_us, round_first=d_first, rounds=d_n,
-                sketch_path=sketch_path)
+                for fl in pending:
+                    hosts.append(jax.device_get(fl.metrics))
+                    stamps.append(time.perf_counter())
+        phase_hist["drain"].observe((stamps[-1] - t_drain0) * 1e3)
+        # each dispatch recorded only a host timestamp; its device-phase
+        # span runs from the later of that and the previous ready stamp (it
+        # was queued behind that dispatch) to its own. sketch_path names
+        # the compiled round variant (ravel | layerwise) so a trace shows
+        # which accumulation program the device time belongs to when
+        # A/B-ing the two arms.
+        prev = ready_mark[0]
+        for i, (stamp, (ts_us, d_first, d_n)) in enumerate(
+                zip(stamps, dispatch_marks)):
+            if prev is not None:
+                (chained_hist if i else first_hist).observe(
+                    (stamp - prev) * 1e3 / d_n)
+            if tracer.enabled:
+                if prev is not None:
+                    ts_us = max(ts_us, tracer.us_at(prev))
+                tracer.complete(
+                    "device", f"rounds {d_first}..{d_first + d_n - 1}",
+                    ts_us, tracer.us_at(stamp) - ts_us, round_first=d_first,
+                    rounds=d_n, sketch_path=sketch_path)
+            prev = stamp
+        dispatch_marks.clear()
+        ready_mark[0] = host_mark[0] = stamps[-1]
         t_commit0 = time.perf_counter()
         with tracer.span("runner", "commit", round_first=first,
                          rounds=committed):
@@ -571,6 +611,7 @@ def run_loop(
                             on_dispatched(rnd + len(lrs) - 1)
                         phase_hist["dispatch"].observe(
                             (time.perf_counter() - t_d0) * 1e3)
+                        note_dispatched()
                         if len(pending) > 1:
                             pending[-2].release_state()  # superseded head
                         pending_rounds += len(lrs)
@@ -604,6 +645,7 @@ def run_loop(
                                 on_dispatched(rnd + j)
                             phase_hist["dispatch"].observe(
                                 (time.perf_counter() - t_d0) * 1e3)
+                            note_dispatched()
                             if len(pending) > 1:
                                 pending[-2].release_state()  # superseded
                             pending_rounds += 1

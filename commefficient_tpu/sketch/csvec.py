@@ -466,9 +466,11 @@ def unsketch_topk(
         chunks = jnp.arange(spec.num_slabs, dtype=jnp.int32)
 
         if _use_pallas(spec) or spec.d * 4 <= UNSKETCH_SINGLE_SHOT_BYTES:
-            est = query_all(spec, table)  # routes Pallas/oracle internally
-            top_idx = topk_abs(est, k, recall=recall, impl=impl)
-            return top_idx, est[top_idx]
+            with jax.named_scope("server_query"):
+                est = query_all(spec, table)  # routes Pallas/oracle internally
+            with jax.named_scope("server_topk"):
+                top_idx = topk_abs(est, k, recall=recall, impl=impl)
+                return top_idx, est[top_idx]
 
         def chunk_estimates(slab):
             idx = slab * spec.c + jnp.arange(spec.c, dtype=jnp.int32)
@@ -482,8 +484,13 @@ def unsketch_topk(
             return idx, query(spec, table, jnp.clip(idx, 0, spec.d - 1))
 
     def body(carry, chunk):
+        with jax.named_scope("server_query"):
+            idx, est = chunk_estimates(chunk)
+        return select(carry, idx, est), None
+
+    @jax.named_scope("server_topk")
+    def select(carry, idx, est):
         run_idx, run_vals = carry
-        idx, est = chunk_estimates(chunk)
         valid = idx < spec.d
         if impl != "exact" and est.shape[0] > k:
             # within-chunk preselection (the one approximate pass; for
@@ -497,7 +504,7 @@ def unsketch_topk(
         cand_valid = jnp.concatenate([run_idx >= 0, valid])
         score = jnp.where(cand_valid, jnp.abs(cand_vals), -1.0)
         _, sel = jax.lax.top_k(score, k)
-        return (cand_idx[sel], cand_vals[sel]), None
+        return cand_idx[sel], cand_vals[sel]
 
     init = (jnp.full((k,), -1, dtype=jnp.int32), jnp.zeros((k,), dtype=table.dtype))
     (top_idx, top_vals), _ = jax.lax.scan(body, init, chunks)

@@ -557,6 +557,291 @@ def test_profile_window_degrades_to_loud_noop(tmp_path, monkeypatch, capsys):
     pw.close()  # nothing active: both no-ops
 
 
+# ------------------------------------ the round's own clock (ready stamps)
+
+
+def _loop_cfg(total, **kw):
+    return RunnerConfig(total_rounds=total, eval_every=1 << 30,
+                        prefetch_depth=2, **kw)
+
+
+def _run_logged(session, cfg):
+    """run_loop with a row sink: (stats, rows less time_s)."""
+    rows = []
+    stats = run_loop(session, FedOptimizer(lambda _: LR, 1), cfg,
+                     build_row=lambda **kw: kw, logger=rows)
+    for r in rows:
+        r.pop("time_s")
+    return stats, rows
+
+
+STAMP_HISTS = ("runner_round_interval_chained_ms",
+               "runner_round_interval_first_ms", "runner_bubble_host_ms")
+
+
+@pytest.mark.parametrize("depth", [2, 1])
+def test_ready_stamps_count_against_drains_and_rounds(depth):
+    """The drain stamps each pending dispatch as its metrics come back: a
+    dispatch behind its predecessor observes `chained`, the first of a
+    drain observes `first` and the first dispatch after a drain
+    `bubble_host`, except in the first drain of a call, which has no stamp
+    before it (none is carried across run_loop calls). Reading the metrics
+    one dispatch at a time changes no result: state and rows equal the
+    sync loop's."""
+    reg = obreg.default()
+    ref = _tiny_session()
+    rows_ref = (_run_logged(ref, _loop_cfg(4, sync_loop=True))[1]
+                + _run_logged(ref, _loop_cfg(7, sync_loop=True))[1])
+
+    before = {n: reg.histogram(n).count for n in STAMP_HISTS}
+    s = _tiny_session()
+    seg1, rows1 = _run_logged(s, _loop_cfg(4, max_inflight=depth))
+    seg2, rows2 = _run_logged(s, _loop_cfg(7, max_inflight=depth))
+    got = {n: reg.histogram(n).count - before[n] for n in STAMP_HISTS}
+
+    assert (seg1.drains, seg2.drains) == ((2, 2) if depth == 2 else (4, 3))
+    rounds, drains = 7, seg1.drains + seg2.drains
+    assert got["runner_round_interval_chained_ms"] == rounds - drains
+    assert got["runner_round_interval_first_ms"] == drains - 2
+    assert got["runner_bubble_host_ms"] == drains - 2
+    for n in STAMP_HISTS:
+        assert reg.histogram(n).count == 0 or reg.histogram(n).percentile(0) >= 0
+    _assert_params_equal(ref, s)
+    assert rows1 + rows2 == rows_ref and len(rows_ref) == 2
+
+
+def test_device_track_spans_neither_overlap_nor_end_together(tmp_path):
+    """The deferred device spans run from max(dispatch mark, previous ready
+    stamp) to their own ready stamp: two rounds in flight read as two
+    spans in a row, not two that end together at the drain."""
+    obtrace.configure(trace_path=str(tmp_path / "t.json"))
+    run_loop(_tiny_session(), FedOptimizer(lambda _: LR, 1),
+             _loop_cfg(6, max_inflight=2))
+    spans = sorted((e for e in obtrace.get().events()
+                    if e["cat"] == "device"), key=lambda e: e["ts"])
+    assert [e["args"]["round_first"] for e in spans] == list(range(6))
+    assert all(e["args"]["sketch_path"] == "ravel" for e in spans)
+    ends = [e["ts"] + e["dur"] for e in spans]
+    assert len(set(ends)) == len(ends)
+    for a_end, b in zip(ends, spans[1:]):
+        assert a_end <= b["ts"] + 2e-3  # ts and dur are rounded to the ns
+
+
+class _RecordingAnnotation:
+    made: list = []
+
+    def __init__(self, name, **kw):
+        self.made.append((name, kw))
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_loop_spans_are_mirrored_inside_a_profile_window(tmp_path,
+                                                         monkeypatch):
+    """While a ProfileWindow capture runs, every tracer span also enters a
+    jax.profiler.TraceAnnotation("<track>/<name>", **args), with --trace
+    off; outside one nothing is constructed."""
+    made = _RecordingAnnotation.made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RecordingAnnotation)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    opt = FedOptimizer(lambda _: LR, 1)
+
+    run_loop(_tiny_session(), opt, _loop_cfg(4, max_inflight=2))
+    assert made == [] and not obtrace.get().enabled
+
+    run_loop(_tiny_session(), opt, _loop_cfg(
+        6, max_inflight=2, profile_rounds="2:3", profile_dir=str(tmp_path)))
+    assert obtrace.get().event_count() == 0  # the buffer stayed disarmed
+    loop = [(n, kw) for n, kw in made if n.startswith("runner/")]
+    assert [(n, kw["round"]) for n, kw in loop if "round" in kw] == [
+        ("runner/prepare", 2), ("runner/dispatch", 2),
+        ("runner/prepare", 3), ("runner/dispatch", 3)]
+    assert [(n, kw["round_first"], kw["rounds"]) for n, kw in loop
+            if "round_first" in kw] == [("runner/drain", 2, 2),
+                                        ("runner/commit", 2, 2)]
+    assert {n for n, _ in made} - {n for n, _ in loop} <= {
+        "federated/prepare_round"}
+
+    made.clear()  # the window closed: the mirror is off again
+    with obtrace.span("runner", "prepare", round=9):
+        pass
+    assert made == []
+
+
+# ------------------------------------------- named phases, capture summary
+
+
+def _lowered_scopes(mode, path):
+    import re
+
+    from commefficient_tpu.federated import engine
+
+    params = {"w": jnp.ones((6, 3)), "b": jnp.zeros(3)}
+    d = ravel_pytree(params)[0].size
+    kw = {"sketch": dict(num_rows=3, num_cols=16, k=4),
+          "true_topk": dict(k=4)}.get(mode, {})
+    cfg = engine.EngineConfig(
+        mode=ModeConfig(mode=mode, d=d, momentum=0.9,
+                        momentum_type="virtual",
+                        error_type="none" if mode == "uncompressed"
+                        else "virtual", **kw),
+        sketch_path=path)
+    batch = {"x": jnp.ones((4, 2, 6)), "y": jnp.zeros((4, 2), jnp.int32),
+             "mask": jnp.ones((4, 2))}
+    text = jax.jit(engine.make_round_step(_quad_loss, cfg)).lower(
+        engine.init_server_state(cfg, params, {}), batch, {},
+        jnp.float32(LR), jax.random.PRNGKey(0)).as_text(debug_info=True)
+    words = set()
+    for loc in re.findall(r'loc\("([^"]*)"', text):
+        words.update(re.findall(r"[A-Za-z_][A-Za-z0-9_]*", loc))
+    return words & set(engine.ROUND_PHASES)
+
+
+@pytest.mark.parametrize("mode,path,lacks", [
+    ("sketch", "ravel", ()),
+    ("sketch", "layerwise", ()),
+    ("true_topk", "ravel", ("server_query",)),
+    ("uncompressed", "ravel", ("server_query", "server_topk")),
+])
+def test_lowered_round_step_names_its_phases(mode, path, lacks):
+    from commefficient_tpu.federated.engine import ROUND_PHASES
+
+    assert _lowered_scopes(mode, path) == set(ROUND_PHASES) - set(lacks)
+
+
+def test_capture_summary_by_phase():
+    """Hand-built device planes in the shape load_device_planes gives:
+    nesting counts once (a while's body goes to its own phases, the rest of
+    the while to the while's), an unknown scope goes to `other`, the phases
+    sum to the busy union, and the rounds are the main module's runs."""
+    from commefficient_tpu.obs import profiler
+
+    US = 1000
+    phases = ("client_grad", "cohort_reduce", "server_topk")
+    ops = [
+        ("%fusion.1", 0, 40 * US, "jit(step)/client_grad/vmap(jvp())/dot"),
+        ("%concatenate", 40 * US, 10 * US,
+         "jit(step)/client_grad/vmap(cohort_reduce)/concatenate"),
+        ("%while", 60 * US, 30 * US, "jit(step)/server_algebra/while"),
+        ("%sort", 65 * US, 20 * US,
+         "jit(step)/server_algebra/while/body/server_topk/sort"),
+        ("%copy", 70 * US, 5 * US, ""),  # inside the sort: the sort's phase
+        ("%threefry", 100 * US, 10 * US, "jit(_threefry_split)/shift"),
+        ("%not_a_phase", 110 * US, 10 * US, "jit(step)/my_client_grad_x/mul"),
+    ]
+    planes = [
+        ("/device:TPU:0", [
+            ("XLA Modules", [("jit_step(1)", 0, 90 * US, ""),
+                             ("jit_step(1)", 100 * US, 1, ""),
+                             ("jit__threefry_split(2)", 100 * US, 10 * US, "")]),
+            ("XLA Ops", ops)])]
+    got = profiler.summarize(planes, phases)
+    assert got["traced_rounds"] == 2 and got["round_program"] == "jit_step(1)"
+    ms = got["phase_device_ms"]
+    assert ms["client_grad"] == pytest.approx(0.040 / 2)
+    assert ms["cohort_reduce"] == pytest.approx(0.010 / 2)
+    assert ms["server_topk"] == pytest.approx(0.020 / 2)
+    assert ms["other"] == pytest.approx((0.010 + 0.010 + 0.010) / 2)
+    assert sum(ms.values()) == pytest.approx(got["device_busy_ms"])
+    assert got["device_busy_ms"] == pytest.approx(0.100 / 2)
+
+    reg = obreg.Registry()
+    profiler.publish(got, reg)
+    shown = reg.snapshot()
+    assert shown["profile_traced_rounds"]["value"] == 2
+    assert shown["profile_phase_device_ms_server_topk"]["value"] == ms["server_topk"]
+    assert shown["profile_device_busy_ms"]["value"] == got["device_busy_ms"]
+    line = profiler.format_summary(got, phases)
+    assert line.startswith("device ms/round: client_grad 0.0 | cohort_reduce")
+    assert "other" in line and "2 rounds" in line
+
+    with pytest.raises(ValueError):  # a capture with no device operations
+        profiler.summarize([("/device:TPU:0", [("XLA Ops", [])])], phases)
+
+
+def test_load_device_planes_reads_the_scope_stat_of_the_metadata(tmp_path):
+    """The op_name stat sits on the event's metadata, which ProfileData does
+    not show: an .xplane.pb built with the protobuf's own classes, with the
+    scope once as a string and once as a reference to a stat metadata, an
+    operation with no scope, a line that is not read and a host plane that
+    must be ignored."""
+    from commefficient_tpu.obs import profiler
+
+    space = profiler._xplane_pb2().XSpace()
+    host = space.planes.add(name="/host:CPU")
+    host.stat_metadata[1].name = "tf_op"
+    host.event_metadata[7].name = "host event"
+    host.event_metadata[7].stats.add(metadata_id=1, str_value="jit(f)/apply/x")
+    host.lines.add(name="XLA Ops").events.add(metadata_id=7, duration_ps=5)
+
+    dev = space.planes.add(id=3, name="/device:TPU:0")
+    for i, name in ((300, "tf_op"), (2, "flops"),
+                    (9, "jit(step)/apply/scatter-add:")):
+        dev.stat_metadata[i].name = name
+    sort = dev.event_metadata[1]
+    sort.name = "%sort = f32[8] sort(...)"
+    sort.stats.add(metadata_id=2, double_value=3.5)
+    sort.stats.add(metadata_id=300, str_value="jit(step)/server_topk/sort:")
+    scatter = dev.event_metadata[2]
+    scatter.name = "%scatter = f32[8] scatter(...)"
+    scatter.stats.add(metadata_id=300, ref_value=9)
+    dev.event_metadata[3].name = "%copy-start = ..."
+    dev.event_metadata[3].stats.add(metadata_id=2, uint64_value=1 << 40)
+    dev.event_metadata[4].name = "jit_step(1)"
+    ops = dev.lines.add(name="XLA Ops", timestamp_ns=1000)
+    ops.events.add(metadata_id=1, offset_ps=0, duration_ps=20_000_000)
+    ops.events.add(metadata_id=2, offset_ps=20_000_000, duration_ps=5_000_000)
+    ops.events.add(metadata_id=3, offset_ps=25_000_000, duration_ps=5_000_000)
+    dev.lines.add(name="XLA Modules", timestamp_ns=1000).events.add(
+        metadata_id=4, offset_ps=0, duration_ps=30_000_000)
+    dev.lines.add(name="Scalar Unit").events.add(metadata_id=3, duration_ps=1)
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space.SerializeToString())
+
+    planes = profiler.load_device_planes(str(path))
+    assert planes == [("/device:TPU:0", [
+        ("XLA Ops", [
+            ("%sort = f32[8] sort(...)", 1000.0, 20000.0,
+             "jit(step)/server_topk/sort:"),
+            ("%scatter = f32[8] scatter(...)", 21000.0, 5000.0,
+             "jit(step)/apply/scatter-add:"),
+            ("%copy-start = ...", 26000.0, 5000.0, "")]),
+        ("XLA Modules", [("jit_step(1)", 1000.0, 30000.0, "")])])]
+    ms = profiler.summarize(planes, ("server_topk", "apply"))["phase_device_ms"]
+    assert ms == pytest.approx(
+        {"server_topk": 0.020, "apply": 0.005, "other": 0.005})
+
+
+def test_profile_window_never_shows_an_earlier_captures_summary(
+        tmp_path, monkeypatch, capsys):
+    """profile_traced_rounds is zeroed when a capture starts, so where its
+    summary fails (here: no capture file; and a capture whose operations
+    name no phase) the readers see no reading, not the last capture's."""
+    from commefficient_tpu.obs import profiler
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    rounds = obreg.default().gauge("profile_traced_rounds")
+    rounds.set(12)  # what an earlier capture of this process published
+    pw = ProfileWindow.parse("0:1", str(tmp_path), phases=("apply",))
+    pw.on_dispatch(0)
+    assert rounds.value == 0
+    pw.on_committed(2)
+    assert "no summary of the capture (FileNotFoundError" in capsys.readouterr().err
+    assert rounds.value == 0
+
+    unscoped = [("/device:TPU:0", [
+        ("XLA Modules", [("jit_step(1)", 0, 10, "")]),
+        ("XLA Ops", [("%fusion", 0, 10, "")])])]
+    with pytest.raises(ValueError, match="names a phase"):
+        profiler.summarize(unscoped, ("apply",))
+
+
 # --------------------------------------------- crash-safe JSONL logging
 
 
