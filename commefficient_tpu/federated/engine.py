@@ -104,21 +104,24 @@ class EngineConfig:
     # device and on a client_shards-way mesh (pinned by the CPU-mesh parity
     # tests), while different shard counts differ at fp-reassociation level.
     client_shards: int = 1
-    # How the round's sketch table is built (mode=sketch only):
-    # - "ravel" (default): every layer's gradient is concatenated into one
-    #   flat [d] vector (ravel_pytree) and compressed in one shot — the
-    #   seed behavior, bit-for-bit.
-    # - "layerwise": per-layer gradients come off the backward pass as a
-    #   pytree and each leaf folds DIRECTLY into the running r x c table
-    #   (sketch/layerwise.py) — the flat [d] gradient, its [W, d] /
-    #   [chunk, d] per-client stacks, and the flat params copy for the
-    #   delta apply never materialize. Pinned BIT-identical to the ravel
-    #   path (fused, split, sharded): sketch addition is the same ordered
-    #   float sum either way (csvec._sketch_vec_rotation's explicit slab
-    #   fold). Caveats: quarantine/dp_clip client norms are folded from
-    #   per-leaf partial sums (values equal to the flat norm only up to fp
-    #   association), and the random hash family requires num_blocks == 1
-    #   (the blocked ravel oracle associates differently).
+    # How the round's sketch table is built (mode=sketch only). Both paths
+    # share ONE cohort reduce (_weighted_client_reduce): per-client
+    # gradients stay a pytree and are summed over clients leaf by leaf, so
+    # neither writes a [W, d] / [chunk, d] stack of flat gradients, and
+    # quarantine/dp_clip client norms are folded from per-leaf partial sums
+    # on both. What differs is only where the sketch is folded and how the
+    # delta is applied:
+    # - "ravel" (default): the REDUCED tree is concatenated into one flat
+    #   [d] vector (ravel_pytree, once a round) and compressed in one shot;
+    #   the delta is applied on the flat params view and unraveled.
+    # - "layerwise": each reduced leaf folds DIRECTLY into the running
+    #   r x c table (sketch/layerwise.py) — not even the one reduced flat
+    #   [d] gradient, nor the flat params copy for the delta apply,
+    #   materializes. Pinned BIT-identical to the ravel path (fused, split,
+    #   sharded): sketch addition is the same ordered float sum either way
+    #   (csvec._sketch_vec_rotation's explicit slab fold). Caveat: the
+    #   random hash family requires num_blocks == 1 (the blocked ravel
+    #   oracle associates differently).
     sketch_path: str = "ravel"
     # Sketch-space quarantine (cohort-level fault tolerance): > 0 rejects any
     # client whose update L2 norm exceeds this multiple of the RUNNING MEDIAN
@@ -876,9 +879,9 @@ def _client_layer_norms(updates: jnp.ndarray, segments) -> jnp.ndarray:
 
 
 def _client_layer_norms_tree(updates_tree) -> jnp.ndarray:
-    """[W, L] per-leaf norms from a PYTREE of [W, ...] leaves — the
-    layerwise-path twin of `_client_layer_norms` (leaf order == ravel
-    order, so column l is the same layer on both sketch paths)."""
+    """[W, L] per-leaf norms from a PYTREE of [W, ...] leaves — the linear
+    grad modes' twin of `_client_layer_norms` (leaf order == ravel order,
+    so column l is the same layer as that one's segment l)."""
     cols = [
         jnp.sqrt(jnp.sum(jnp.square(leaf.astype(jnp.float32)),
                          axis=tuple(range(1, leaf.ndim))))
@@ -1074,106 +1077,12 @@ def _survivor_metrics(metrics, part) -> dict:
     return out
 
 
-def _weighted_client_reduce(
-    cfg: EngineConfig, grad_client: Callable,
-    params, pflat, net_state, batch, client_rngs, part,
-    *, qmed=None, nan_safe: bool = False, lmed=None, segments=None,
-):
-    """Participation-weighted SUMS over the sampled clients of (clipped)
-    updates, mutable-collection contributions, and metric values — the whole
-    client phase of a linear-mode round before normalization. Returns
-    (wsum, ns_sum, m_sum, part_eff, norms, lnorms): `part_eff` is the [W]
-    mask of clients that actually contributed (the input mask minus any
-    quarantined clients), `norms` the [W] per-client update L2 norms (None
-    with the quarantine off), `lnorms` the [W, L] per-leaf norms
-    (quarantine_scope="layer" only — `lmed`/`segments` carry that scope's
-    per-leaf medians and static leaf ranges; a client over ANY leaf's
-    screen is quarantined exactly like a scalar-screen rejection).
-
-    One vmap when cfg.client_chunk is 0; otherwise a lax.scan over chunks of
-    client_chunk clients (each chunk vmapped), accumulating additively, so at
-    most client_chunk full [d] gradients coexist in HBM (SURVEY.md §7 hard
-    part (e)). Linearity of the weighted sum makes chunking exact up to fp
-    summation order — which is also what lets the quarantine run per chunk
-    against the replicated running-median threshold (`qmed`, from server
-    state): the verdict never needs the other chunks' norms.
-
-    nan_safe switches the 0/1 weighting from multiply to modes.mask_rows so
-    a masked client carrying NaN/Inf (poisoned update, zeroed dead-client
-    batch) still contributes an exact zero; it is forced on whenever the
-    quarantine is armed, and value-identical to the multiply form on finite
-    data."""
-    nan_safe = nan_safe or cfg.client_update_clip > 0
-
-    @jax.named_scope("cohort_reduce")
-    def reduce(updates, nstates, metrics, cpart):
-        norms_c = lnorms_c = None
-        if cfg.client_update_clip > 0:
-            norms_c = _client_norms(updates)
-            bad = _quarantine_mask(cfg, norms_c, qmed)
-            if lmed is not None:
-                lnorms_c = _client_layer_norms(updates, segments)
-                bad = bad | _quarantine_layer_mask(cfg, lnorms_c, lmed)
-            cpart = cpart * (1.0 - bad.astype(cpart.dtype))
-        updates = _clip_updates(cfg, updates)
-        if nan_safe:
-            wsum = modes.mask_rows(cpart, updates).sum(axis=0)
-            ns_sum = jax.tree.map(
-                lambda s: modes.mask_rows(cpart, s).sum(0), nstates)
-            m_sum = jax.tree.map(
-                lambda m: modes.mask_rows(cpart, m).sum(axis=0), metrics)
-        else:
-            wsum = (updates * cpart[:, None]).sum(axis=0)
-            ns_sum = jax.tree.map(
-                lambda s: (s * modes.bcast(cpart, s)).sum(0), nstates)
-            m_sum = jax.tree.map(
-                lambda m: jnp.sum(m * modes.bcast(cpart, m), axis=0), metrics)
-        return wsum, ns_sum, m_sum, cpart, norms_c, lnorms_c
-
-    def chunk(cb, crngs, cpart):
-        with jax.named_scope("client_grad"):
-            updates, nstates, metrics = jax.vmap(
-                lambda b, r: grad_client(params, pflat, net_state, b, r)
-            )(cb, crngs)
-        return reduce(updates, nstates, metrics, cpart)
-
-    W = part.shape[0]
-    C = cfg.client_chunk
-    if not C or C >= W:
-        return chunk(batch, client_rngs, part)
-    if W % C:
-        raise ValueError(
-            f"client_chunk={C} must divide the sampled cohort ({W})"
-        )
-    re = lambda a: a.reshape((W // C, C) + a.shape[1:])  # noqa: E731
-    xs = (jax.tree.map(re, batch),
-          client_rngs.reshape((W // C, C) + client_rngs.shape[1:]),
-          part.reshape(W // C, C))
-    shapes = jax.eval_shape(chunk, *jax.tree.map(lambda a: a[0], xs))
-    init = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes[:3])
-
-    def body(carry, x):
-        wsum, ns_sum, m_sum, cpart_eff, norms_c, lnorms_c = chunk(*x)
-        with jax.named_scope("cohort_reduce"):
-            carry = jax.tree.map(jnp.add, carry, (wsum, ns_sum, m_sum))
-        return carry, (cpart_eff, norms_c, lnorms_c)
-
-    acc, (pe, norms, lnorms) = jax.lax.scan(body, init, xs)
-    part_eff = pe.reshape(W)
-    if norms is not None:
-        norms = norms.reshape(W)
-    if lnorms is not None:
-        lnorms = lnorms.reshape(W, -1)
-    return acc + (part_eff, norms, lnorms)
-
-
 def _client_norms_tree(updates_tree) -> jnp.ndarray:
     """[W] per-client update L2 norms from a PYTREE of [W, ...] leaves:
     per-leaf squared sums folded in ravel leaf order (f32 accumulation).
-    The layerwise counterpart of `_client_norms` — equal to the flat-vector
-    norm only up to fp association (the flat path reduces one contiguous
-    [d] axis; this folds per-leaf partials), which is why the quarantine
-    median metric is pinned across sketch paths at tolerance, not bitwise."""
+    The linear grad modes' counterpart of `_client_norms` — equal to the
+    flat-vector norm only up to fp association (that one reduces one
+    contiguous [d] axis; this folds per-leaf partials)."""
     total = None
     for leaf in jax.tree.leaves(updates_tree):
         s = jnp.sum(jnp.square(leaf.astype(jnp.float32)),
@@ -1193,25 +1102,51 @@ def _clip_updates_tree(cfg: EngineConfig, updates_tree):
     return jax.tree.map(lambda l: l * modes.bcast(fac, l), updates_tree)
 
 
-def _weighted_client_reduce_tree(
+# graftlint: sketch-boundary — THE ravel of the linear grad modes: the
+# cohort's already-reduced gradient tree becomes the flat [d] the compress
+# and the server step take, once a round (never one client's gradient)
+@jax.named_scope("cohort_reduce")
+def _ravel_reduced(wsum_tree):
+    return ravel_pytree(wsum_tree)[0]
+
+
+def _weighted_client_reduce(
     cfg: EngineConfig, grad_client_tree: Callable,
     params, net_state, batch, client_rngs, part,
-    *, qmed=None, nan_safe: bool = False, lmed=None, segments=None,
+    *, qmed=None, nan_safe: bool = False, lmed=None, ravel: bool = True,
 ):
-    """The layerwise (`sketch_path="layerwise"`) mirror of
-    `_weighted_client_reduce`: identical participation weighting, validity
-    masking, quarantine screen, DP clip, and chunked-scan structure — but
-    per-client updates stay a PYTREE of per-layer leaves ([W, ...leaf]) and
-    the weighted sums are taken per leaf, so the flat [d] gradient (and its
-    [W, d]/[chunk, d] stacks) never materializes. Per coordinate the
-    client-axis sums are the same ordered fp reduction as the flat path's,
-    which is what keeps the downstream sketch bit-identical. Returns
-    (wsum_tree, ns_sum, m_sum, part_eff, norms, lnorms) — lnorms as in the
-    flat reduce (layer scope only; `segments` is unused here, the tree IS
-    the segmentation). Kept as a deliberate structural mirror rather than
-    a shared polymorphic body: the ravel path's compiled program must stay
-    byte-for-byte the seed's."""
-    del segments  # the pytree carries its own leaf boundaries
+    """Participation-weighted SUMS over the sampled clients of (clipped)
+    updates, mutable-collection contributions, and metric values — the whole
+    client phase of a linear-mode round before normalization. Returns
+    (wsum, ns_sum, m_sum, part_eff, norms, lnorms): `part_eff` is the [W]
+    mask of clients that actually contributed (the input mask minus any
+    quarantined clients), `norms` the [W] per-client update L2 norms (None
+    with the quarantine off), `lnorms` the [W, L] per-leaf norms
+    (quarantine_scope="layer" only — `lmed` carries that scope's per-leaf
+    medians; a client over ANY leaf's screen is quarantined exactly like a
+    scalar-screen rejection).
+
+    Per-client updates stay a PYTREE of [W, ...leaf] gradients
+    (`_make_grad_client_tree`) through the screen, the clip and the mask,
+    and the weighted sum is taken per leaf: sum_i w_i ravel(g_i) ==
+    ravel(sum_i w_i g_i), so only the reduced tree is raveled (`ravel`, the
+    flat [d] `wsum` every caller but sketch_path="layerwise" takes) and no
+    [W, d] or [chunk, d] stack of flat gradients is ever written.
+
+    One vmap when cfg.client_chunk is 0; otherwise a lax.scan over chunks of
+    client_chunk clients (each chunk vmapped) that carries the tree of sums,
+    so at most client_chunk clients' per-leaf gradients coexist in HBM
+    (SURVEY.md §7 hard part (e)). Linearity of the weighted sum makes
+    chunking exact up to fp summation order — which is also what lets the
+    quarantine run per chunk against the replicated running-median threshold
+    (`qmed`, from server state): the verdict never needs the other chunks'
+    norms.
+
+    nan_safe switches the 0/1 weighting from multiply to modes.mask_rows so
+    a masked client carrying NaN/Inf (poisoned update, zeroed dead-client
+    batch) still contributes an exact zero; it is forced on whenever the
+    quarantine is armed, and value-identical to the multiply form on finite
+    data."""
     nan_safe = nan_safe or cfg.client_update_clip > 0
 
     @jax.named_scope("cohort_reduce")
@@ -1226,19 +1161,11 @@ def _weighted_client_reduce_tree(
             cpart = cpart * (1.0 - bad.astype(cpart.dtype))
         updates = _clip_updates_tree(cfg, updates)
         if nan_safe:
-            wsum = jax.tree.map(
-                lambda l: modes.mask_rows(cpart, l).sum(axis=0), updates)
-            ns_sum = jax.tree.map(
-                lambda s: modes.mask_rows(cpart, s).sum(0), nstates)
-            m_sum = jax.tree.map(
-                lambda m: modes.mask_rows(cpart, m).sum(axis=0), metrics)
+            rows = lambda a: modes.mask_rows(cpart, a)  # noqa: E731
         else:
-            wsum = jax.tree.map(
-                lambda l: (l * modes.bcast(cpart, l)).sum(axis=0), updates)
-            ns_sum = jax.tree.map(
-                lambda s: (s * modes.bcast(cpart, s)).sum(0), nstates)
-            m_sum = jax.tree.map(
-                lambda m: jnp.sum(m * modes.bcast(cpart, m), axis=0), metrics)
+            rows = lambda a: a * modes.bcast(cpart, a)  # noqa: E731
+        wsum, ns_sum, m_sum = jax.tree.map(
+            lambda a: rows(a).sum(axis=0), (updates, nstates, metrics))
         return wsum, ns_sum, m_sum, cpart, norms_c, lnorms_c
 
     def chunk(cb, crngs, cpart):
@@ -1251,31 +1178,32 @@ def _weighted_client_reduce_tree(
     W = part.shape[0]
     C = cfg.client_chunk
     if not C or C >= W:
-        return chunk(batch, client_rngs, part)
-    if W % C:
-        raise ValueError(
-            f"client_chunk={C} must divide the sampled cohort ({W})"
-        )
-    re = lambda a: a.reshape((W // C, C) + a.shape[1:])  # noqa: E731
-    xs = (jax.tree.map(re, batch),
-          client_rngs.reshape((W // C, C) + client_rngs.shape[1:]),
-          part.reshape(W // C, C))
-    shapes = jax.eval_shape(chunk, *jax.tree.map(lambda a: a[0], xs))
-    init = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes[:3])
+        out = chunk(batch, client_rngs, part)
+    else:
+        if W % C:
+            raise ValueError(
+                f"client_chunk={C} must divide the sampled cohort ({W})"
+            )
+        re = lambda a: a.reshape((W // C, C) + a.shape[1:])  # noqa: E731
+        xs = (jax.tree.map(re, batch),
+              client_rngs.reshape((W // C, C) + client_rngs.shape[1:]),
+              part.reshape(W // C, C))
+        shapes = jax.eval_shape(chunk, *jax.tree.map(lambda a: a[0], xs))
+        init = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes[:3])
 
-    def body(carry, x):
-        wsum, ns_sum, m_sum, cpart_eff, norms_c, lnorms_c = chunk(*x)
-        with jax.named_scope("cohort_reduce"):
-            carry = jax.tree.map(jnp.add, carry, (wsum, ns_sum, m_sum))
-        return carry, (cpart_eff, norms_c, lnorms_c)
+        def body(carry, x):
+            wsum, ns_sum, m_sum, cpart_eff, norms_c, lnorms_c = chunk(*x)
+            with jax.named_scope("cohort_reduce"):
+                carry = jax.tree.map(jnp.add, carry, (wsum, ns_sum, m_sum))
+            return carry, (cpart_eff, norms_c, lnorms_c)
 
-    acc, (pe, norms, lnorms) = jax.lax.scan(body, init, xs)
-    part_eff = pe.reshape(W)
-    if norms is not None:
-        norms = norms.reshape(W)
-    if lnorms is not None:
-        lnorms = lnorms.reshape(W, -1)
-    return acc + (part_eff, norms, lnorms)
+        acc, (pe, norms, lnorms) = jax.lax.scan(body, init, xs)
+        out = acc + (pe.reshape(W),
+                     None if norms is None else norms.reshape(W),
+                     None if lnorms is None else lnorms.reshape(W, -1))
+    if ravel:
+        out = (_ravel_reduced(out[0]),) + out[1:]
+    return out
 
 
 @jax.named_scope("cohort_reduce")
@@ -1313,13 +1241,16 @@ def _ravel_params(params):
     return ravel_pytree(params)
 
 
-# graftlint: sketch-boundary — the ravel path's declared flat boundary: the
-# per-client gradient is raveled here ON PURPOSE (sketch_path="ravel", the
-# seed behavior); the layerwise path uses _make_grad_client_tree instead
+# graftlint: sketch-boundary — the NON-LINEAR modes' declared flat boundary:
+# per-client compression (local_topk, client-state modes) and the payload
+# round need each client's own flat [d] update, so the per-client gradient
+# is raveled here ON PURPOSE; the linear grad modes reduce the pytree of
+# _make_grad_client_tree instead and ravel once (_ravel_reduced)
 def _make_grad_client(loss_fn: Callable, cfg: EngineConfig) -> Callable:
-    """One client's contribution for grad-based modes: flat gradient (+ weight
-    decay, applied client-side as in the reference workers — SURVEY.md §3.1),
-    new mutable collections, metric sums."""
+    """One client's contribution as a flat [d] gradient (+ weight decay,
+    applied client-side as in the reference workers — SURVEY.md §3.1), new
+    mutable collections, metric sums — for the rounds that compress per
+    client."""
 
     def grad_client(params, pflat, net_state, cbatch, rng):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -1336,18 +1267,20 @@ def _make_grad_client(loss_fn: Callable, cfg: EngineConfig) -> Callable:
 
 
 def _make_grad_client_tree(loss_fn: Callable, cfg: EngineConfig) -> Callable:
-    """The layerwise mirror of `_make_grad_client`: per-layer gradients stay
-    a pytree (no ravel — each leaf folds straight into the sketch table
-    downstream). Weight decay applies per leaf, unconditionally like the
-    flat path's `gflat + wd * pflat` (same per-coordinate arithmetic, so
-    wd == 0 keeps the identical ±0.0 additions)."""
+    """One client's contribution for the linear grad modes
+    (`_weighted_client_reduce`): the gradient stays a pytree of per-layer
+    leaves — nothing is raveled per client. Weight decay applies per leaf,
+    client-side and unconditionally like `_make_grad_client`'s
+    `gflat + wd * pflat` (same per-coordinate arithmetic, so wd == 0 keeps
+    the identical ±0.0 additions)."""
 
     def grad_client(params, net_state, cbatch, rng):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             params, net_state, cbatch, rng
         )
-        grads = jax.tree.map(
-            lambda g, p: g + cfg.weight_decay * p, grads, params)
+        with jax.named_scope("cohort_reduce"):
+            grads = jax.tree.map(
+                lambda g, p: g + cfg.weight_decay * p, grads, params)
         return grads, aux["net_state"], aux["metrics"]
 
     return grad_client
@@ -1413,11 +1346,10 @@ def make_round_step(
     mcfg = cfg.mode
     _robust_scope_check(cfg)
     grad_client = _make_grad_client(loss_fn, cfg)
+    grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
     layerwise = cfg.sketch_path == "layerwise"
     layer_q = (cfg.client_update_clip > 0
                and cfg.quarantine_scope == "layer")
-    grad_client_tree = (_make_grad_client_tree(loss_fn, cfg) if layerwise
-                        else None)
 
     # graftlint: sketch-boundary — weight-delta modes (fedavg/localSGD) run
     # their local-SGD loop over the flat params by design; out of the
@@ -1483,29 +1415,22 @@ def make_round_step(
             # folds into the same reduction (survivor mean = sum(part·u) /
             # count(part); sum drops the /), and the reduce itself may run
             # chunked (cfg.client_chunk) so W full gradients never coexist.
+            wsum, ns_sum, m_sum, part_eff, norms, lnorms = (
+                _weighted_client_reduce(
+                    cfg, grad_client_tree, params, net_state, batch,
+                    client_rngs, part, qmed=qmed, nan_safe=valid is not None,
+                    lmed=lmed, ravel=not layerwise,
+                ))
             if layerwise:
-                # sketch-as-you-backprop: per-layer grads reduce per leaf
-                # and fold straight into the running r x c table — the flat
-                # [d] gradient never materializes (bit-identical to the
-                # ravel branch below, see EngineConfig.sketch_path)
-                wsum, ns_sum, m_sum, part_eff, norms, lnorms = (
-                    _weighted_client_reduce_tree(
-                        cfg, grad_client_tree, params, net_state, batch,
-                        client_rngs, part, qmed=qmed,
-                        nan_safe=valid is not None, lmed=lmed,
-                    ))
+                # sketch-as-you-backprop: the per-leaf sums fold straight
+                # into the running r x c table — not even the reduced flat
+                # [d] gradient materializes (see EngineConfig.sketch_path)
                 weighted = _layerwise_normalize(
                     mcfg, wsum, jnp.maximum(part_eff.sum(), 1.0))
                 new_net_state, out_metrics = _merged_survivor_finalize(
                     ns_sum, m_sum, part_eff, net_state)
                 agg = _layerwise_compress(mcfg, weighted, plan)
             else:
-                (wsum, ns_sum, m_sum, part_eff, norms,
-                 lnorms) = _weighted_client_reduce(
-                    cfg, grad_client, params, pflat, net_state, batch,
-                    client_rngs, part, qmed=qmed, nan_safe=valid is not None,
-                    lmed=lmed, segments=segments,
-                )
                 weighted, new_net_state, out_metrics = _finalize_client_reduce(
                     mcfg, wsum, ns_sum, m_sum, net_state, part_eff
                 )
@@ -1837,15 +1762,12 @@ def make_sharded_round_step(
             "sharded round needs client_shards > 1 (or a mesh with > 1 "
             "client shard); use make_round_step for the unsharded round"
         )
-    grad_client = _make_grad_client(loss_fn, cfg)
+    grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
     layerwise = cfg.sketch_path == "layerwise"
-    grad_client_tree = (_make_grad_client_tree(loss_fn, cfg) if layerwise
-                        else None)
     quarantine = cfg.client_update_clip > 0
     layer_q = quarantine and cfg.quarantine_scope == "layer"
 
-    def local_phase(params, pflat, net_state, qmed, lmed, batch_l, rngs_l,
-                    part_l):
+    def local_phase(params, net_state, qmed, lmed, batch_l, rngs_l, part_l):
         """One shard's client phase. Returns (wire, ns_sum, m_sum, part_eff)
         plus, with the quarantine armed, (part_valid, norms[, lnorms]) — the
         per-shard slices the merged tail reassembles into cohort-order [W]
@@ -1853,28 +1775,20 @@ def make_sharded_round_step(
         per shard against the replicated per-leaf medians, exactly like the
         scalar screen). On the layerwise path the shard's partial Count
         Sketch accumulates straight from the per-leaf weighted sums — the
-        shard's dense [d] partial never exists either (pflat is None
-        there)."""
+        shard's dense [d] partial never exists either."""
         batch_l, valid_l = split_valid(batch_l)
         if valid_l is not None:
             part_l = part_l * valid_l
-        segments = _leaf_segments(params) if layer_q else None
+        wsum, ns_sum, m_sum, part_eff_l, norms_l, lnorms_l = (
+            _weighted_client_reduce(
+                cfg, grad_client_tree, params, net_state, batch_l, rngs_l,
+                part_l, qmed=qmed, nan_safe=valid_l is not None, lmed=lmed,
+                ravel=not layerwise,
+            ))
         if layerwise:
-            wsum, ns_sum, m_sum, part_eff_l, norms_l, lnorms_l = (
-                _weighted_client_reduce_tree(
-                    cfg, grad_client_tree, params, net_state, batch_l,
-                    rngs_l, part_l, qmed=qmed, nan_safe=valid_l is not None,
-                    lmed=lmed,
-                ))
             wire = _layerwise_compress(mcfg, wsum,
                                        _layerwise_plan(mcfg, params))
         else:
-            (wsum, ns_sum, m_sum, part_eff_l, norms_l,
-             lnorms_l) = _weighted_client_reduce(
-                cfg, grad_client, params, pflat, net_state, batch_l, rngs_l,
-                part_l, qmed=qmed, nan_safe=valid_l is not None,
-                lmed=lmed, segments=segments,
-            )
             with jax.named_scope("compress"):
                 wire, _ = modes.client_compress(mcfg, wsum, {})
         if layer_q:
@@ -1908,7 +1822,6 @@ def make_sharded_round_step(
         def step(state, batch, client_rows, lr, rng):
             batch, health_flag = split_health(batch)
             params, net_state = state["params"], state["net_state"]
-            pflat = None if layerwise else _ravel_params(params)[0]
             W = jax.tree.leaves(batch)[0].shape[0]
             if W % S:
                 raise ValueError(
@@ -1937,8 +1850,7 @@ def make_sharded_round_step(
             # (unrolled, length-1 map, top-level tail) removes it for
             # every mode at once, it only moves which ops carry the ulp.
             stacked = jax.lax.map(
-                lambda xs: local_phase(params, pflat, net_state, qmed, lmed,
-                                       *xs),
+                lambda xs: local_phase(params, net_state, qmed, lmed, *xs),
                 shards,
             )
             new_state, out_metrics = _tail(state, stacked, lr, noise_rng,
@@ -1965,7 +1877,6 @@ def make_sharded_round_step(
 
     def body(state, batch_l, lr, rng):
         params, net_state = state["params"], state["net_state"]
-        pflat = None if layerwise else _ravel_params(params)[0]
         wl = jax.tree.leaves(batch_l)[0].shape[0]
         # replicated derivation of the FULL cohort's streams on every
         # device, then this shard's contiguous slice — per-client rng
@@ -1977,7 +1888,7 @@ def make_sharded_round_step(
         rngs_l = jax.lax.dynamic_slice_in_dim(all_rngs, lo, wl)
         part_l = jax.lax.dynamic_slice_in_dim(part, lo, wl)
         locals_ = local_phase(
-            params, pflat, net_state, qmed, lmed, batch_l, rngs_l, part_l)
+            params, net_state, qmed, lmed, batch_l, rngs_l, part_l)
         # THE cross-device move: gather the [S] partial wires (plus the tiny
         # per-shard effective-mask/norm rows) in shard order; the ordered
         # reduce happens outside, shared with the reference (merged tail)
@@ -2057,10 +1968,8 @@ def make_sharded_split_round_step(
             f"cfg.client_shards={cfg.client_shards} disagrees with the "
             f"{S}-way client mesh"
         )
-    grad_client = _make_grad_client(loss_fn, cfg)
+    grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
     layerwise = cfg.sketch_path == "layerwise"
-    grad_client_tree = (_make_grad_client_tree(loss_fn, cfg) if layerwise
-                        else None)
 
     from jax.sharding import PartitionSpec as P
 
@@ -2078,7 +1987,6 @@ def make_sharded_split_round_step(
     def client_body(state, batch_l, lr, rng):
         params, net_state = state["params"], state["net_state"]
         batch_l, valid_l = split_valid(batch_l)
-        pflat = None if layerwise else _ravel_params(params)[0]
         wl = jax.tree.leaves(batch_l)[0].shape[0]
         all_rngs, part, noise_rng = _cohort_streams(cfg, rng, wl * S)
         qmed = state["quarantine"]["median"] if quarantine else None
@@ -2087,13 +1995,14 @@ def make_sharded_split_round_step(
         part_l = jax.lax.dynamic_slice_in_dim(part, lo, wl)
         if valid_l is not None:
             part_l = part_l * valid_l
+        # layer scope is split-rejected (_split_quarantine_scope_check):
+        # the trailing lnorms slot is always None here
+        wsum_l, ns_l, m_l, pe_l, norms_l, _ = _weighted_client_reduce(
+            cfg, grad_client_tree, params, net_state, batch_l, rngs_l,
+            part_l, qmed=qmed, nan_safe=valid_l is not None,
+            ravel=not layerwise,
+        )
         if layerwise:
-            # layer scope is split-rejected (_split_quarantine_scope_check):
-            # the trailing lnorms slot is always None here
-            wsum_l, ns_l, m_l, pe_l, norms_l, _ = _weighted_client_reduce_tree(
-                cfg, grad_client_tree, params, net_state, batch_l, rngs_l,
-                part_l, qmed=qmed, nan_safe=valid_l is not None,
-            )
             # this shard's partial table, built straight from the per-leaf
             # sums: the dense [d] partial never exists, and the [r, c]
             # table is what crosses the program boundary (gathered below)
@@ -2102,10 +2011,6 @@ def make_sharded_split_round_step(
             wire_out = jax.lax.all_gather(table_l, axis_names, axis=0)
             fin_l = jnp.isfinite(table_l).all()[None]
         else:
-            wsum_l, ns_l, m_l, pe_l, norms_l, _ = _weighted_client_reduce(
-                cfg, grad_client, params, pflat, net_state, batch_l, rngs_l,
-                part_l, qmed=qmed, nan_safe=valid_l is not None,
-            )
             wire_out = wsum_l[None]
             fin_l = jnp.isfinite(wsum_l).all()[None]
         gathered = (ns_l, m_l, pe_l) + ((part_l, norms_l) if quarantine
@@ -2259,10 +2164,8 @@ def make_split_round_step(
             f"error_type={mcfg.error_type!r} momentum_type="
             f"{mcfg.momentum_type!r} needs the fused make_round_step"
         )
-    grad_client = _make_grad_client(loss_fn, cfg)
+    grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
     layerwise = cfg.sketch_path == "layerwise"
-    grad_client_tree = (_make_grad_client_tree(loss_fn, cfg) if layerwise
-                        else None)
 
     quarantine = cfg.client_update_clip > 0
     _split_quarantine_scope_check(cfg)
@@ -2270,7 +2173,6 @@ def make_split_round_step(
     def client_step(state, batch, lr, rng):
         batch, valid = split_valid(batch)
         params, net_state = state["params"], state["net_state"]
-        pflat = None if layerwise else _ravel_params(params)[0]
         num_sampled = jax.tree.leaves(batch)[0].shape[0]
         # identical stream derivation to the fused step (see its comment on
         # fold_in collisions), so split == fused holds bit-for-bit
@@ -2281,13 +2183,12 @@ def make_split_round_step(
             part = part * valid
         qmed = state["quarantine"]["median"] if quarantine else None
 
+        # layer scope is split-rejected: lnorms is always None here
+        wsum, ns_sum, m_sum, part_eff, norms, _ = _weighted_client_reduce(
+            cfg, grad_client_tree, params, net_state, batch, client_rngs,
+            part, qmed=qmed, nan_safe=valid is not None, ravel=not layerwise,
+        )
         if layerwise:
-            # layer scope is split-rejected: lnorms is always None here
-            wsum, ns_sum, m_sum, part_eff, norms, _ = (
-                _weighted_client_reduce_tree(
-                    cfg, grad_client_tree, params, net_state, batch,
-                    client_rngs, part, qmed=qmed, nan_safe=valid is not None,
-                ))
             weighted = _layerwise_compress(
                 mcfg,
                 _layerwise_normalize(mcfg, wsum,
@@ -2296,10 +2197,6 @@ def make_split_round_step(
             new_net_state, out_metrics = _merged_survivor_finalize(
                 ns_sum, m_sum, part_eff, net_state)
         else:
-            wsum, ns_sum, m_sum, part_eff, norms, _ = _weighted_client_reduce(
-                cfg, grad_client, params, pflat, net_state, batch, client_rngs,
-                part, qmed=qmed, nan_safe=valid is not None,
-            )
             weighted, new_net_state, out_metrics = _finalize_client_reduce(
                 mcfg, wsum, ns_sum, m_sum, net_state, part_eff
             )

@@ -815,3 +815,131 @@ def test_localsgd_virtual_momentum_multi_iter():
         p = p - V
     for a, b in zip(jax.tree.leaves(s2["params"]), jax.tree.leaves(unravel(p))):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+# ---- the per-leaf cohort reduce (PR 26) -------------------------------------
+
+@pytest.mark.parametrize("chunk", [0, 2])
+@pytest.mark.parametrize("mode_kw", [
+    dict(mode="sketch", k=16, num_rows=3, num_cols=1024, hash_family="rotation",
+         momentum_type="virtual", error_type="virtual"),
+    _ucfg(momentum_type="virtual", momentum=0.9),
+], ids=["sketch", "uncompressed"])
+def test_linear_round_program_holds_no_client_stack(mode_kw, chunk):
+    """The guard that keeps the [W, d] stack of flat per-client gradients from
+    coming back: the linear grad modes reduce the cohort leaf by leaf and
+    ravel the reduced tree once, so neither the lowered nor the compiled
+    round program holds an f32[W, d] (or, chunked, f32[chunk, d]) array."""
+    W = 4
+    data = _data(jax.random.PRNGKey(1), W * 4)
+    batch = jax.tree.map(lambda a: a.reshape((W, 4) + a.shape[1:]), data)
+    cfg, state, step = _make(dict(mode_kw), wd=5e-4, client_chunk=chunk)
+    d = cfg.mode.d
+    lowered = step.lower(state, batch, {}, jnp.float32(0.1), jax.random.PRNGKey(0))
+    texts = (lowered.as_text(), lowered.compile().as_text())
+    assert f"tensor<{d}xf32>" in texts[0] and f"f32[{d}]" in texts[1]
+    for rows in {W, chunk or W}:
+        for text in texts:
+            assert f"tensor<{rows}x{d}xf32>" not in text
+            assert f"f32[{rows},{d}]" not in text
+
+
+def _one_at_a_time_reduce(cfg, params, batch, rngs, part, qmed, lmed):
+    """What `_weighted_client_reduce` must equal: every client's FLAT
+    gradient computed alone (no vmap, no tree), then the screen, the clip
+    and the masked sum in numpy, in the order the engine documents."""
+    pflat, _ = ravel_pytree(params)
+    segs = engine._leaf_segments(params)
+    W = part.shape[0]
+    flats = []
+    for i in range(W):
+        cb = jax.tree.map(lambda a: a[i], batch)
+        g = jax.grad(lambda p: mlp_loss(p, {}, cb, rngs[i])[0])(params)
+        flats.append(np.asarray(ravel_pytree(g)[0] + cfg.weight_decay * pflat))
+    flats = np.stack(flats)
+    norms = np.sqrt((flats ** 2).sum(1))
+    lnorms = np.stack([np.sqrt((flats[:, o:o + n] ** 2).sum(1)) for o, n in segs], 1)
+    part_eff = np.asarray(part).copy()
+    if cfg.client_update_clip > 0:
+        bad = ~np.isfinite(norms)
+        if qmed > 0:
+            bad |= norms > cfg.client_update_clip * qmed
+        if lmed is not None:
+            bad |= (~np.isfinite(lnorms)).any(1)
+            bad |= ((lmed[None] > 0) & (lnorms > cfg.client_update_clip * lmed[None])).any(1)
+        part_eff = part_eff * (1.0 - bad)
+    if cfg.dp_clip > 0:
+        flats = flats * np.minimum(1.0, cfg.dp_clip / np.maximum(norms, 1e-12))[:, None]
+    wsum = sum((flats[i] for i in range(W) if part_eff[i] > 0),
+               np.zeros_like(flats[0]))
+    return wsum, part_eff, norms, lnorms
+
+
+@pytest.mark.parametrize("case", [
+    "plain", "valid_mask_nan", "quarantine_cohort", "quarantine_layer",
+    "dp_clip", "client_chunk"])
+def test_leafwise_reduce_equals_one_client_at_a_time(case):
+    """sum_i w_i ravel(g_i) == ravel(sum_i w_i g_i): the per-leaf reduce
+    against per-client flat gradients computed one client at a time, under
+    every transform that sits between the gradient and the sum."""
+    W = 8
+    params = init_mlp(jax.random.PRNGKey(0))
+    d = ravel_pytree(params)[0].size
+    data = _data(jax.random.PRNGKey(3), W * 4)
+    batch = jax.tree.map(lambda a: a.reshape((W, 4) + a.shape[1:]), data)
+    # distinct gradient scales, so that a norm screen has something to tell
+    batch["x"] = batch["x"] * jnp.linspace(0.5, 3.0, W)[:, None, None]
+    rngs = jax.random.split(jax.random.PRNGKey(5), W)
+    part = np.ones(W, np.float32)
+    eng_kw, nan_safe, qmed, lmed = {}, False, None, None
+    if case == "valid_mask_nan":
+        part[2] = 0.0
+        batch["x"] = batch["x"].at[2].set(jnp.nan)
+        nan_safe = True
+    elif case.startswith("quarantine"):
+        eng_kw = dict(client_update_clip=2.0, quarantine_scope=case.split("_")[1])
+        batch["x"] = batch["x"].at[5].set(jnp.nan)   # live and poisoned
+    elif case == "dp_clip":
+        eng_kw = dict(dp_clip=0.05)
+    elif case == "client_chunk":
+        eng_kw = dict(client_chunk=2)
+        part[6] = 0.0
+    cfg = engine.EngineConfig(mode=ModeConfig(**_ucfg(d=d)), weight_decay=5e-4, **eng_kw)
+
+    if cfg.client_update_clip > 0:
+        # thresholds between the two largest finite norms: the screen
+        # rejects exactly one healthy client beside the poisoned one
+        _, _, norms0, lnorms0 = _one_at_a_time_reduce(
+            cfg, params, batch, rngs, part, 0.0, None)
+        between = lambda v: float(np.sort(v[np.isfinite(v)])[-2:].mean()) / 2.0  # noqa: E731
+        if case == "quarantine_cohort":
+            qmed = between(norms0)
+        else:
+            qmed = 0.0
+            lmed = np.zeros(lnorms0.shape[1], np.float32)
+            lmed[2] = between(lnorms0[:, 2])
+    want, want_part, want_norms, want_lnorms = _one_at_a_time_reduce(
+        cfg, params, batch, rngs, part, qmed, lmed)
+
+    got, _, m_sum, part_eff, norms, lnorms = jax.jit(
+        lambda b, r, p: engine._weighted_client_reduce(
+            cfg, engine._make_grad_client_tree(mlp_loss, cfg), params, {}, b, r, p,
+            qmed=None if qmed is None else jnp.float32(qmed), nan_safe=nan_safe,
+            lmed=None if lmed is None else jnp.asarray(lmed)))(
+        batch, rngs, jnp.asarray(part))
+    assert got.shape == (d,) and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(part_eff), want_part)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
+                               atol=1e-6 * np.abs(want).max())
+    assert float(m_sum["count"]) == 4.0 * want_part.sum()
+    if case == "valid_mask_nan":
+        assert want_part.sum() == W - 1
+    if cfg.client_update_clip > 0:
+        assert want_part.sum() == W - 2 and want_part[5] == 0
+        live = np.isfinite(want_norms)
+        np.testing.assert_allclose(np.asarray(norms)[live], want_norms[live], rtol=1e-5)
+        assert not np.isfinite(np.asarray(norms)[~live]).any()
+        if lmed is not None:
+            np.testing.assert_allclose(np.asarray(lnorms)[live], want_lnorms[live], rtol=1e-5)
+    else:
+        assert norms is None and lnorms is None
