@@ -115,14 +115,17 @@ def make_lm_mc_loss(model, train: bool, mc_coef: float = 1.0, pad_id: int = 0):
     return loss_fn
 
 
-def make_lm_loss(model, train: bool, moe_aux_coef: float = 0.0):
+def make_lm_loss(model, train: bool, moe_aux_coef: float = 0.0,
+                 model_metrics: bool = False):
     """Next-token cross-entropy for causal LMs.
 
     batch = {"input_ids": [B, T] int, "labels": [B, T] int with -100 = ignore,
     optionally "token_type_ids": [B, T] int (PersonaChat speaker segments)}.
     Metrics: loss_sum / count (token-level) -> PPL = exp(loss_sum / count).
     `moe_aux_coef > 0` (MoE models) adds the Switch load-balancing aux sown
-    by MoEMLP, averaged over MoE layers.
+    by MoEMLP, averaged over MoE layers. `model_metrics` adds what the model
+    sows under its `metrics` collection (sums, and counts to divide them by:
+    the engine sums metrics over clients), each name summed over the layers.
     """
 
     def loss_fn(params, net_state, batch, rng):
@@ -132,7 +135,11 @@ def make_lm_loss(model, train: bool, moe_aux_coef: float = 0.0):
             rngs={"dropout": rng} if (train and rng is not None) else None,
         )
         moe_aux = jnp.float32(0.0)
-        if moe_aux_coef > 0:
+        sown = {}
+        if model_metrics:
+            logits, sown = model.apply(
+                {"params": params}, batch["input_ids"], mutable=["metrics"], **kwargs)
+        elif moe_aux_coef > 0:
             logits, inter = model.apply(
                 {"params": params}, batch["input_ids"],
                 mutable=["intermediates"], **kwargs,
@@ -162,6 +169,9 @@ def make_lm_loss(model, train: bool, moe_aux_coef: float = 0.0):
             # cohort-size-inflated — normalize via moe_aux_sum/moe_aux_count
             metrics["moe_aux_sum"] = moe_aux
             metrics["moe_aux_count"] = jnp.float32(1.0)
+        for path, value in jax.tree_util.tree_leaves_with_path(sown):
+            name = next(k.key for k in reversed(path) if hasattr(k, "key"))
+            metrics[name] = metrics.get(name, 0.0) + value
         return loss, {"net_state": net_state, "metrics": metrics}
 
     return loss_fn

@@ -20,7 +20,9 @@ mirrors its spans into the capture as TraceAnnotations. After `stop_trace`
 it reads the capture back (`summarize`): the device's operations, each
 attributed to the round-program phase its `jax.named_scope` names, as
 registry gauges and one stderr line — what a machine with no TensorBoard
-can show.
+can show. A second, separate reduction of the same capture goes by kind of
+model block (`BLOCK_SCOPES`, the scopes a model names inside `client_grad`):
+a program that names none publishes nothing there, and that is no failure.
 """
 
 from __future__ import annotations
@@ -45,6 +47,11 @@ MODULES_LINE = "XLA Modules"
 # the protobuf's own generated classes (`_xplane_pb2`).
 SCOPE_STAT = "tf_op"
 OTHER = "other"
+# The kinds of block a model may name inside its forward pass
+# (models/qwen3_next.py does): the second reduction's scopes. Backward
+# operations carry their block's name as transpose(jvp(<name>)).
+BLOCK_SCOPES = ("gdn", "gated_attn", "moe_route", "moe_experts", "moe_shared",
+                "lm_head")
 _WORD = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 
 
@@ -124,6 +131,7 @@ class ProfileWindow:
         # say so until this capture's own summary is published, and keep
         # saying so where it fails
         obreg.default().gauge("profile_traced_rounds").set(0)
+        obreg.default().gauge("profile_block_traced_rounds").set(0)
         try:
             import jax
 
@@ -180,13 +188,20 @@ class ProfileWindow:
             return
         try:
             t0 = time.perf_counter()
-            summary = summarize(load_device_planes(newest_capture(
-                self.log_dir)), self.phases)
+            planes = load_device_planes(newest_capture(self.log_dir))
+            summary = summarize(planes, self.phases)
             publish(summary, obreg.default())
             self._note(format_summary(summary, self.phases)
                        + f" ({time.perf_counter() - t0:.2f} s to read)")
         except Exception as e:  # noqa: BLE001 — LOUD no-op by contract
             self._note(f"no summary of the capture ({type(e).__name__}: {e})")
+            return
+        try:
+            blocks = summarize(planes, BLOCK_SCOPES)
+        except ValueError:
+            return  # the model names no block: nothing to publish
+        publish_blocks(blocks, obreg.default())
+        self._note("by block, " + format_summary(blocks, BLOCK_SCOPES))
 
 
 def newest_capture(log_dir: str) -> str:
@@ -334,6 +349,16 @@ def publish(summary: dict, reg) -> None:
         reg.gauge(f"profile_phase_device_ms_{phase}").set(ms)
     reg.gauge("profile_device_busy_ms").set(summary["device_busy_ms"])
     reg.gauge("profile_traced_rounds").set(summary["traced_rounds"])
+
+
+def publish_blocks(summary: dict, reg) -> None:
+    """The second reduction as gauges: profile_block_device_ms_<block>, ms per
+    traced round (what no block names is the phases' to account for), and
+    profile_block_traced_rounds."""
+    for block, ms in summary["phase_device_ms"].items():
+        if block != OTHER:
+            reg.gauge(f"profile_block_device_ms_{block}").set(ms)
+    reg.gauge("profile_block_traced_rounds").set(summary["traced_rounds"])
 
 
 def format_summary(summary: dict, phases) -> str:

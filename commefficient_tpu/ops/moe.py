@@ -22,6 +22,7 @@ Semantics (top-1, capacity factor c):
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Callable
 
@@ -100,3 +101,188 @@ def dense_oracle(x, router_w, expert_params, expert_fn):
     # same residual convention as moe_ffn: (1 - gate) of every token's mass
     # stays on x (no token is dropped here, so routed == gate)
     return (gate[:, None] * sel + (1.0 - gate)[:, None] * x.astype(jnp.float32)).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Top-k routing over the experts held here (one chip's share of an
+# expert-parallel deployment), no dropped token.
+#
+# The router keeps its published width: every token scores all E experts and
+# takes its k best with renormalised weights. This chip holds the experts
+# [first, first + count) and computes their part of the result; what absent
+# experts would have added is left out (no code stands in for other chips or
+# their exchange). The token-to-expert assignments are sorted by expert, held
+# ones first, and the held experts' matmuls run grouped over the sorted rows
+# (`jax.lax.ragged_dot`: on a TPU a tiled kernel), BLOCK_ROWS assignments at a
+# time, for as many blocks as assignments landed here: a loop whose trip count
+# is data, so the cost follows the assignments that land here and not E, and
+# no assignment can be dropped however unevenly the router spreads them.
+# ---------------------------------------------------------------------------
+
+BLOCK_ROWS = 1024  # at 2,048 tokens, top-10 of 512 and 16 held: 640 land here
+
+
+def _sequential_vmap(fn):
+    """`fn` as a `custom_vmap` whose batching rule takes the batch one element
+    at a time. The loop over blocks must stay a loop whose length is one
+    client's data (under a plain `vmap` it would run every client for the
+    longest), the TPU's ragged-dot kernel takes no batch dimension, and under
+    the engine's `vmap` over clients the weights' cotangents are batched while
+    the weights are not."""
+    wrapped = jax.custom_batching.custom_vmap(fn)
+
+    @wrapped.def_vmap
+    def rule(axis_size, in_batched, *args):
+        def one(i):
+            return wrapped(*jax.tree.map(lambda a, b: a[i] if b else a, args, tuple(in_batched)))
+
+        out = jax.lax.map(one, jnp.arange(axis_size))
+        return out, jax.tree.map(lambda _: True, out)
+
+    return wrapped
+
+
+def _grouped(x, w, group_sizes):
+    """x [M, K] with its rows sorted by group, w [G, K, N], group_sizes [G]
+    (sum <= M) -> [M, N]: row i times the matrix of its group. `ragged_dot`
+    leaves the rows past the last group undefined: they are zeroed."""
+    out = jax.lax.ragged_dot(x, w, group_sizes)
+    return jnp.where(jnp.arange(x.shape[0])[:, None] < group_sizes.sum(), out, 0.0)
+
+
+def _block_rows(rows, experts, weight, group_sizes):
+    """One block of sorted assignments through their experts:
+    weight * down(silu(gate x) * up x), row by row."""
+    h = jax.nn.silu(_grouped(rows, experts["gate"], group_sizes)) * _grouped(
+        rows, experts["up"], group_sizes)
+    return _grouped(h, experts["down"], group_sizes) * weight[:, None]
+
+
+def _blocks(token, weight, group_sizes, block_rows):
+    """(number of blocks that hold an assignment, b -> (tokens, weights, group
+    sizes) of block b). The groups' rows are contiguous from row 0, so a
+    block's share of each group is a clipped difference of offsets."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+
+    def block(b):
+        lo = b * block_rows
+        clip = lambda a: jnp.clip(a, lo, lo + block_rows)  # noqa: E731
+        return (jax.lax.dynamic_slice(token, (lo,), (block_rows,)),
+                jax.lax.dynamic_slice(weight, (lo,), (block_rows,)),
+                (clip(ends) - clip(starts)).astype(jnp.int32))
+
+    return (ends[-1] + block_rows - 1) // block_rows, block
+
+
+def _held_forward(x, experts, token, weight, group_sizes, block_rows):
+    count, block = _blocks(token, weight, group_sizes, block_rows)
+
+    def add_block(b, y):
+        tok, w, sizes = block(b)
+        return y.at[tok].add(_block_rows(x[tok], experts, w, sizes))
+
+    return jax.lax.fori_loop(0, count, add_block, jnp.zeros_like(x))
+
+
+def _held_backward(x, experts, token, weight, group_sizes, dy, block_rows):
+    """Cotangents of (x, experts, weight), block by block: each block's
+    forward is recomputed and transposed by JAX."""
+    count, block = _blocks(token, weight, group_sizes, block_rows)
+
+    def add_block(b, carry):
+        dx, dexperts, dweight = carry
+        tok, w, sizes = block(b)
+        _, transpose = jax.vjp(lambda r, e, w_: _block_rows(r, e, w_, sizes), x[tok], experts, w)
+        drows, de, dw = transpose(dy[tok])
+        # the transposed products leave rows past the last group undefined too
+        drows = jnp.where(jnp.arange(block_rows)[:, None] < sizes.sum(), drows, 0.0)
+        return (dx.at[tok].add(drows), jax.tree.map(jnp.add, dexperts, de),
+                jax.lax.dynamic_update_slice(dweight, dw, (b * block_rows,)))
+
+    init = (jnp.zeros_like(x), jax.tree.map(jnp.zeros_like, experts), jnp.zeros_like(weight))
+    return jax.lax.fori_loop(0, count, add_block, init)
+
+
+@functools.cache
+def _held_experts(block_rows: int):
+    """held(x [T, D], experts, token [M], weight [M], group_sizes [G]) ->
+    y [T, D]: y[token[i]] += weight[i] * E_group(i)(x[token[i]]) over the
+    assignments i that the groups cover (M a multiple of block_rows, sorted by
+    group). The gradient is written out so that forward and backward each are
+    one `_sequential_vmap` leaf with a data-dependent loop inside."""
+    forward = _sequential_vmap(functools.partial(_held_forward, block_rows=block_rows))
+    backward = _sequential_vmap(functools.partial(_held_backward, block_rows=block_rows))
+
+    @jax.custom_vjp
+    def held(x, experts, token, weight, group_sizes):
+        return forward(x, experts, token, weight, group_sizes)
+
+    def fwd(x, experts, token, weight, group_sizes):
+        return forward(x, experts, token, weight, group_sizes), (x, experts, token, weight, group_sizes)
+
+    def bwd(res, dy):
+        x, experts, token, weight, group_sizes = res
+        dx, dexperts, dweight = backward(x, experts, token, weight, group_sizes, dy)
+        return dx, dexperts, None, dweight, None
+
+    held.defvjp(fwd, bwd)
+    return held
+
+
+def topk_route(x, router_w, k: int):
+    """x [T, D], router_w [D, E] -> (experts [T, k] int32, weights [T, k]):
+    softmax over all E in float32, the k largest, weights renormalised to
+    sum 1. The one matmul runs at `highest` precision: at a TPU's default a
+    float32 matmul rounds its inputs to bfloat16, and which expert comes
+    10th and which 11th is a discrete outcome."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    top, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    return experts.astype(jnp.int32), top / top.sum(-1, keepdims=True)
+
+
+def topk_moe_ffn(x, router_w, expert_params, held: tuple[int, int], k: int,
+                 block_rows: int = BLOCK_ROWS):
+    """Top-k expert layer over tokens x [T, D], for the experts held here.
+
+    `expert_params` = {"gate": [G, D, F], "up": [G, D, F], "down": [G, F, D]}
+    are the G = held[1] experts with ids held[0] .. held[0] + G - 1 of the
+    E = router_w.shape[1] the router scores; E(x) = down(silu(gate x) * up x).
+    Returns (y [T, D], counts): y = sum over a token's chosen experts that
+    are held here of weight * E(x); counts = {"assignments": T * k,
+    "assignments_held", "expert_load_max"} as float32 scalars, and "experts",
+    the [T, k] choices themselves.
+    """
+    T, D = x.shape
+    first, G = held
+    with jax.named_scope("moe_route"):
+        experts, weights = topk_route(x, router_w, k)
+        local = experts.reshape(-1) - first
+        here = (local >= 0) & (local < G)
+        key = jnp.where(here, local, G)  # absent experts sort last
+        order = jnp.argsort(key, stable=True)
+        group_sizes = (key[:, None] == jnp.arange(G)[None, :]).sum(0).astype(jnp.int32)
+        pad = (-T * k) % block_rows
+        token = jnp.pad(order // k, (0, pad)).astype(jnp.int32)
+        # rows past the last group come out of the experts as zeros, whatever their weight
+        weight = jnp.pad(weights.reshape(-1).astype(x.dtype)[order], (0, pad))
+    with jax.named_scope("moe_experts"):
+        y = _held_experts(block_rows)(x, expert_params, token, weight, group_sizes)
+    counts = {"assignments": jnp.float32(T * k),
+              "assignments_held": group_sizes.sum().astype(jnp.float32),
+              "expert_load_max": group_sizes.max().astype(jnp.float32),
+              "experts": experts}
+    return y, counts
+
+
+def topk_dense_oracle(x, router_w, expert_params, held: tuple[int, int], k: int):
+    """Every held expert over ALL tokens, selected after: what topk_moe_ffn
+    must equal (O(G T D F); tests only)."""
+    first, G = held
+    experts, weights = topk_route(x, router_w, k)
+    ids = first + jnp.arange(G)
+    gate = (weights[:, :, None] * (experts[:, :, None] == ids[None, None, :])).sum(1)  # [T, G]
+    every = jax.vmap(lambda g, u, d: (jax.nn.silu(x @ g) * (x @ u)) @ d)(
+        expert_params["gate"], expert_params["up"], expert_params["down"])  # [G, T, D]
+    return jnp.einsum("tg,gtd->td", gate.astype(x.dtype), every)

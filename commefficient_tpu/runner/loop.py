@@ -531,6 +531,14 @@ def run_loop(
                 for k, v in m.items():
                     if isinstance(v, (int, float)):
                         totals[k] += v
+                if "moe_assignments" in m:
+                    # what an expert model's clients counted (losses.make_lm_loss)
+                    reg.counter("model_moe_assignments_total").inc(
+                        int(m["moe_assignments"]))
+                    reg.counter("model_moe_assignments_held_total").inc(
+                        int(m["moe_assignments_held"]))
+                    reg.gauge("model_moe_expert_load_max").set(
+                        m["moe_load_max_sum"] / max(m["moe_load_max_count"], 1))
         phase_hist["commit"].observe((time.perf_counter() - t_commit0) * 1e3)
         pending.clear()
         pending_rounds = 0

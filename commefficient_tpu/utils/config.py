@@ -581,6 +581,13 @@ def make_parser(task: str = "cv") -> argparse.ArgumentParser:
         p.add_argument("--dataset", default="personachat", choices=["personachat"])
         p.add_argument("--seq_len", type=int, default=256)
         p.add_argument("--model_size", default="small", choices=["tiny", "small"])
+        p.add_argument("--model_config", default="",
+                       help="a configuration file (JSON) whose `model` block "
+                            "names another architecture to train in GPT-2's "
+                            "place: model_type qwen3_next (Gated DeltaNet + "
+                            "gated attention + top-k experts, "
+                            "models/qwen3_next.py), built offline from that "
+                            "block's keys")
         p.add_argument("--init_from", default="",
                        help="HF GPT-2 checkpoint dir (config.json + "
                             "pytorch_model.bin) to fine-tune from; the wte is "

@@ -61,7 +61,31 @@ def build(args, fault_plan=None, retry_policy=None):
         mc_hard_negatives=args.mc_hard_negatives,
     )
     args.num_clients = train_set.num_clients
-    if args.init_from:
+    model_metrics = False
+    if args.model_config:
+        if (args.init_from or args.moe_experts > 0 or args.mc_coef > 0
+                or args.model_parallel > 1 or args.attn_impl != "dense"
+                or args.eval_f1 > 0 or args.dtype != "float32"):
+            raise SystemExit(
+                "--model_config builds its own float32 model: it goes with none "
+                "of --init_from, --moe_experts, --mc_coef, --model_parallel, "
+                "--attn_impl ring, --eval_f1, --dtype bfloat16")
+        import json
+
+        from commefficient_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
+
+        with open(args.model_config) as f:
+            cfg = Qwen3NextConfig.from_model_block(json.load(f)["model"])
+        if tok.vocab_size > cfg.vocab_size:
+            raise SystemExit(
+                f"the tokenizer's {tok.vocab_size} ids do not fit the "
+                f"configuration's vocabulary of {cfg.vocab_size}")
+        model = Qwen3NextLM(cfg)
+        ids0 = jnp.zeros((1, args.seq_len), dtype=jnp.int32)
+        params = model.init(jax.random.PRNGKey(args.seed), ids0, train=False)["params"]
+        model_metrics = True  # the expert layers' counters
+        init_note = f"  model_config={args.model_config}"
+    elif args.init_from:
         if args.moe_experts > 0:
             raise SystemExit(
                 "--moe_experts with --init_from is not supported: HF GPT-2 "
@@ -109,7 +133,8 @@ def build(args, fault_plan=None, retry_policy=None):
         params = model.init(jax.random.PRNGKey(args.seed), ids0, train=False)["params"]
         init_note = ""
     d = ravel_pytree(params)[0].size
-    print(f"model: GPT2({args.model_size})  d={d:,}  vocab={cfg.vocab_size}  "
+    named = "Qwen3Next" if args.model_config else f"GPT2({args.model_size})"
+    print(f"model: {named}  d={d:,}  vocab={cfg.vocab_size}  "
           f"clients={train_set.num_clients}  mode={args.mode}{init_note}", flush=True)
 
     if args.attn_impl == "ring" and args.seq_parallel <= 1:
@@ -150,7 +175,8 @@ def build(args, fault_plan=None, retry_policy=None):
         eval_loss = make_lm_mc_loss(model, False, args.mc_coef, tok.pad_id)
     else:
         aux = args.moe_aux_coef if args.moe_experts > 0 else 0.0
-        train_loss = make_lm_loss(model, train=True, moe_aux_coef=aux)
+        train_loss = make_lm_loss(model, train=True, moe_aux_coef=aux,
+                                  model_metrics=model_metrics)
         eval_loss = make_lm_loss(model, train=False, moe_aux_coef=aux)
     mode_cfg = mode_config_from_args(args, d)
     if mode_cfg.mode == "sketch":

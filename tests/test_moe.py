@@ -6,6 +6,7 @@ results."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from commefficient_tpu.ops import moe
@@ -147,3 +148,119 @@ def test_moe_checkpoint_roundtrip(tmp_path):
     got = np.asarray(ravel_pytree(session2.state["params"])[0])
     np.testing.assert_array_equal(got, want)
     assert session2.round == 2
+
+
+# ------------------------------------------------------------------
+# top-k routing over the experts held here (ops/moe.topk_moe_ffn)
+
+TK_T, TK_D, TK_F, TK_E, TK_K = 48, 16, 8, 12, 3
+
+
+def _topk_params(key, held_count):
+    ks = jax.random.split(key, 4)
+    experts = {"gate": 0.3 * jax.random.normal(ks[1], (held_count, TK_D, TK_F)),
+               "up": 0.3 * jax.random.normal(ks[2], (held_count, TK_D, TK_F)),
+               "down": 0.3 * jax.random.normal(ks[3], (held_count, TK_F, TK_D))}
+    return jax.random.normal(ks[0], (TK_D, TK_E)), experts
+
+
+@pytest.mark.parametrize("held, block_rows", [
+    ((0, 4), 16), ((5, 4), 1024), ((8, 4), 40), ((0, 12), 16), ((11, 1), 8)])
+def test_topk_moe_matches_dense_oracle_values_and_gradients(held, block_rows):
+    """block_rows 16 / 40 / 8: the T * k = 144 sorted assignments take several
+    blocks, the last one ragged, and groups straddle block edges."""
+    router, experts = _topk_params(jax.random.PRNGKey(0), held[1])
+    x = jax.random.normal(jax.random.PRNGKey(1), (TK_T, TK_D))
+    y, counts = moe.topk_moe_ffn(x, router, experts, held, TK_K, block_rows)
+    want = moe.topk_dense_oracle(x, router, experts, held, TK_K)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5, atol=1e-5)
+    chosen, _ = moe.topk_route(x, router, TK_K)
+    here = (chosen >= held[0]) & (chosen < held[0] + held[1])
+    assert float(counts["assignments"]) == TK_T * TK_K
+    assert float(counts["assignments_held"]) == int(here.sum())
+    assert float(counts["expert_load_max"]) == max(
+        int((chosen == e).sum()) for e in range(held[0], held[0] + held[1]))
+
+    def grads(fn):
+        return jax.grad(lambda x, r, e: (fn(x, r, e) ** 2).sum(), argnums=(0, 1, 2))(
+            x, router, experts)
+
+    got = grads(lambda x, r, e: moe.topk_moe_ffn(x, r, e, held, TK_K, block_rows)[0])
+    ref = grads(lambda x, r, e: moe.topk_dense_oracle(x, r, e, held, TK_K))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+
+def test_topk_weights_are_renormalised_over_the_chosen():
+    router, _ = _topk_params(jax.random.PRNGKey(2), 1)
+    x = jax.random.normal(jax.random.PRNGKey(3), (TK_T, TK_D))
+    chosen, weights = moe.topk_route(x, router, TK_K)
+    probs = jax.nn.softmax(x @ router, axis=-1)
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.0, rtol=1e-6)
+    np.testing.assert_array_equal(np.asarray(chosen), np.asarray(jax.lax.top_k(probs, TK_K)[1]))
+
+
+def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
+    """Section 4 of the model-configs guide: three chips hold experts 0-3, 4-7
+    and 8-11 of the same layer; their routed parts add up to what one chip
+    holding all twelve computes."""
+    router, all_experts = _topk_params(jax.random.PRNGKey(4), TK_E)
+    x = jax.random.normal(jax.random.PRNGKey(5), (TK_T, TK_D))
+    whole, counts = moe.topk_moe_ffn(x, router, all_experts, (0, TK_E), TK_K)
+    assert float(counts["assignments_held"]) == TK_T * TK_K
+    parts, landed = 0.0, 0.0
+    for first in (0, 4, 8):
+        share = jax.tree.map(lambda a: a[first: first + 4], all_experts)
+        y, c = moe.topk_moe_ffn(x, router, share, (first, 4), TK_K)
+        parts, landed = parts + y, landed + float(c["assignments_held"])
+    assert landed == TK_T * TK_K
+    np.testing.assert_allclose(np.asarray(parts), np.asarray(whole), rtol=1e-5, atol=1e-5)
+
+
+def test_no_token_is_dropped_when_every_assignment_lands_on_one_held_expert():
+    """The router sends every token to expert 6 (top-1): its group is all T
+    sorted rows, three whole blocks of 16 where an even share is 4 rows, and
+    every token gets its expert's output."""
+    _, experts = _topk_params(jax.random.PRNGKey(6), 4)
+    x = jax.random.normal(jax.random.PRNGKey(7), (TK_T, TK_D))
+    router = jnp.zeros((TK_D, TK_E)).at[:, 6].set(1e3 * jnp.sign(x.sum(0)))
+    x = jnp.abs(x) * jnp.sign(x.sum(0))  # x . router[:, 6] > 0 for every token
+    y, counts = moe.topk_moe_ffn(x, router, experts, (4, 4), 1, 16)
+    assert float(counts["assignments_held"]) == TK_T == float(counts["expert_load_max"])
+    one = jax.tree.map(lambda a: a[2], experts)
+    want = (jax.nn.silu(x @ one["gate"]) * (x @ one["up"])) @ one["down"]
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5, atol=1e-5)
+    assert float(jnp.abs(y).min(axis=1).max()) > 0  # no row passed through as zero
+
+
+def test_topk_moe_under_vmap_over_clients_inside_a_scan():
+    """engine._weighted_client_reduce with --client_chunk: per-client gradients
+    by vmap (weights unbatched, their cotangents batched) inside lax.scan; the
+    loops over blocks take the batch one client at a time, each for as many
+    blocks as that client's assignments fill."""
+    held, W, C = (5, 4), 6, 2
+    router, experts = _topk_params(jax.random.PRNGKey(8), held[1])
+    xs = jax.random.normal(jax.random.PRNGKey(9), (W, TK_T, TK_D))
+
+    def client_grad(x):
+        return jax.grad(lambda r, e: (moe.topk_moe_ffn(x, r, e, held, TK_K, 32)[0] ** 2).sum(),
+                        argnums=(0, 1))(router, experts)
+
+    def body(acc, xb):
+        g = jax.vmap(client_grad)(xb)
+        return jax.tree.map(lambda a, b: a + b.sum(0), acc, g), None
+
+    init = jax.tree.map(jnp.zeros_like, (router, experts))
+    got, _ = jax.jit(lambda xs: jax.lax.scan(body, init, xs))(xs.reshape(W // C, C, TK_T, TK_D))
+    want = init
+    for x in xs:
+        want = jax.tree.map(jnp.add, want, client_grad(x))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+def test_grouped_rows_past_the_last_group_are_zero():
+    x = jnp.ones((10, 4))
+    w = jnp.stack([jnp.full((4, 3), 1.0), jnp.full((4, 3), 2.0)])
+    out = moe._grouped(x, w, jnp.asarray([3, 4], jnp.int32))
+    np.testing.assert_array_equal(np.asarray(out[:, 0]), [4, 4, 4, 8, 8, 8, 8, 0, 0, 0])

@@ -119,3 +119,47 @@ def test_sharded_round_partitions_kernels_on_four_chips(topo, monkeypatch):
     assert hlo.count("tpu_custom_call") >= 2
     # the ordered cross-device table merge
     assert "all-gather" in hlo
+
+
+# --- Qwen3-Next's two new operations at the published widths (PR 27) ---
+
+
+def test_held_experts_compile_to_the_ragged_dot_kernel_one_client_at_a_time(one_chip):
+    """vmap over 2 clients of the gradient of the expert layer (2,048 tokens of
+    2,048, top-10 of 512, 16 experts of width 512 held): the TPU's ragged-dot
+    kernel takes no batch dimension, so every grouped product must have
+    reached it unbatched (ops/moe._sequential_vmap) and none as a dense dot
+    over all the experts."""
+    from commefficient_tpu.ops import moe
+
+    T, D, F, E, G, k = 2048, 2048, 512, 512, 16, 10
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    experts = {"gate": f32(G, D, F), "up": f32(G, D, F), "down": f32(G, F, D)}
+
+    def client_grad(x, router, experts):
+        return jax.grad(lambda r, e: moe.topk_moe_ffn(x, r, e, (0, G), k)[0].sum(),
+                        argnums=(0, 1))(router, experts)
+
+    text = jax.jit(jax.vmap(client_grad, in_axes=(0, None, None))).lower(
+        f32(2, T, D), f32(D, E), experts).compile().as_text()
+    # each grouped product sits once in the body of the loop over the clients:
+    # the experts' three products, the rows' cotangents and the weights'
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and line.lstrip().startswith("%ragged-dot-none")]
+    assert len(calls) >= 6, len(calls)
+    assert not any(f"f32[2,{T * k}," in line for line in calls)  # no batch dimension
+    assert f"f32[{G},{T * k},{D}]" not in text  # the dense fallback's expanded rows
+
+
+def test_chunked_delta_rule_compiles_for_v5e_at_the_published_heads(one_chip):
+    """One client's sequence of 2,048 tokens, 32 value heads of 128 x 128,
+    chunk 64, forward and backward: the triangular solve and the scan over 32
+    chunks as the TPU compiler takes them."""
+    from commefficient_tpu.ops import gated_delta
+
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    q = f32(1, 2048, 32, 128)
+    grad = jax.grad(lambda *a: gated_delta.chunk_gated_delta_rule(*a).sum(), argnums=(0, 1, 2, 3, 4))
+    compiled = jax.jit(grad).lower(q, q, q, f32(1, 2048, 32), f32(1, 2048, 32)).compile()
+    need = compiled.memory_analysis().temp_size_in_bytes
+    assert need < 2 * 1024 ** 3, need
