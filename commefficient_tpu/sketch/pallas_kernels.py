@@ -5,28 +5,37 @@ These are the "accumulate / query" kernel pair SURVEY.md §3.5 / §7.1 targets
 gather programs; here the rotation hash family makes both ops *structured*,
 and these kernels express that structure directly on the TPU vector unit):
 
-- Every roll of a c-sized slab is two sublane rotates + two lane rotates + a
-  select (`_flat_roll`, built on `pltpu.roll` → Mosaic `tpu.dynamic_rotate`)
-  over the slab viewed as [c/128, 128] — no scatter/gather at any granularity
-  and no DMA at unaligned offsets.
+- Both kernel bodies walk the slab, viewed as [c/128, 128], in tiles of a few
+  vector registers, and everything a row needs of a tile happens between one
+  load and one store. The roll of a slab becomes a WINDOW: a tile of the
+  rolled slab is two loads of the source at a dynamic sublane offset, one row
+  apart, a select on the lane and one lane rotate within the register
+  (`_rolled_tile`; `pltpu.roll` → Mosaic `tpu.dynamic_rotate`). The source is
+  kept with its first tile repeated after its end (`_extend`), so no window
+  straddles the wrap — no scatter/gather at any granularity, no rotate across
+  the slab, and no value of the slab's size.
 - Bucket signs are recomputed inside the kernel from the integer seed with
-  the same murmur mixer as `hashing.py` (uint32 elementwise VPU ops), so no
-  [r, d] hash tensor ever exists in HBM.
+  the same murmur mixer as `hashing.py` (uint32 elementwise VPU ops) on the
+  tile where they are used, so no [r, d] hash tensor ever exists in HBM and
+  no [c] one in VMEM. The mixer's ~15 operations a register and row are what
+  the tile loop spends most of its vector slots on.
 - The slab axis is the pipelined grid dimension: Pallas streams each slab of
   the input HBM→VMEM exactly once while the whole [r, c] table stays resident
   in VMEM, every slab feeding all r rows — HBM traffic is d reads + r·c
-  writes, the algorithm's minimum.
+  writes (accumulate) or r·c reads + d writes (query), the algorithm's
+  minimum.
 - The median-of-rows query uses an odd-even-transposition network of
   `minimum`/`maximum` (r is tiny and static) — `sort` has no Mosaic lowering
   (the round-2 MosaicError), a comparator network lowers to plain VPU ops.
 
 Layout requirements for this fast path (`unsupported_reason()`):
 `c % 1024 == 0` (so the [c/128, 128] slab view is fully (8,128)-tiled for
-f32) and the resident working set — the whole [r, c] table plus a couple of
-slabs — must fit in VMEM.  Anything else, and any non-TPU backend unless
-`interpret=True`, takes the pure-JAX oracle in `csvec.py`, which remains the
-correctness reference (`tests/test_pallas.py` pins the two together in
-interpreter mode; `chip_smoke.py` compares them on the chip).
+f32) and the resident working set — the whole [r, c] table, twice, plus three
+slabs (`_worst_case_vmem`) — must fit in VMEM.  Anything else, and any
+non-TPU backend unless `interpret=True`, takes the pure-JAX oracle in
+`csvec.py`, which remains the correctness reference (`tests/test_pallas.py`
+pins the two together bit for bit in interpreter mode; `chip_smoke.py`
+compares them on the chip).
 
 `probe()` compiles and runs both kernels once per (c, r) layout at first
 use. On a TPU backend with a supported layout a failure RAISES with the
@@ -57,26 +66,28 @@ from .hashing import row_keys, sign_hash, slab_shifts
 # resident-VMEM budgets. The default *scoped* vmem limit is 16 MiB, so every
 # pallas_call raises it explicitly via CompilerParams — to 48 MiB when the
 # spec's worst-case footprint fits, else to 96 MiB (a v5e core has 128 MiB of
-# VMEM; at GPT-2 dims c=2^20 r=5 the accumulate kernel needs a little over
-# 48 MiB scoped). Checked against the installed compiler for a described v5e
-# (PR 21): it takes any vmem_limit_bytes as given and refuses only a kernel
-# whose real need exceeds it; every layout on the 96 MiB edge of the model
-# below (c=2^20 r=9, c=2^21 r=3) compiles under the 96 MiB limit, and the
-# 128 MiB-model layouts (c=2^21 r=5, c=2^22 r=1) are refused at 96 MiB — the
-# query at c=2^21 r=5 at 120 MiB too. So 96 MiB stays the screen.
+# VMEM). Checked against the installed compiler for a described v5e (PR 21):
+# it takes any vmem_limit_bytes as given and refuses only a kernel whose real
+# need exceeds it. The two tiers are the ones the whole-slab kernels had, so
+# the flagship layout (c=2^19 r=5) keeps 48 MiB and the language models'
+# (c=2^20 r=5) 96 MiB: the limit is part of the round program's compile.
 _VMEM_SMALL_BYTES = 48 * 1024 * 1024
 _VMEM_LARGE_BYTES = 96 * 1024 * 1024
+_VMEM_SLACK_BYTES = 1024 * 1024  # the tiles' halo rows and Mosaic's own scratch
 
 
 def _worst_case_vmem(c: int, r: int) -> int:
-    """Upper-bound scoped-VMEM model for BOTH kernels at a (c, r) layout.
+    """Upper-bound scoped-VMEM model for BOTH kernels at a (c, r) layout: what
+    Pallas allocates when XLA keeps none of the operands in VMEM for it.
 
-    accumulate: [r, c] table resident + ~7 slab-sized buffers (double-buffered
-    input slab, roll temporaries a/b, sign/iota intermediates) ≈ (r+7)·c·4 —
-    at c=2^20 r=5 this gives 48 MiB, matching Mosaic's measured 48.21 MiB.
-    query: table resident + r live median operands + out/temp slabs
-    ≈ (2r+6)·c·4, the larger of the two for r ≥ 1."""
-    return (2 * r + 6) * c * 4
+    accumulate: the [r, c] output block (two buffers) + the double-buffered
+    input slab + the extended slab (`_extend`) = (2r + 3)·c·4.
+    query: the extended table + the double-buffered output slab, and the
+    table operand itself where it counts = (2r + 2)·c·4.
+    No slab-sized temporary exists in either body. Measured (PR 28, compiled
+    for a described v5e at c=2^20 r=5 d=124,443,648, where XLA holds the
+    table in VMEM itself): accumulate 12.06 MiB, query 28.25 MiB."""
+    return (2 * r + 3) * c * 4 + _VMEM_SLACK_BYTES
 
 
 def _compiler_params(c: int, r: int):
@@ -94,7 +105,7 @@ def unsupported_reason(spec) -> str | None:
         return f"num_cols {spec.c} % 1024 != 0"
     need = _worst_case_vmem(spec.c, spec.r)
     if need > _VMEM_LARGE_BYTES:
-        return (f"VMEM model (2r+6)*c*4 = {need >> 20} MiB > "
+        return (f"VMEM model (2r+3)*c*4 = {need >> 20} MiB > "
                 f"{_VMEM_LARGE_BYTES >> 20} MiB at c={spec.c} r={spec.r}")
     return None
 
@@ -132,23 +143,56 @@ def _replicated(fn, x):
                          check_vma=False)(x)
 
 
-def _flat_roll(x: jnp.ndarray, shift: jnp.ndarray) -> jnp.ndarray:
-    """Roll-right by `shift` (traced scalar in [0, c)) of the flat [c] vector
-    stored as x[c//128, 128] (row-major: flat p = 128*sublane + lane).
+# sublane heights a tile may take: the largest that divides c/128 (one
+# algorithm at every size; the height only sets how much a loop step holds in
+# registers). A slab of fewer sublanes than the ladder's foot, or of a number
+# none divides (the interpreter tests' c = 256), is one tile.
+_TILE_LADDER = (64, 32, 16, 8)
 
-    Flat roll by s = 128*sq + sl decomposes into sublane rolls and a lane
-    roll with borrow: out lane l takes sublane-roll sq for l >= sl and
-    sq + 1 (one extra carry row) for l < sl, both lane-rolled by sl.
-    """
-    shift = shift.astype(jnp.int32)
-    sq = shift // 128
-    sl = shift % 128
-    a = pltpu.roll(x, sq, 0)
-    b = pltpu.roll(x, sq + 1, 0)
-    a = pltpu.roll(a, sl, 1)
-    b = pltpu.roll(b, sl, 1)
-    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    return jnp.where(lane >= sl, a, b)
+
+def _tile_height(cq: int) -> int:
+    return next((t for t in _TILE_LADDER if cq % t == 0), cq)
+
+
+def _tile_rows(t, tile: int):
+    """The sublanes of tile `t` as a ref index (an aligned dynamic slice)."""
+    return pl.ds(pl.multiple_of(t * tile, tile), tile)
+
+
+def _extend(src, ext, *, cq: int, tile: int) -> None:
+    """ext[i] = src[i mod cq] for i in [0, cq + tile): the source slab
+    followed by its own first tile, so that no window of `_rolled_tile` ever
+    straddles the slab's end. `src` is a [cq, 128] ref view, `ext` the
+    [cq + tile, 128] scratch view. One load and one store a register, once a
+    slab — the only pass over the slab that is not the tile loop itself."""
+
+    def body(t, carry):
+        rows = _tile_rows(t, tile)
+        ext[rows, :] = src[rows, :]
+        return carry
+
+    jax.lax.fori_loop(0, cq // tile, body, 0)
+    ext[pl.ds(cq, tile), :] = src[pl.ds(0, tile), :]
+
+
+def _rolled_tile(ext, row0, shift, *, cq: int, tile: int) -> jnp.ndarray:
+    """Rows [row0, row0 + tile) of the flat roll-right by `shift` (traced
+    scalar in [0, c)) of a [c] vector stored [cq, 128] (row-major: flat
+    p = 128*sublane + lane), read from its extension `ext` (`_extend`).
+
+    out[p] = in[(p - shift) mod c]. With shift = 128*sq + sl, output row i
+    takes lanes >= sl from source row i - sq and the lanes below sl from row
+    i - sq - 1 (the lane borrow), both lane-rotated by sl. So the tile is two
+    loads of the window at a dynamic sublane offset, one row apart, a select
+    on the source lane and one lane rotate — the wrap is in `ext`, and the
+    only rotate left is within a register."""
+    sl = shift & 127
+    first = row0 + (cq - 1 - (shift >> 7))  # row0 - sq - 1, kept >= 0
+    first = jnp.where(first >= cq, first - cq, first)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tile, 128), 1)
+    window = jnp.where(lane < 128 - sl,
+                       ext[pl.ds(first + 1, tile), :], ext[pl.ds(first, tile), :])
+    return pltpu.roll(window, sl, 1)
 
 
 def _lower_median(vals: list[jnp.ndarray]) -> jnp.ndarray:
@@ -165,41 +209,57 @@ def _lower_median(vals: list[jnp.ndarray]) -> jnp.ndarray:
     return v[(n - 1) // 2]
 
 
-def _coord_iota(slab, c: int) -> jnp.ndarray:
-    """Global coordinate index of each element of slab `slab`'s [c/128, 128]
-    view (flat order: 128*sublane + lane)."""
-    cq = c // 128
-    sub = jax.lax.broadcasted_iota(jnp.int32, (cq, 128), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (cq, 128), 1)
-    return slab * c + sub * 128 + lane
+def _tile_positions(row0, tile: int) -> jnp.ndarray:
+    """Flat position within the slab of each element of the tile that starts
+    at sublane `row0` (flat order: 128*sublane + lane)."""
+    sub = jax.lax.broadcasted_iota(jnp.int32, (tile, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tile, 128), 1)
+    return (row0 + sub) * 128 + lane
 
 
 # --------------------------------------------------------------- accumulate
 
 
-def _accumulate_kernel(shifts_ref, keys_ref, v_ref, out_ref, *, c: int, r: int):
+def _accumulate_kernel(shifts_ref, keys_ref, v_ref, out_ref, ext_ref, *, c: int, r: int):
     """Grid (S,): the whole [r, c] table stays VMEM-resident while the slab
     axis streams, and every input slab is read from HBM exactly ONCE,
     contributing sign ⊙ v rolled by shifts[j, b] to all r rows.
 
-    (The previous (r, S) grid held one row resident and re-streamed the full
-    input per row — r× the HBM input traffic. At r=5 those re-reads dominated
-    the kernel's measured ~43% of the bandwidth roofline; this layout's
-    traffic is d reads + r·c writes, the minimum the algorithm admits. The
-    coordinate iota and the input slab load are shared across rows; only the
-    sign hash and the roll are inherently per-row, since each row has its own
-    key and shift.)"""
+    The slab is walked in register-sized tiles of the TABLE: for a tile and a
+    row, the window of the input that the roll brings there is loaded
+    (`_rolled_tile`), the sign of each SOURCE coordinate is computed from the
+    output position (p - shift, wrapped), and the table tile takes one
+    read-modify-write. Nothing of the slab's size is ever a value."""
     b = pl.program_id(0)
-    idx = _coord_iota(b, c)
-    v = v_ref[0]
+    cq = c // 128
+    tile = _tile_height(cq)
 
     @pl.when(b == 0)
     def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        def zero(t, carry):
+            rows = _tile_rows(t, tile)
+            for j in range(r):
+                out_ref[j, rows, :] = jnp.zeros((tile, 128), out_ref.dtype)
+            return carry
 
-    for j in range(r):  # r is tiny and static
-        signed = sign_hash(idx, keys_ref[j], dtype=out_ref.dtype) * v
-        out_ref[j] += _flat_roll(signed, shifts_ref[j, b])
+        jax.lax.fori_loop(0, cq // tile, zero, 0)
+
+    _extend(v_ref.at[0], ext_ref, cq=cq, tile=tile)
+    shifts = [shifts_ref[j, b] for j in range(r)]  # r is tiny and static
+    keys = [keys_ref[j] for j in range(r)]
+
+    def body(t, carry):
+        row0 = pl.multiple_of(t * tile, tile)
+        pos = _tile_positions(row0, tile)
+        for j in range(r):
+            src = pos - shifts[j]
+            idx = b * c + jnp.where(src < 0, src + c, src)
+            vals = _rolled_tile(ext_ref, row0, shifts[j], cq=cq, tile=tile)
+            out_ref[j, pl.ds(row0, tile), :] += (
+                sign_hash(idx, keys[j], dtype=out_ref.dtype) * vals)
+        return carry
+
+    jax.lax.fori_loop(0, cq // tile, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("d", "c", "r", "seed", "interpret"))
@@ -215,6 +275,7 @@ def _accumulate_call(v, *, d, c, r, seed, interpret):
         grid=(num_slabs,),
         in_specs=[pl.BlockSpec((1, cq, 128), lambda b, *_: (b, 0, 0))],
         out_specs=pl.BlockSpec((r, cq, 128), lambda b, *_: (0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((cq + _tile_height(cq), 128), v.dtype)],
     )
 
     table = pl.pallas_call(
@@ -256,19 +317,35 @@ def sketch_vec(spec, v: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
 # -------------------------------------------------------------------- query
 
 
-def _query_kernel(shifts_ref, keys_ref, tab_ref, out_ref, *, c: int, r: int):
-    """Grid (S,): the whole [r, c] table stays resident in VMEM; slab s's
-    estimates are the lower median over rows of sign ⊙ (row unrolled by
-    shifts[j, s])."""
+def _query_kernel(shifts_ref, keys_ref, tab_ref, out_ref, ext_ref, *, c: int, r: int):
+    """Grid (S,): the whole [r, c] table stays resident in VMEM, extended row
+    by row at the first step; slab s's estimates are the lower median over
+    rows of sign ⊙ (row unrolled by shifts[j, s]), tile by tile: r windows of
+    the table, the sign of the tile's own coordinates, the median network in
+    registers, one store."""
     s = pl.program_id(0)
-    idx = _coord_iota(s, c)
-    ests = []
-    for j in range(r):  # r is tiny and static
-        # roll-left by shift == roll-right by (c - shift) mod c
-        inv = jax.lax.rem(c - shifts_ref[j, s], c)
-        row = _flat_roll(tab_ref[j], inv)
-        ests.append(sign_hash(idx, keys_ref[j], dtype=out_ref.dtype) * row)
-    out_ref[0] = _lower_median(ests)
+    cq = c // 128
+    tile = _tile_height(cq)
+
+    @pl.when(s == 0)
+    def _():
+        for j in range(r):  # r is tiny and static
+            _extend(tab_ref.at[j], ext_ref.at[j], cq=cq, tile=tile)
+
+    # roll-left by shift == roll-right by (c - shift) mod c
+    invs = [jax.lax.rem(c - shifts_ref[j, s], c) for j in range(r)]
+    keys = [keys_ref[j] for j in range(r)]
+
+    def body(t, carry):
+        row0 = pl.multiple_of(t * tile, tile)
+        idx = s * c + _tile_positions(row0, tile)
+        out_ref[0, pl.ds(row0, tile), :] = _lower_median([
+            sign_hash(idx, keys[j], dtype=out_ref.dtype)
+            * _rolled_tile(ext_ref.at[j], row0, invs[j], cq=cq, tile=tile)
+            for j in range(r)])
+        return carry
+
+    jax.lax.fori_loop(0, cq // tile, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("d", "c", "r", "seed", "interpret"))
@@ -282,8 +359,9 @@ def _query_call(table, *, d, c, r, seed, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(num_slabs,),
-        in_specs=[pl.BlockSpec((r, cq, 128), lambda s, *_: (0, 0, 0))],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((1, cq, 128), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((r, cq + _tile_height(cq), 128), table.dtype)],
     )
 
     est = pl.pallas_call(

@@ -29,16 +29,23 @@ def test_supported_layouts():
 
 
 def test_vmem_budget_selection():
-    """Flagship dims keep the 48 MiB scoped limit (compile-cache stability);
-    GPT-2 dims (c=2^20 r=5, whose accumulate kernel measures 48.21 MiB —
-    the round-5 phase-E OOM) get the 96 MiB limit; the model stays an upper
-    bound on Mosaic's measured footprint at the known calibration point."""
+    """Flagship dims keep the 48 MiB scoped limit and the language models'
+    (c=2^20 r=5) the 96 MiB one — the tiers the whole-slab kernels had, so the
+    round programs' compile options do not move. The model stays an upper
+    bound on Mosaic's measured need at the calibration point: compiled for a
+    described v5e at c=2^20 r=5 d=124,443,648 (PR 28), accumulate needs
+    12.06 MiB and query 28.25 MiB with the table held in VMEM by XLA; the
+    table's own buffers (two of 20 MiB) are the model's, not Mosaic's."""
     small = pk._compiler_params(524_288, 5).vmem_limit_bytes
     large = pk._compiler_params(1_048_576, 5).vmem_limit_bytes
     assert small == pk._VMEM_SMALL_BYTES
     assert large == pk._VMEM_LARGE_BYTES
-    # calibration: measured 48.21 MiB at c=2^20 r=5 must fit under the model
-    assert pk._worst_case_vmem(1_048_576, 5) >= int(48.21 * 1024 * 1024)
+    table = 5 * 1_048_576 * 4
+    assert pk._worst_case_vmem(1_048_576, 5) >= int(12.06 * 2**20) + 2 * table
+    assert pk._worst_case_vmem(1_048_576, 5) >= int(28.25 * 2**20) + table
+    # every layout on the old model's 96 MiB edge is still taken
+    for c, r in ((1_572_864, 5), (1_048_576, 9), (2_097_152, 3)):
+        assert pk.supported(CSVecSpec(d=10 * c, c=c, r=r, family="rotation"))
 
 
 def test_accumulate_matches_oracle():
@@ -87,6 +94,102 @@ def test_even_rows_lower_median():
         rtol=1e-6,
         atol=1e-6,
     )
+
+
+def _edge_shifts(c: int, turn: int):
+    """A `slab_shifts` that plants the window's edge cases, [r, S] in order
+    from `turn`: no shift, a shift under one sublane (s // 128 == 0), whole
+    sublanes (s % 128 == 0), c - 1, a window that crosses the slab's end by
+    less than a sublane, and a sublane short of c with a lane remainder."""
+    cq = c // 128
+    edges = [0, 5, 128 * max(1, cq // 2), c - 1, c - 125, (cq - 1) * 128 + 64, 127, 128]
+
+    def planted(seed, num_rows, num_slabs, num_cols):
+        assert num_cols == c
+        flat = [edges[(turn + i) % len(edges)] % c for i in range(num_rows * num_slabs)]
+        return jnp.asarray(flat, jnp.int32).reshape(num_rows, num_slabs)
+
+    return planted
+
+
+def _assert_bit_equal_to_oracle(spec, key):
+    v = _v(key, spec.d)
+    want_t = csvec._sketch_vec_rotation(spec, v)
+    got_t = pk.sketch_vec(spec, v, interpret=True)
+    assert np.array_equal(np.asarray(got_t), np.asarray(want_t))
+    got_q = pk.query_all(spec, want_t, interpret=True)
+    assert np.array_equal(np.asarray(got_q), np.asarray(csvec._query_all_rotation(spec, want_t)))
+
+
+# c/128 -> its tile: the whole slab as one tile, every rung of the tile ladder,
+# and 8 x 17 (17 tiles: no power of two)
+_TILE_OF = {2: 2, 8: 8, 48: 16, 96: 32, 64: 64, 136: 8}
+_ROWS = (1, 4, 5)
+# d: under c, an exact multiple of c, a padded last slab
+_D_OF_C = {"one_slab": lambda c: c - 37, "exact": lambda c: 3 * c, "padded": lambda c: 2 * c + c // 3}
+
+
+@pytest.mark.parametrize("d_kind", list(_D_OF_C))
+@pytest.mark.parametrize("r", _ROWS)
+@pytest.mark.parametrize("cq", list(_TILE_OF))
+def test_kernels_equal_oracle_bit_for_bit(monkeypatch, cq, r, d_kind):
+    """The tiled kernels return the oracle's BITS (sign times value is exact,
+    the roll is a permutation, each table cell takes one addend a slab in slab
+    order, the median is min/max), at every tile height and at the window's
+    edge cases, which a planted `slab_shifts` brings on in both programs."""
+    c = 128 * cq
+    turn = list(_TILE_OF).index(cq) + 3 * _ROWS.index(r) + list(_D_OF_C).index(d_kind)
+    planted = _edge_shifts(c, turn)
+    monkeypatch.setattr(pk, "slab_shifts", planted)
+    monkeypatch.setattr(csvec, "slab_shifts", planted)
+    # the seed is in the jit cache's key and `slab_shifts` is not: one seed a case
+    spec = CSVecSpec(d=_D_OF_C[d_kind](c), c=c, r=r, seed=1000 + turn + 100 * cq,
+                     family="rotation")
+    assert pk._tile_height(cq) == _TILE_OF[cq]
+    _assert_bit_equal_to_oracle(spec, key=turn)
+
+
+@pytest.mark.parametrize("cq", list(_TILE_OF))
+def test_kernels_equal_oracle_bit_for_bit_at_hashed_shifts(cq):
+    """The same pin at the shifts the seed really gives (several slabs)."""
+    spec = CSVecSpec(d=5 * 128 * cq + 11, c=128 * cq, r=5, seed=29 + cq, family="rotation")
+    _assert_bit_equal_to_oracle(spec, key=cq)
+
+
+def _values_in(jaxpr):
+    """Shapes of every non-ref value a (closed) jaxpr computes, its nested
+    jaxprs (loops, conditionals) included."""
+    from jax._src import core as jcore
+    from jax._src.state.types import AbstractRef
+
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            if not isinstance(var.aval, AbstractRef) and hasattr(var.aval, "shape"):
+                yield eqn.primitive.name, tuple(var.aval.shape)
+        for sub in jcore.jaxprs_in_params(eqn.params):
+            yield from _values_in(sub)
+
+
+@pytest.mark.parametrize("kernel", ("accumulate", "query"))
+def test_no_slab_sized_value_in_kernel_body(kernel):
+    """Structural guard at the language models' layout (tracing only, nothing
+    runs): no value inside the kernel is a slab [c/128, 128] or anything
+    larger than a tile. A whole-slab expression — what made both kernels
+    20-odd VMEM passes a row before PR 28 — fails here."""
+    d, c, r = 124_443_648, 1_048_576, 5
+    cq = c // 128
+    call, shape = {"accumulate": (pk._accumulate_call, (d,)), "query": (pk._query_call, (r, c))}[kernel]
+    closed = jax.make_jaxpr(lambda x: call.__wrapped__(
+        x, d=d, c=c, r=r, seed=42, interpret=False))(jax.ShapeDtypeStruct(shape, jnp.float32))
+    (eqn,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    values = list(_values_in(eqn.params["jaxpr"]))
+    assert values
+    # the tallest tile of the ladder and a register of halo
+    most = (64 + 8) * 128
+    for name, shape in values:
+        assert shape[-2:] != (cq, 128), (name, shape)
+        assert int(np.prod(shape, dtype=np.int64)) <= most, (name, shape)
 
 
 def test_probe_failure_raises_on_tpu_backend(monkeypatch):

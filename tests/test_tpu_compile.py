@@ -49,9 +49,12 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-# (d, c, r): the probe's layout, and the flagship's (ResNet-9, cv_train's
-# --num_cols 524288 --num_rows 5 — what chip_smoke.py trains with)
-LAYOUTS = {"probe": (2560, 1024, 3), "flagship": (6_573_130, 524_288, 5)}
+# (d, c, r): the probe's layout, the flagship's (ResNet-9, cv_train's
+# --num_cols 524288 --num_rows 5 — what chip_smoke.py trains with) and the
+# language models' (GPT-2 small's d under 5 x 1,048,576: 119 slabs of 8,192
+# sublanes, the layout the benchmark's claimed cells run)
+LAYOUTS = {"probe": (2560, 1024, 3), "flagship": (6_573_130, 524_288, 5),
+           "gpt2": (124_443_648, 1_048_576, 5)}
 
 
 @pytest.mark.parametrize("name", list(LAYOUTS))
