@@ -49,7 +49,7 @@ import jax.numpy as jnp  # noqa: E402
 ROUNDS, EVAL_EVERY = 8, 4
 SHARDED_ROUNDS = 4
 SYNC_N = 2048  # side of the sync check's matmul chain
-# the flagship FetchSGD configuration (bench.py's flagship layout; README
+# the flagship FetchSGD configuration (benchmark/configs/'s layout; README
 # §Usage): 64 of 512 clients a round, 8 images each, r x c = 5 x 2^19, k=50k
 FLAGSHIP = [
     "--dataset", "cifar10", "--mode", "sketch",

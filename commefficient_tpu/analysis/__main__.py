@@ -44,7 +44,7 @@ def _staged_files() -> list[str] | None:
 def _lintable(rel: str) -> bool:
     return rel.endswith(".py") and (
         rel.startswith("commefficient_tpu/")
-        or rel in ("cv_train.py", "gpt2_train.py", "bench.py")
+        or rel in ("cv_train.py", "gpt2_train.py", "chip_smoke.py")
     )
 
 

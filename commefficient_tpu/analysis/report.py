@@ -1,5 +1,5 @@
-"""graftlint output: human text and `--json` (CI / archival next to the
-bench JSONs)."""
+"""graftlint output: human text and `--json` (CI / archival at the repo
+root)."""
 
 from __future__ import annotations
 

@@ -157,6 +157,11 @@ class FederatedSession:
         # being the default whenever more than one device is visible);
         # > 1 without a mesh runs the SAME shard-structured program on one
         # device (the bit-parity reference the CPU-mesh tests pin against).
+        if split_compile:
+            # stub: benchmark/builders/common.py still passes the keyword
+            raise ValueError(
+                "split_compile was removed in PR 29: the fused round "
+                "compiles and runs on the chip; drop the flag")
         if on_nonfinite not in ("off", "skip", "halt"):
             raise ValueError(
                 f"on_nonfinite must be 'off', 'skip', or 'halt', got "
@@ -211,13 +216,6 @@ class FederatedSession:
         if health_every < 0:
             raise ValueError(
                 f"health_every must be >= 0, got {health_every}")
-        if (health_every or ledger_fingerprint) and split_compile:
-            raise ValueError(
-                "health_every / ledger fingerprints are fused-paths-only "
-                "(the split program boundary does not thread the round "
-                "metrics the estimators ride); drop --split_compile or the "
-                "obs flag"
-            )
         self._health_every = max(health_every, 1)
         # obs sinks, attached by the CLIs (or tests) after construction:
         # commit_rounds hands every committed round to them in order —
@@ -226,12 +224,6 @@ class FederatedSession:
         self.health_monitor = None
         self.slo = None
         self.ledger = None
-        if wire_payloads and split_compile:
-            raise ValueError(
-                "wire_payloads IS a two-program round (client tables + "
-                "table merge); --split_compile is redundant and would pick "
-                "a different program pair — drop one of the two"
-            )
         # The per-client-TABLE round shape (engine.make_payload_round_steps)
         # serves three masters: a real wire (--serve_payload sketch), a
         # robust merge policy (order statistics need individual client
@@ -268,12 +260,6 @@ class FederatedSession:
                     f"{why} need(s) the per-client-table round "
                     "(sketch_path='ravel'); layerwise accumulation has no "
                     "per-client wire to screen or attack"
-                )
-            if split_compile:
-                raise ValueError(
-                    f"{why} route(s) the round through the table-round "
-                    "program pair; --split_compile would pick a different "
-                    "pair — drop one of the two"
                 )
         # cohort-degradation re-queue: client ids whose batch load failed (or
         # were fault-dropped) wait here and displace sampled ids in a later
@@ -423,10 +409,6 @@ class FederatedSession:
 
         self._train_loss_fn = train_loss_fn
         self._multi = None  # lazy: jitted by the first run_rounds block
-        # split sessions exist to keep Mosaic OUT of the big fused module;
-        # a multi-round scan over the fused step would reintroduce it, so
-        # run_rounds falls back to per-round dispatch there
-        self._split = split_compile
         self._payload_client = None
         self._payload_merge = None
         self._payload_merge_stale = None
@@ -487,27 +469,6 @@ class FederatedSession:
                     merge_er, donate_argnums=self._state_donation())
             self._step = engine.compose_payload(
                 self._payload_client, self._payload_merge)
-        elif split_compile:
-            # two XLA programs per round: the Pallas/Mosaic sketch server step
-            # compiles separately from the big vmapped grad module (see
-            # engine.make_split_round_step for why). On the SPMD path the
-            # program boundary carries per-device-resident partials instead
-            # of one dense [d] update (engine.make_sharded_split_round_step).
-            if self._spmd:
-                if mesh is None:
-                    raise ValueError(
-                        "split_compile with client_shards > 1 needs a mesh; "
-                        "the single-device sharded reference is fused-only"
-                    )
-                client_p, server_p = engine.make_sharded_split_round_step(
-                    train_loss_fn, self.cfg, mesh)
-            else:
-                client_p, server_p = engine.make_split_round_step(
-                    train_loss_fn, self.cfg)
-            self._step = engine.compose_split(
-                jax.jit(client_p),
-                jax.jit(server_p, donate_argnums=self._state_donation()),
-            )
         elif self._spmd:
             self._step = jax.jit(
                 engine.make_sharded_round_step(train_loss_fn, self.cfg,
@@ -1264,11 +1225,10 @@ class FederatedSession:
     @property
     def supports_block_dispatch(self) -> bool:
         """Whether run_rounds can actually fuse a block into one dispatch:
-        per-client-state modes need the host gather/scatter between rounds,
-        and split sessions exist to keep Mosaic OUT of big fused modules.
+        per-client-state modes need the host gather/scatter between rounds.
         An active fault plan also forces per-round dispatch: injection sites
         are scheduled by round, which a K-round fused block cannot honor."""
-        return (self.client_state is None and not self._split
+        return (self.client_state is None
                 and self.fault_plan is None
                 # table rounds (wire payloads / robust merge / adversarial
                 # chaos) are per-round by construction: the wire crossing —
@@ -1282,7 +1242,7 @@ class FederatedSession:
         blocks amortize the per-dispatch host work K-fold. Sampling and rng
         streams are IDENTICAL
         to sequential run_round calls (pinned by tests); per-client-state
-        modes and split-compile sessions fall back to per-round dispatch."""
+        modes fall back to per-round dispatch."""
         lrs = list(lrs)
         if not self.supports_block_dispatch or len(lrs) <= 1:
             return [self.run_round(lr) for lr in lrs]

@@ -117,7 +117,7 @@ class EngineConfig:
     # - "layerwise": each reduced leaf folds DIRECTLY into the running
     #   r x c table (sketch/layerwise.py) — not even the one reduced flat
     #   [d] gradient, nor the flat params copy for the delta apply,
-    #   materializes. Pinned BIT-identical to the ravel path (fused, split,
+    #   materializes. Pinned BIT-identical to the ravel path (fused,
     #   sharded): sketch addition is the same ordered float sum either way
     #   (csvec._sketch_vec_rotation's explicit slab fold). Caveat: the
     #   random hash family requires num_blocks == 1 (the blocked ravel
@@ -142,9 +142,7 @@ class EngineConfig:
     # screens against the MEDIAN OVER THE WINDOW, so a model whose update
     # norms drift fast (early training, lr pivots) doesn't quarantine
     # healthy clients just because this round's norms moved: one outlier
-    # round perturbs one window slot, not the whole threshold. Fused round
-    # paths only (the split-compile program boundary threads a single
-    # scalar median).
+    # round perturbs one window slot, not the whole threshold.
     quarantine_window: int = 1
     # Wire-payload round (--serve_payload sketch): the round's aggregate is
     # the ordered sum of PER-CLIENT Count-Sketch tables instead of the
@@ -194,8 +192,7 @@ class EngineConfig:
     # rounds the scalar screen is sketch-space (table norms) while the
     # per-leaf screens are update-space, so layer scope genuinely ADDS a
     # second statistic even single-leaf (by design: the table superimposes
-    # all layers and cannot be screened per leaf). Fused round paths only
-    # (the split program boundary threads one scalar median).
+    # all layers and cannot be screened per leaf).
     quarantine_scope: str = "cohort"
     # Buffered-ASYNC serving (--serve_async, FedBuff-shaped): > 0 sizes the
     # stale-fold slot stack of the payload MERGE program — late tables
@@ -819,21 +816,12 @@ def _round_median(norms, part_eff):
     return _masked_median(norms, live, n_live), n_live
 
 
-def _update_running_median(norms, part_eff, old_med):
-    """Next round's quarantine baseline, window=1 semantics: the median L2
-    norm over this round's live clients, keeping the previous median when
-    the whole cohort dropped/quarantined — an empty round must not zero the
-    threshold."""
-    med, n_live = _round_median(norms, part_eff)
-    return jnp.where(n_live > 0, med, old_med)
-
-
 def _advance_quarantine(cfg: EngineConfig, qstate: dict, norms, part_eff) -> dict:
     """One round's update of the quarantine server state.
 
-    quarantine_window == 1 (default): {"median": <window=1 update>} — the
-    exact pre-window arithmetic AND state tree, so the default is
-    bit-identical to the running-median behavior it replaces.
+    quarantine_window == 1 (default): {"median": <this round's live-cohort
+    median>}, keeping the previous median when the whole cohort dropped or
+    was quarantined — an empty round must not zero the threshold.
 
     quarantine_window K > 1: push this round's live-cohort median into a
     [K] ring (empty rounds push nothing) and set the ACTIVE threshold
@@ -842,12 +830,11 @@ def _advance_quarantine(cfg: EngineConfig, qstate: dict, norms, part_eff) -> dic
     snapping to the newest round, so fast-drifting models don't quarantine
     healthy clients (and one outlier round perturbs one slot, not the whole
     baseline)."""
-    if cfg.quarantine_window <= 1:
-        return {"median": _update_running_median(
-            norms, part_eff, qstate["median"])}
-    K = cfg.quarantine_window
     med, n_live = _round_median(norms, part_eff)
     has = n_live > 0
+    if cfg.quarantine_window <= 1:
+        return {"median": jnp.where(has, med, qstate["median"])}
+    K = cfg.quarantine_window
     window = jnp.where(
         has, jnp.concatenate([qstate["window"][1:], med[None]]),
         qstate["window"])
@@ -924,41 +911,9 @@ def _advance_quarantine_layers(cfg: EngineConfig, qstate: dict,
     return new
 
 
-def _split_quarantine_scope_check(cfg: EngineConfig):
-    """The split-compile program boundary threads exactly one scalar
-    (metrics['quarantine_median']) between the client and server programs —
-    a K-slot window ring cannot cross it without widening the boundary for
-    every split caller. The windowed baseline and the per-layer rings are
-    fused-path features (make_round_step, make_sharded_round_step, the
-    payload merge); reject the combination at build time instead of
-    silently running window=1 / cohort scope."""
-    if cfg.client_update_clip > 0 and cfg.quarantine_window > 1:
-        raise ValueError(
-            "quarantine_window > 1 is fused-paths-only: the split-compile "
-            "program boundary threads a single scalar median "
-            f"(got quarantine_window={cfg.quarantine_window} with a split "
-            "round step); drop --split_compile or use quarantine_window=1"
-        )
-    if cfg.client_update_clip > 0 and cfg.quarantine_scope == "layer":
-        raise ValueError(
-            "quarantine_scope='layer' is fused-paths-only: the split-"
-            "compile program boundary threads a single scalar median and "
-            "the per-leaf rings cannot cross it; drop --split_compile or "
-            "use quarantine_scope=cohort"
-        )
-    if cfg.health or cfg.ledger_fingerprint:
-        raise ValueError(
-            "health estimators / ledger fingerprints are fused-paths-only: "
-            "they ride the round metrics tree, which the split program "
-            "boundary does not thread (the client program's metrics are "
-            "emitted before the server algebra the estimators read); drop "
-            "--split_compile or the obs flag"
-        )
-
-
 def _robust_scope_check(cfg: EngineConfig):
     """Robust merge policies need per-client tables: the linear round
-    builders (fused / sharded / split — all built on the compress-once or
+    builders (fused / sharded — built on the compress-once or
     per-shard-partial shortcut) cannot apply them. The session routes
     robust-policy configs through make_payload_round_steps; a direct
     caller reaching a linear builder with one armed gets a loud error
@@ -1022,16 +977,13 @@ def _guard_nonfinite(cfg: EngineConfig, agg, new_net_state, net_state,
 
 
 def _skip_metrics(ok, out_metrics) -> dict:
-    """The one source of truth for a skipped round's metric semantics (used
-    by BOTH the fused guard and the split client reduce, so split == fused
-    metric parity can't drift): zero the round's training-stat sums
+    """A skipped round's metric semantics: zero the round's training-stat sums
     (loss_sum/count/... came from the poisoned forward pass, and one NaN
     loss_sum would NaN the whole eval window), keep participants (the
     clients DID transmit; only the server discards), and emit the
     nonfinite_rounds flag. The quarantine keys survive the zeroing like
     participants: the quarantine verdicts/median are server-side bookkeeping,
-    not training stats from the poisoned forward pass (zeroing the median
-    metric would reset the split path's running threshold)."""
+    not training stats from the poisoned forward pass."""
     keep = ("participants", "clients_quarantined", "quarantine_median")
     out_metrics = {
         k: v if k in keep else jnp.where(ok, v, jnp.zeros_like(v))
@@ -1211,8 +1163,7 @@ def _finalize_client_reduce(mcfg: ModeConfig, wsum, ns_sum, m_sum, net_state, pa
     """Normalize the weighted SUMS from `_weighted_client_reduce`: the reduced
     update (survivor mean unless agg_op=sum), the survivor-mean mutable
     collections (previous stats when no survivors), and the metrics dict with
-    the participants count. One place, so the fused and split steps cannot
-    drift apart."""
+    the participants count."""
     n_live = jnp.maximum(part.sum(), 1.0)
     weighted = wsum if mcfg.agg_op == "sum" else wsum / n_live
     new_net_state = jax.tree.map(
@@ -1556,7 +1507,7 @@ def supports_sharded_round(mcfg: ModeConfig) -> bool:
     linear grad modes without client-local state and without the local-SGD
     weight-delta loop — compression must commute with the cross-shard sum,
     which is exactly FetchSGD's sketch linearity (and trivially holds for
-    dense wires). Same scope as the split step: the flagship configuration.
+    dense wires): the flagship configuration.
     Everything else keeps the GSPMD-annotation path (XLA partitions the
     unchanged round program; cross-device reduction is the dense wire)."""
     return (modes.is_linear(mcfg) and not mcfg.needs_local_state
@@ -1593,7 +1544,8 @@ def _merged_survivor_finalize(ns_sum, m_sum, part, net_state):
     """Survivor-mean mutable collections + metrics/participants from MERGED
     cross-shard sums — the sharded round's counterpart of
     _finalize_client_reduce, the ONE place for these semantics so the fused
-    tail and the split client program cannot drift apart."""
+    layerwise round, the sharded tail and the payload merge cannot drift
+    apart."""
     n_live = jnp.maximum(part.sum(), 1.0)
     new_net_state = jax.tree.map(
         lambda s, prev: jnp.where(part.sum() > 0, s / n_live, prev),
@@ -1608,7 +1560,7 @@ def _merged_survivor_finalize(ns_sum, m_sum, part, net_state):
 def _normalize_merged_wire(mcfg: ModeConfig, wire_sum: dict, n_live) -> dict:
     """Survivor normalization IN WIRE SPACE (compression is homogeneous only
     up to fp order, so every sharded path normalizes after the merge — one
-    place, shared by the fused tail and the split server program)."""
+    place, shared by the sharded tail and the payload merge)."""
     if mcfg.agg_op == "sum":
         return dict(wire_sum)
     return {k: v / n_live for k, v in wire_sum.items()}
@@ -1921,342 +1873,6 @@ def make_sharded_round_step(
     return _kernels_replicated(mesh, step)
 
 
-def make_sharded_split_round_step(
-    loss_fn: Callable, cfg: EngineConfig, mesh
-) -> tuple[Callable, Callable]:
-    """The sharded round split into the same TWO jittable programs as
-    make_split_round_step — and for the same reason (keep Mosaic out of the
-    big vmapped module) — but with the program boundary
-    moved so the dense [d] update still never crosses the mesh:
-
-        client_step(state, batch, lr, rng) -> (wpart[S, d] SHARDED,
-                                               net_state', metrics, noise_rng)
-        server_step(state, wpart, net_state', participants, lr, noise_rng)
-            -> state'
-
-    The client program (Mosaic-free) reduces each shard to its local dense
-    partial and leaves it RESIDENT on its device ([S, d] sharded over the
-    client axes — no transfer). The server program (small, Mosaic-bearing)
-    sketches each partial where it lives, merges the r x c tables with the
-    ordered all_gather sum, and runs the FetchSGD algebra replicated. Same
-    signature arity as make_split_round_step, so compose_split and the
-    session's split wiring work unchanged. Bit-identical to
-    make_sharded_round_step on the same mesh (pinned in tests).
-
-    sketch_path="layerwise": each shard's partial Count Sketch accumulates
-    from the per-leaf weighted sums INSIDE the client program (pure-JAX
-    roll+add — still Mosaic-free) and is all_gathered there, so the program
-    boundary carries the replicated [S, r, c] partial tables instead of a
-    per-device-resident [S, d] dense stack; neither the flat gradient nor
-    the flat params copy ever exists. The server program keeps the
-    Pallas-bearing unsketch/query algebra.
-    """
-    mcfg = cfg.mode
-    _sharded_scope_check(mcfg)
-    _robust_scope_check(cfg)
-    if mesh is None:
-        raise ValueError(
-            "sharded split round needs a mesh; the single-device reference "
-            "is the fused make_sharded_round_step(mesh=None)"
-        )
-    S, axis_names = _mesh_shard_info(mesh)
-    if S <= 1:
-        raise ValueError("sharded split round needs a mesh with > 1 client "
-                         "shard; use make_split_round_step")
-    if cfg.client_shards > 1 and cfg.client_shards != S:
-        raise ValueError(
-            f"cfg.client_shards={cfg.client_shards} disagrees with the "
-            f"{S}-way client mesh"
-        )
-    grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
-    layerwise = cfg.sketch_path == "layerwise"
-
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel import mesh as meshlib
-
-    axes = meshlib.client_axes(mesh)
-
-    quarantine = cfg.client_update_clip > 0
-    _split_quarantine_scope_check(cfg)
-
-    # As in the fused sharded step, ONLY the per-shard work + gathers live
-    # inside shard_map; merges and the server algebra run at jit top level
-    # on the replicated stacks so both programs (and the single-device
-    # reference) share one compile context for the value-sensitive fp tail.
-    def client_body(state, batch_l, lr, rng):
-        params, net_state = state["params"], state["net_state"]
-        batch_l, valid_l = split_valid(batch_l)
-        wl = jax.tree.leaves(batch_l)[0].shape[0]
-        all_rngs, part, noise_rng = _cohort_streams(cfg, rng, wl * S)
-        qmed = state["quarantine"]["median"] if quarantine else None
-        lo = _shard_index(mesh, axis_names) * wl
-        rngs_l = jax.lax.dynamic_slice_in_dim(all_rngs, lo, wl)
-        part_l = jax.lax.dynamic_slice_in_dim(part, lo, wl)
-        if valid_l is not None:
-            part_l = part_l * valid_l
-        # layer scope is split-rejected (_split_quarantine_scope_check):
-        # the trailing lnorms slot is always None here
-        wsum_l, ns_l, m_l, pe_l, norms_l, _ = _weighted_client_reduce(
-            cfg, grad_client_tree, params, net_state, batch_l, rngs_l,
-            part_l, qmed=qmed, nan_safe=valid_l is not None,
-            ravel=not layerwise,
-        )
-        if layerwise:
-            # this shard's partial table, built straight from the per-leaf
-            # sums: the dense [d] partial never exists, and the [r, c]
-            # table is what crosses the program boundary (gathered below)
-            table_l = _layerwise_compress(
-                mcfg, wsum_l, _layerwise_plan(mcfg, params))["table"]
-            wire_out = jax.lax.all_gather(table_l, axis_names, axis=0)
-            fin_l = jnp.isfinite(table_l).all()[None]
-        else:
-            wire_out = wsum_l[None]
-            fin_l = jnp.isfinite(wsum_l).all()[None]
-        gathered = (ns_l, m_l, pe_l) + ((part_l, norms_l) if quarantine
-                                        else ())
-        stacked = jax.tree.map(
-            lambda x: jax.lax.all_gather(x, axis_names, axis=0), gathered,
-        )
-        # finiteness of the partials == finiteness of the merged wire
-        # (compression propagates every NaN/Inf — the same equivalence
-        # make_split_round_step already relies on); gathered here so both
-        # programs share the identical verdict
-        parts_ok = jax.lax.all_gather(fin_l, axis_names, axis=0).all()
-        return (wire_out,) + stacked + (noise_rng, parts_ok)
-
-    n_gathered = 5 if quarantine else 3
-    client_mapped = jax.shard_map(
-        client_body, mesh=mesh,
-        in_specs=(P(), P(axes), P(), P()),
-        # layerwise: the boundary object is the gathered [S, r, c] table
-        # stack, replicated; ravel: the [S, d] dense partials, sharded
-        out_specs=((P() if layerwise else P(axes),)
-                   + tuple(P() for _ in range(n_gathered + 2))),
-        check_vma=False,
-    )
-
-    def client_step(state, batch, lr, rng):
-        outs = client_mapped(state, batch, lr, rng)
-        wpart, stacked_ns, stacked_m, pe_s = outs[:4]
-        noise_rng, parts_ok = outs[-2], outs[-1]
-        part_eff = pe_s.reshape(-1)
-        with jax.named_scope("cohort_reduce"):
-            ns_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_ns)
-            m_sum = jax.tree.map(lambda x: x.sum(axis=0), stacked_m)
-        new_net_state, out_metrics = _merged_survivor_finalize(
-            ns_sum, m_sum, part_eff, state["net_state"])
-        if quarantine:
-            pv, norms = outs[4].reshape(-1), outs[5].reshape(-1)
-            qmed = state["quarantine"]["median"]
-            out_metrics["clients_quarantined"] = pv.sum() - part_eff.sum()
-            out_metrics["quarantine_median"] = _update_running_median(
-                norms, part_eff, qmed)
-        if cfg.on_nonfinite == "skip":
-            ok = parts_ok & _tree_finite(new_net_state)
-            out_metrics = _skip_metrics(ok, out_metrics)
-        return wpart, new_net_state, out_metrics, noise_rng
-
-    @jax.named_scope("compress")
-    def server_body(wpart_l):
-        wire_l, _ = modes.client_compress(mcfg, wpart_l[0], {})
-        stacked_wire = jax.tree.map(
-            lambda x: jax.lax.all_gather(x, axis_names, axis=0), wire_l)
-        parts_ok = jax.lax.all_gather(
-            jnp.isfinite(wpart_l).all()[None], axis_names, axis=0).all()
-        return stacked_wire, parts_ok
-
-    server_mapped = jax.shard_map(
-        server_body, mesh=mesh,
-        in_specs=P(axes),
-        out_specs=(P(), P()),
-        check_vma=False,
-    )
-
-    def server_step(state, wpart, new_net_state, participants, lr, noise_rng,
-                    qmed=None):
-        if layerwise:
-            # wpart is the replicated [S, r, c] partial-table stack the
-            # client program gathered; nothing dense to compress here
-            stacked_wire = {"table": wpart}
-            parts_ok = jnp.isfinite(wpart).all()
-        else:
-            stacked_wire, parts_ok = server_mapped(wpart)
-            pflat, unravel = _ravel_params(state["params"])
-        with jax.named_scope("compress"):
-            wire_sum = modes.merge_partial_wires(mcfg, stacked_wire)
-        agg = _normalize_merged_wire(
-            mcfg, wire_sum, jnp.maximum(participants, 1.0))
-        if cfg.on_nonfinite == "skip":
-            # derived from the PARTIALS (available here), matching the
-            # client program's verdict exactly
-            ok = parts_ok & _tree_finite(new_net_state)
-            agg = jax.tree.map(
-                lambda a: jnp.where(ok, a, jnp.zeros_like(a)), agg)
-            new_net_state = jax.tree.map(
-                lambda new, old: jnp.where(ok, new, old),
-                new_net_state, state["net_state"],
-            )
-            participants = participants * ok
-        if cfg.dp_noise > 0:
-            agg = _dp_noise_agg(cfg, agg, participants, noise_rng)
-        delta, mode_state = modes.server_step_sparse(
-            mcfg, agg, state["mode_state"], lr)
-        new_params = (
-            _layerwise_apply(state["params"], delta,
-                             _layerwise_plan(mcfg, state["params"]))
-            if layerwise else _flat_apply(pflat, unravel, delta))
-        new_state = {
-            "params": new_params,
-            "net_state": new_net_state,
-            "mode_state": mode_state,
-            "round": state["round"] + 1,
-        }
-        if quarantine:
-            if qmed is None:
-                raise ValueError(
-                    "client_update_clip > 0: server_step needs the updated "
-                    "running median (metrics['quarantine_median'] from the "
-                    "client program)"
-                )
-            new_state["quarantine"] = {"median": qmed}
-        return new_state
-
-    return (_kernels_replicated(mesh, client_step),
-            _kernels_replicated(mesh, server_step))
-
-
-def make_split_round_step(
-    loss_fn: Callable, cfg: EngineConfig
-) -> tuple[Callable, Callable]:
-    """The same round as `make_round_step`, split into TWO jittable programs:
-
-        client_step(state, batch, lr, rng) -> (weighted[d], net_state',
-                                               metrics, noise_rng)
-        server_step(state, weighted, net_state', participants, lr, noise_rng)
-            -> state'
-
-    Why it exists: splitting keeps the Mosaic custom-calls in a small
-    dedicated XLA module (compress + FetchSGD server algebra) while the big
-    vmapped fwd/bwd module stays Mosaic-free — an isolation and debugging
-    aid, and the shape the serving path's wire boundary needs; the cost is
-    one extra host dispatch per round. (The fused module with the kernels
-    inlined is the trainers' default and compiles and runs on a v5e —
-    chip_smoke.py.) Bit-equal to the fused step
-    (tests/test_engine.py pins it): both derive the same rng streams, and
-    both take the linear-mode shortcut — which is also the supported scope
-    (linear mode, no client-local state, no weight-delta local loop), exactly
-    the flagship sketch configuration.
-
-    sketch_path="layerwise" moves the table accumulation INTO the client
-    program (pure-JAX roll+add — Mosaic-free by construction, so the
-    isolation story is intact; the Pallas-bearing unsketch/query algebra
-    stays in the server program) and the program boundary carries the r x c
-    wire table instead of the dense [d] reduced update.
-    """
-    mcfg = cfg.mode
-    _robust_scope_check(cfg)
-    if not (modes.is_linear(mcfg) and not mcfg.needs_local_state
-            and not mcfg.uses_weight_delta):
-        raise ValueError(
-            "split round step supports linear grad modes without client-local "
-            f"state (the flagship sketch config); mode={mcfg.mode!r} "
-            f"error_type={mcfg.error_type!r} momentum_type="
-            f"{mcfg.momentum_type!r} needs the fused make_round_step"
-        )
-    grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
-    layerwise = cfg.sketch_path == "layerwise"
-
-    quarantine = cfg.client_update_clip > 0
-    _split_quarantine_scope_check(cfg)
-
-    def client_step(state, batch, lr, rng):
-        batch, valid = split_valid(batch)
-        params, net_state = state["params"], state["net_state"]
-        num_sampled = jax.tree.leaves(batch)[0].shape[0]
-        # identical stream derivation to the fused step (see its comment on
-        # fold_in collisions), so split == fused holds bit-for-bit
-        crng, noise_rng, drop_rng = jax.random.split(rng, 3)
-        client_rngs = jax.random.split(crng, num_sampled)
-        part = participation_mask(drop_rng, num_sampled, cfg.client_dropout)
-        if valid is not None:
-            part = part * valid
-        qmed = state["quarantine"]["median"] if quarantine else None
-
-        # layer scope is split-rejected: lnorms is always None here
-        wsum, ns_sum, m_sum, part_eff, norms, _ = _weighted_client_reduce(
-            cfg, grad_client_tree, params, net_state, batch, client_rngs,
-            part, qmed=qmed, nan_safe=valid is not None, ravel=not layerwise,
-        )
-        if layerwise:
-            weighted = _layerwise_compress(
-                mcfg,
-                _layerwise_normalize(mcfg, wsum,
-                                     jnp.maximum(part_eff.sum(), 1.0)),
-                _layerwise_plan(mcfg, params))
-            new_net_state, out_metrics = _merged_survivor_finalize(
-                ns_sum, m_sum, part_eff, net_state)
-        else:
-            weighted, new_net_state, out_metrics = _finalize_client_reduce(
-                mcfg, wsum, ns_sum, m_sum, net_state, part_eff
-            )
-        if quarantine:
-            out_metrics["clients_quarantined"] = part.sum() - part_eff.sum()
-            out_metrics["quarantine_median"] = _update_running_median(
-                norms, part_eff, qmed)
-        if cfg.on_nonfinite == "skip":
-            # same verdict the fused step computes from the compressed agg:
-            # compression (sketch sums / dense passthrough) propagates every
-            # NaN/Inf, so finiteness of `weighted` == finiteness of the wire
-            # (on the layerwise path `weighted` IS the wire table — the
-            # identical object the fused guard inspects)
-            ok = _tree_finite(weighted) & _tree_finite(new_net_state)
-            out_metrics = _skip_metrics(ok, out_metrics)
-        return weighted, new_net_state, out_metrics, noise_rng
-
-    def server_step(state, weighted, new_net_state, participants, lr,
-                    noise_rng, qmed=None):
-        if not layerwise:
-            pflat, unravel = _ravel_params(state["params"])
-        if cfg.on_nonfinite == "skip":
-            ok = _tree_finite(weighted) & _tree_finite(new_net_state)
-            weighted = jax.tree.map(
-                lambda a: jnp.where(ok, a, jnp.zeros_like(a)), weighted)
-            new_net_state = jax.tree.map(
-                lambda new, old: jnp.where(ok, new, old),
-                new_net_state, state["net_state"],
-            )
-            # a skipped round transmits nothing and must release nothing:
-            # zero the count so _dp_noise_agg's empty-round gate kicks in
-            participants = participants * ok
-        agg = weighted if layerwise else _compress_reduced(mcfg, weighted)
-        if cfg.dp_noise > 0:
-            agg = _dp_noise_agg(cfg, agg, participants, noise_rng)
-        delta, mode_state = modes.server_step_sparse(
-            mcfg, agg, state["mode_state"], lr)
-        new_params = (
-            _layerwise_apply(state["params"], delta,
-                             _layerwise_plan(mcfg, state["params"]))
-            if layerwise else _flat_apply(pflat, unravel, delta))
-        new_state = {
-            "params": new_params,
-            "net_state": new_net_state,
-            "mode_state": mode_state,
-            "round": state["round"] + 1,
-        }
-        if quarantine:
-            if qmed is None:
-                raise ValueError(
-                    "client_update_clip > 0: server_step needs the updated "
-                    "running median (metrics['quarantine_median'] from the "
-                    "client program)"
-                )
-            new_state["quarantine"] = {"median": qmed}
-        return new_state
-
-    return client_step, server_step
-
-
 def make_multi_round_step(
     loss_fn: Callable, cfg: EngineConfig, mesh=None
 ) -> Callable:
@@ -2302,26 +1918,6 @@ def make_multi_round_step(
         return jax.lax.scan(body, state, (batches, lrs, rngs))
 
     return multi
-
-
-def compose_split(client_step: Callable, server_step: Callable) -> Callable:
-    """Adapt a (client_step, server_step) pair back to the fused-step
-    signature `(state, batch, client_rows, lr, rng) -> (state', rows,
-    metrics)`, so call sites (session, bench) stay agnostic of the
-    two-program protocol. client_rows pass through untouched — the split
-    scope has no client-local state. The quarantine's running-median update
-    crosses the program boundary as metrics['quarantine_median'] (absent →
-    qmed=None, quarantine off)."""
-
-    def step(state, batch, client_rows, lr, rng):
-        weighted, net_state, metrics, noise_rng = client_step(state, batch, lr, rng)
-        new_state = server_step(
-            state, weighted, net_state, metrics["participants"], lr,
-            noise_rng, qmed=metrics.get("quarantine_median"),
-        )
-        return new_state, client_rows, metrics
-
-    return step
 
 
 def _table_norms(tables: jnp.ndarray) -> jnp.ndarray:

@@ -178,7 +178,7 @@ def merge_comm_bytes(n_shards: int, r: int, c: int, d: int) -> dict:
     """Analytic per-round cross-device traffic of the sharded round's merge,
     per device: the sketch-table merge (what the engine ships) vs the dense
     [d] all-reduce a gradient-synchronous data-parallel round would ship —
-    the comm-efficiency headline bench.py's mesh section records.
+    the comm-efficiency headline of the README's multi-chip section.
 
     allgather = (S-1) tables received per device (the deterministic ordered
     merge the engine uses); psum = 2(S-1)/S tables (the classic ring

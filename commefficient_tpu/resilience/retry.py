@@ -32,9 +32,9 @@ import numpy as np
 from ..obs import registry as obreg
 
 # process-wide per-site count of FAILED attempts (each one either backed off
-# and retried, or exhausted the budget) — the benchmarkable footprint of a
-# chaos run: bench.py surfaces this dict in its JSON so "the run recovered
-# from N flakes" is a number, not a log-grep
+# and retried, or exhausted the budget) — the countable footprint of a
+# chaos run (`retry_counts()`): "the run recovered from N flakes" is a
+# number, not a log-grep
 _COUNTS_LOCK = threading.Lock()
 _RETRY_COUNTS: dict[str, int] = {}
 

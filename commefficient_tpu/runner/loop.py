@@ -78,9 +78,8 @@ def _process_count() -> int:
 # the measured RTT is what the in-flight chain amortizes
 def measure_rtt_ms(samples: int = 5) -> float:
     """Median host<->device round-trip of a trivial jitted op + device_get —
-    the per-drain sync cost the in-flight chain exists to amortize. Same
-    discipline as bench.py's round-trip measurement; cheap enough to run
-    once at loop start."""
+    the per-drain sync cost the in-flight chain exists to amortize. Cheap
+    enough to run once at loop start."""
     import jax.numpy as jnp
 
     f = jax.jit(lambda x: x + 1.0)
@@ -114,7 +113,8 @@ def auto_inflight(rtt_ms: float, round_ms: float,
 @dataclasses.dataclass
 class RunnerConfig:
     """Loop shape + operational policy (mirrors the CLI flag surface; build
-    one with from_args in the CLIs, or directly in tests/bench)."""
+    one with from_args in the CLIs, or directly in tests and in
+    benchmark/harness.py)."""
 
     total_rounds: int
     eval_every: int
@@ -164,16 +164,18 @@ class RunnerConfig:
 
 @dataclasses.dataclass
 class RunStats:
-    """What the loop did — bench.py's run_loop section reads these.
+    """What the loop did — the CLIs' final-metrics line, the chaos smokes
+    and the tests read these.
 
     Since the obs/ layer landed these are a per-run VIEW over the
     process-wide metrics registry: run_loop increments named registry
     counters (runner_rounds_total, cohort_clients_dropped_total, ...) at
     the same points it always counted, takes a RegistryMark at loop start,
     and fills this dataclass from the deltas at loop end — so RunStats,
-    serve's /metrics snapshot, and bench's resilience block all read the
-    SAME numbers. (Concurrent run_loops in one process would cross-count;
-    the loops in this repo — bench arms, the CLIs — run sequentially.)"""
+    serve's /metrics snapshot and benchmark/harness.py's window deltas all
+    read the SAME numbers. (Concurrent run_loops in one process would
+    cross-count; the loops in this repo — the CLIs, the benchmark's set-up
+    and window — run sequentially.)"""
 
     rounds: int = 0
     wall_s: float = 0.0
@@ -186,10 +188,10 @@ class RunStats:
     # in-flight depth the loop ended on (auto-tuned unless --max_inflight)
     rtt_ms: float = 0.0
     max_inflight_used: int = 0
-    # cohort degradation (bench.py resilience block): clients masked out of
-    # rounds (failed loads / injected drops), clients rejected by the
-    # sketch-space quarantine, rounds that ran degraded at all, and how deep
-    # the dropped-client re-queue got
+    # cohort degradation: clients masked out of rounds (failed loads /
+    # injected drops), clients rejected by the sketch-space quarantine,
+    # rounds that ran degraded at all, and how deep the dropped-client
+    # re-queue got
     clients_dropped: int = 0
     clients_quarantined: int = 0
     degraded_rounds: int = 0
@@ -263,8 +265,8 @@ def run_loop(
     build_row(rnd, m, totals, ev, time_s, nonfinite_total) -> row dict for
     the logger; `m` is the last round's metrics, `totals` the sum of every
     numeric metric key since the previous eval row. Either may be None (no
-    eval / no logging — bench runs). save_ckpt defaults to make_save_ckpt
-    when cfg.checkpoint_dir is set.
+    eval / no logging — benchmark/harness.py). save_ckpt defaults to
+    make_save_ckpt when cfg.checkpoint_dir is set.
 
     source: an external round source (next() -> PreparedRound in round
     order, stop()) — the serving layer (serve/ServedSource) passes one so
@@ -310,7 +312,8 @@ def run_loop(
         profile.declare_unreachable(cfg.total_rounds)
         profile = None
     # (client_* fault schedules are validated against the FULL run length by
-    # the CLIs — run_loop may legitimately cover a segment, e.g. bench arms)
+    # the CLIs — run_loop may legitimately cover a segment, e.g. the
+    # benchmark's checked rounds and its window)
     # multi-host coordinated preemption: with > 1 process the LOCAL SIGTERM
     # flag must not short-circuit the SPMD schedule (the un-signalled hosts
     # would block in the next round's collectives) — every preemption

@@ -10,7 +10,7 @@ that inversion over the existing engine/runner machinery:
   plus the wire-payload validation gauntlet (`validate_payload` — THE
   sanctioned deserialization boundary for untrusted frame bytes,
   graftlint G011)
-- `transport` — in-process (tests/bench/parity) and loopback-socket
+- `transport` — in-process (tests, parity pins) and loopback-socket
   (JSON-lines wire realism) submission fronts, hardened against a hostile
   peer: per-connection read deadlines, max-frame caps, force-closed
   connections on stop; client helpers with bounded jittered retries
@@ -21,7 +21,7 @@ that inversion over the existing engine/runner machinery:
 - `clients`   — O(1)-per-participant client state: fold_in-derived per-
   client streams and device classes, no per-client table (10M-ID safe)
 - `traffic`   — trace-driven generator: diurnal load, bursts, device
-  classes with distinct straggle distributions (test harness + BENCH_SERVE);
+  classes with distinct straggle distributions (the test harness);
   payload rounds ship per-invitee tables with wire-fault injection at the
   transport seam
 - `metrics`   — the ops surface: /metrics JSON endpoint (round, queue

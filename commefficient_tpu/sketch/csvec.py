@@ -327,8 +327,8 @@ def mask_transmitted(
     Note on cost: expressing this as one call changes nothing measured —
     inside one jitted program XLA already CSE's the three ops' identical
     (r, k) hash evaluations (the isolated algebra cost is the
-    scatter/gather/sort itself — bench.py server_split's
-    algebra_sketch_ms). So this is a plain composition of the shared
+    scatter/gather/sort itself, the round's `server_algebra`
+    phase). So this is a plain composition of the shared
     primitives, preserving _accumulate's single-scatter-path invariant;
     the value is the single call site and the documented semantics."""
     E = E - sketch_sparse(spec, idx, vals)

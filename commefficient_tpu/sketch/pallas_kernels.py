@@ -437,8 +437,8 @@ def eligible(spec) -> bool:
     a supported layout on a TPU backend. The first use per (c, r) probes,
     and a failed probe raises — it never downgrades to the oracle. Shared by
     `csvec.sketch_impl` (which layers the COMMEFFICIENT_NO_PALLAS /
-    COMMEFFICIENT_PALLAS_INTERPRET env policy on top) and bench.py's kernel
-    microbench (which deliberately ignores that env policy)."""
+    COMMEFFICIENT_PALLAS_INTERPRET env policy on top) and by callers that
+    ask about the backend alone, whatever that env policy says."""
     if not (supported(spec) and jax.default_backend() == "tpu"):
         return False
     probe(spec.c, spec.r)
@@ -446,6 +446,6 @@ def eligible(spec) -> bool:
 
 
 def probe_status() -> dict:
-    """Layouts probed so far (bench.py embeds this in its JSON)."""
+    """Layouts probed so far (read by tests/test_pallas.py)."""
     layouts = [f"c={c},r={r}" for c, r in sorted(_PROBED)]
     return {"probed": len(layouts) > 0, "layouts": layouts}

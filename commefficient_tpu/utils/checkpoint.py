@@ -60,7 +60,7 @@ def _round_dirs(ckpt_dir: str) -> list[str]:
 # process-wide count of committed checkpoints that FAILED the post-commit
 # read-back (save-time manifest verification): silent-bitrot-on-write media
 # caught in the act. Each failure also raises inside the retry wrapper, so a
-# transient flake gets re-written; bench.py surfaces the count in its JSON.
+# transient flake gets re-written; `save_verify_failures()` is the count.
 _VERIFY_FAILURES = 0
 
 

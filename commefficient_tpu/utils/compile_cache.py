@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives.
 
-One rule for every entry point (cv_train.py, gpt2_train.py, bench.py,
-chip_smoke.py): where JAX_COMPILATION_CACHE_DIR is set, JAX reads it and
+One rule for every entry point (cv_train.py, gpt2_train.py, chip_smoke.py,
+benchmark/harness.py): where JAX_COMPILATION_CACHE_DIR is set, JAX reads it and
 nothing is set in code; where it is not, the cache goes to
 `<checkout>/.jax_cache` — a fixed path computed from this file's location,
 because the path is part of what a later process has to find again. The

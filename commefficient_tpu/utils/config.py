@@ -167,9 +167,7 @@ def make_parser(task: str = "cv") -> argparse.ArgumentParser:
                         "medians, so models whose update norms drift fast "
                         "don't quarantine healthy clients (one outlier "
                         "round perturbs one window slot, not the whole "
-                        "threshold). Works on the fused, sharded, and "
-                        "payload rounds; --split_compile rejects it loudly "
-                        "(the split boundary threads one scalar median)")
+                        "threshold)")
     p.add_argument("--requeue_policy", default="fifo",
                    choices=["fifo", "aged"],
                    help="serving order for the dropped-client re-queue: "
@@ -384,10 +382,10 @@ def make_parser(task: str = "cv") -> argparse.ArgumentParser:
                         "many clients (must divide --num_workers), so at "
                         "most client_chunk full gradients coexist in HBM — "
                         "lets GPT-2-scale rounds sample big cohorts per chip")
+    # stub (removed in PR 29): benchmark/builders/common.py reads the
+    # attribute; resolve_defaults exits when it is set
     p.add_argument("--split_compile", action="store_true",
-                   help="compile the round as TWO XLA programs (client grads "
-                        "| sketch server step) so Pallas custom-calls stay in "
-                        "a small dedicated module; linear grad modes only")
+                   help=argparse.SUPPRESS)
     p.add_argument("--multihost", action="store_true",
                    help="force jax.distributed.initialize() at startup "
                         "(auto-detected multi-host environments initialize "
@@ -649,6 +647,10 @@ def resolve_defaults(args: argparse.Namespace) -> argparse.Namespace:
         }.get(args.mode, "none")
     if args.mode in ("fedavg", "localSGD") and args.num_local_iters < 1:
         args.num_local_iters = 1
+    if getattr(args, "split_compile", False):
+        raise SystemExit(
+            "--split_compile was removed in PR 29: the fused round compiles "
+            "and runs on the chip; drop the flag")
     if getattr(args, "share_ps_gpu", False) or getattr(args, "port", 0):
         print("note: --share_ps_gpu/--port are reference-CLI compatibility "
               "no-ops (the TPU engine has no worker processes)", flush=True)
@@ -777,11 +779,6 @@ def resolve_defaults(args: argparse.Namespace) -> argparse.Namespace:
             raise SystemExit(
                 "--health_every computes SKETCH-wire quality estimators; "
                 f"--mode {args.mode} has no table to estimate from")
-        if getattr(args, "split_compile", False):
-            raise SystemExit(
-                "--health_every is fused-paths-only (the split program "
-                "boundary does not thread the estimator metrics); drop "
-                "--split_compile")
     if getattr(args, "slo_rules", "") and getattr(args, "slo", "off") == "off":
         raise SystemExit(
             "--slo_rules names rules for the SLO engine; arm it with "

@@ -129,7 +129,6 @@ def build(args, fault_plan=None, retry_policy=None):
                      if args.merge_policy == "sum"
                      or (args.merge_policy == "trimmed"
                          and args.merge_trim == 0) else 0),
-        split_compile=args.split_compile,
         client_chunk=args.client_chunk,
         on_nonfinite=args.on_nonfinite,
         fault_plan=fault_plan,
@@ -137,11 +136,8 @@ def build(args, fault_plan=None, retry_policy=None):
         # sketch-health estimators compiled into the round program at the
         # --health_every cadence; --ledger adds per-round state
         # fingerprints (both read-only: armed == unarmed, bit-for-bit).
-        # Fingerprints are fused-paths-only — a split ledger run still
-        # records cohorts/counters/health, just without them.
         health_every=getattr(args, "health_every", 0),
-        ledger_fingerprint=(bool(getattr(args, "ledger", ""))
-                            and not args.split_compile),
+        ledger_fingerprint=bool(getattr(args, "ledger", "")),
         # a checkpoint dir arms the watchdog's mid-round emergency save,
         # which needs the live (non-donated) server state readable; the
         # opt-out keeps donation for HBM-tight runs

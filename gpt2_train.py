@@ -227,17 +227,14 @@ def build(args, fault_plan=None, retry_policy=None):
                      if args.merge_policy == "sum"
                      or (args.merge_policy == "trimmed"
                          and args.merge_trim == 0) else 0),
-        split_compile=args.split_compile,
         client_chunk=args.client_chunk,
         on_nonfinite=args.on_nonfinite,
         fault_plan=fault_plan,
         retry_policy=retry_policy,
         # sketch-health estimators + ledger fingerprints: read-only
-        # in-program observability (armed == unarmed bit-for-bit);
-        # fingerprints are fused-paths-only
+        # in-program observability (armed == unarmed bit-for-bit)
         health_every=getattr(args, "health_every", 0),
-        ledger_fingerprint=(bool(getattr(args, "ledger", ""))
-                            and not args.split_compile),
+        ledger_fingerprint=bool(getattr(args, "ledger", "")),
         # a checkpoint dir arms the watchdog's mid-round emergency save,
         # which needs the live (non-donated) server state readable; the
         # opt-out keeps donation for HBM-tight runs

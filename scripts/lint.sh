@@ -16,9 +16,8 @@
 # (G001–G020) are the part no generic tool covers, so that is the part
 # that must never be skippable by accident.
 #
-# The machine-readable report is archived next to the bench JSONs
-# (GRAFTLINT.json at the repo root) so CI and the driver can
-# diff rule counts across PRs the same way they diff bench numbers.
+# The machine-readable report is archived at the repo root
+# (GRAFTLINT.json) so CI and the driver can diff rule counts across PRs.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 
@@ -28,13 +27,13 @@ if [[ "${LINT_SKIP:-0}" == "1" ]]; then
 fi
 
 fail=0
-LINT_PATHS=(commefficient_tpu cv_train.py gpt2_train.py bench.py)
+LINT_PATHS=(commefficient_tpu cv_train.py gpt2_train.py chip_smoke.py)
 
 echo "== graftlint (commefficient_tpu/analysis) =="
-# one analysis run: human text on stdout, the JSON report archived next to
-# the bench JSONs (also on failure — the archive is how a red gate is
-# triaged). The report is deterministic (no timestamps), so a clean tree
-# leaves the checked-in copy byte-identical.
+# one analysis run: human text on stdout, the JSON report archived at the
+# repo root (also on failure — the archive is how a red gate is triaged).
+# The report is deterministic (no timestamps), so a clean tree leaves the
+# checked-in copy byte-identical.
 python -m commefficient_tpu.analysis "${LINT_PATHS[@]}" \
     --jobs "${LINT_JOBS:-0}" \
     --report-json GRAFTLINT.json || fail=1

@@ -242,26 +242,28 @@ def test_trimmed_zero_is_sum_bitwise_payload():
     np.testing.assert_array_equal(flat_params(a), flat_params(b))
 
 
-def test_robust_policy_validation():
-    with pytest.raises(ValueError, match="mode='sketch'"):
-        engine.EngineConfig(
-            mode=ModeConfig(mode="uncompressed", d=8, momentum_type="none",
-                            error_type="none"),
-            merge_policy="median")
-    with pytest.raises(ValueError, match="merge_trim"):
-        engine.EngineConfig(
-            mode=ModeConfig(mode="sketch", d=8, k=2, num_rows=2, num_cols=4),
-            merge_policy="median", merge_trim=1)
-    with pytest.raises(ValueError, match="ravel"):
-        make_session(merge_policy="median", sketch_path="layerwise")
-    with pytest.raises(ValueError, match="split_compile|table-round"):
-        make_session(merge_policy="median", split_compile=True)
+_SKETCH_8 = dict(mode="sketch", d=8, k=2, num_rows=2, num_cols=4)
+
+
+@pytest.mark.parametrize("match, build", [
+    ("mode='sketch'", lambda: engine.EngineConfig(
+        mode=ModeConfig(mode="uncompressed", d=8, momentum_type="none",
+                        error_type="none"),
+        merge_policy="median")),
+    ("merge_trim", lambda: engine.EngineConfig(
+        mode=ModeConfig(**_SKETCH_8), merge_policy="median", merge_trim=1)),
+    ("ravel", lambda: make_session(merge_policy="median",
+                                   sketch_path="layerwise")),
     # the linear builders refuse a robust cfg outright
-    cfg = engine.EngineConfig(
-        mode=ModeConfig(mode="sketch", d=8, k=2, num_rows=2, num_cols=4),
-        merge_policy="trimmed", merge_trim=1)
-    with pytest.raises(ValueError, match="make_payload_round_steps"):
-        engine.make_round_step(quad_loss, cfg)
+    ("make_payload_round_steps", lambda: engine.make_round_step(
+        quad_loss, engine.EngineConfig(
+            mode=ModeConfig(**_SKETCH_8), merge_policy="trimmed",
+            merge_trim=1))),
+], ids=["non_sketch_mode", "median_with_trim", "layerwise_path",
+        "linear_builder"])
+def test_robust_policy_validation(match, build):
+    with pytest.raises(ValueError, match=match):
+        build()
 
 
 def test_robust_session_falls_back_to_per_round_blocks():
@@ -290,6 +292,20 @@ def test_robust_merge_shard_invariant(policy, kw):
 
 
 def test_robust_merge_mesh_matches_single_device():
+    """The robust table round on a four-device mesh (one client a device)
+    equals the same shard-structured program on one device
+    (`client_shards=4`) bit for bit: params, server state and every metric
+    row. Against the UNSHARDED one-device session it is pinned to last-bit
+    tolerance, because those two are different programs by construction:
+    the per-client gradients and tables are computed under a vmap of width 1
+    on each device there and of width 4 here, and XLA:CPU vectorises the
+    same per-client subgraph differently at the two widths (the class
+    `test_sharded_round.py::test_sharded_mesh_bit_identical_to_single_device`
+    names; width 2 against 4 happens to come out bitwise, see
+    `test_robust_merge_shard_invariant`). It shows from round 0 with or
+    without the attack. Measured gap on this toolchain (jax 0.9.0): 3 of 21
+    params after three rounds, 1.5e-8 abs, 2.6e-7 rel; `loss_sum` of round 0
+    differs by one ulp (13.859041 / 13.859042), every count is equal."""
     from commefficient_tpu.parallel import mesh as meshlib
 
     if jax.device_count() < 4:
@@ -298,10 +314,25 @@ def test_robust_merge_mesh_matches_single_device():
     plan = "client_collude@1:frac=0.5"
     a = make_session(merge_policy="median",
                      fault_plan=FaultPlan.parse(plan))
+    r = make_session(merge_policy="median", client_shards=4,
+                     fault_plan=FaultPlan.parse(plan))
     b = make_session(merge_policy="median", mesh=mesh,
                      fault_plan=FaultPlan.parse(plan))
-    run(a, 3), run(b, 3)
-    np.testing.assert_array_equal(flat_params(a), flat_params(b))
+    ma, mr, mb = run(a, 3), run(r, 3), run(b, 3)
+    assert mr == mb
+    np.testing.assert_array_equal(flat_params(r), flat_params(b))
+    for x, y in zip(jax.tree.leaves(jax.device_get(r.state["mode_state"])),
+                    jax.tree.leaves(jax.device_get(b.state["mode_state"]))):
+        np.testing.assert_array_equal(x, y)
+    np.testing.assert_allclose(flat_params(a), flat_params(b),
+                               rtol=2e-7, atol=1e-8)
+    for row_a, row_b in zip(ma, mb):
+        assert set(row_a) == set(row_b)
+        for k in row_a:
+            if k == "loss_sum":
+                np.testing.assert_allclose(row_a[k], row_b[k], rtol=2e-7)
+            else:
+                assert row_a[k] == row_b[k], k
 
 
 # ------------------------------------------------------- adversarial suite
@@ -579,9 +610,6 @@ def test_layer_scope_quarantines_poison_on_payload_and_sharded_paths():
 def test_layer_scope_validation():
     with pytest.raises(ValueError, match="client_update_clip"):
         make_session(quarantine_scope="layer")
-    with pytest.raises(ValueError, match="fused-paths-only"):
-        make_session(client_update_clip=3.0, quarantine_scope="layer",
-                     split_compile=True)
 
 
 # --------------------------------------- quarantine window, sharded/payload

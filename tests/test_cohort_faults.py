@@ -92,8 +92,19 @@ def _with_valid(batch, valid):
 
 def test_masked_round_bit_identical_to_surviving_cohort_fused():
     """THE acceptance pin, fused path: kill clients {2, 5} of an 8-cohort via
-    the validity mask -> params AND every metric bit-equal to the round
-    sampled with just the 6 survivors."""
+    the validity mask -> params, server state AND every metric bit-equal to
+    the round sampled with just the 6 survivors, when that round carries an
+    all-ones mask of its own.
+
+    Against the 6 survivors with NO mask, metrics and counts stay bitwise and
+    params are pinned to last-bit tolerance, because the two sides are then
+    different programs by construction: with no mask and no dropout the
+    participation vector is a compile-time constant, so the survivor count is
+    the literal 6.0 and XLA:CPU turns `wsum / 6.0` into a multiply by the
+    rounded reciprocal, where a masked round divides by a count it reads at
+    run time. The cohort reduce itself is bit-equal at vmap widths 8 and 6.
+    Measured gap on this toolchain (jax 0.9.0): 3 of 44 params, 7.5e-9 abs,
+    1.1e-7 rel; 21 of 3072 Vvelocity entries, 3.0e-8 abs."""
     W, dead = 8, [2, 5]
     params, cfg = _cfg(client_chunk=1)
     batch = _batch(jax.random.PRNGKey(1), W)
@@ -102,22 +113,28 @@ def test_masked_round_bit_identical_to_surviving_cohort_fused():
     lr, rng = jnp.float32(0.1), jax.random.PRNGKey(7)
 
     step = jax.jit(engine.make_round_step(quad_loss, cfg))
-    s_m = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    s_m, _, m_m = step(s_m, _with_valid(batch, valid), {}, lr, rng)
 
-    surv = np.flatnonzero(valid).tolist()
-    ref_batch = jax.tree.map(lambda a: a[np.asarray(surv)], batch)
-    ref_step = jax.jit(engine.make_round_step(quad_loss, cfg))
-    s_r = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    s_r, _, m_r = ref_step(s_r, ref_batch, {}, lr, rng)
+    def one_round(b):
+        s = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
+        s, _, m = step(s, b, {}, lr, rng)
+        return s, m
+
+    s_m, m_m = one_round(_with_valid(batch, valid))
+    surv = np.flatnonzero(valid)
+    ref_batch = jax.tree.map(lambda a: a[surv], batch)
+    s_r, m_r = one_round(_with_valid(ref_batch, np.ones(len(surv))))
+    s_u, m_u = one_round(ref_batch)
 
     np.testing.assert_array_equal(_flat(s_m), _flat(s_r))
     for a, b in zip(jax.tree.leaves(s_m["mode_state"]),
                     jax.tree.leaves(s_r["mode_state"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert set(m_m) == set(m_r)
+    np.testing.assert_allclose(_flat(s_m), _flat(s_u), rtol=2e-7, atol=1e-8)
+    assert set(m_m) == set(m_r) == set(m_u)
     for k in m_r:
         np.testing.assert_array_equal(np.asarray(m_m[k]), np.asarray(m_r[k]),
+                                      err_msg=k)
+        np.testing.assert_array_equal(np.asarray(m_m[k]), np.asarray(m_u[k]),
                                       err_msg=k)
     assert float(m_m["participants"]) == float(len(surv))
 
@@ -289,35 +306,6 @@ def test_quarantine_clean_run_untouched():
                                        err_msg=k)
     np.testing.assert_allclose(_flat(s_off), _flat(s_on), rtol=1e-6,
                                atol=1e-7)
-
-
-def test_quarantine_split_matches_fused():
-    """The two-program split round threads the quarantine verdict + running
-    median across the program boundary (metrics['quarantine_median'] ->
-    server qmed): params stay bit-equal to the fused step with a poisoned
-    client in the cohort."""
-    W, bad = 8, 2
-    params, cfg = _cfg(client_update_clip=10.0)
-    b0 = _batch(jax.random.PRNGKey(8), W)
-    b1 = _poison_rows(_batch(jax.random.PRNGKey(9), W), bad, 1e3)
-    lr = jnp.float32(0.1)
-
-    fused = jax.jit(engine.make_round_step(quad_loss, cfg))
-    client_p, server_p = engine.make_split_round_step(quad_loss, cfg)
-    split = engine.compose_split(jax.jit(client_p), jax.jit(server_p))
-    s_f = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    s_s = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    for b, seed in ((b0, 40), (b1, 41)):
-        rng = jax.random.PRNGKey(seed)
-        s_f, _, m_f = fused(s_f, b, {}, lr, rng)
-        s_s, _, m_s = split(s_s, b, {}, lr, rng)
-        assert float(m_f["clients_quarantined"]) == float(
-            m_s["clients_quarantined"])
-    assert float(m_f["clients_quarantined"]) == 1.0
-    np.testing.assert_array_equal(_flat(s_f), _flat(s_s))
-    np.testing.assert_array_equal(
-        np.asarray(s_f["quarantine"]["median"]),
-        np.asarray(s_s["quarantine"]["median"]))
 
 
 def test_quarantine_sharded_mesh_matches_reference():
@@ -539,12 +527,3 @@ def test_quarantine_window_tolerates_one_collapsed_round():
             s, _, last = step(s, b, {}, lr, jax.random.PRNGKey(90 + r))
         assert float(last["clients_quarantined"]) == expect_quarantined, (
             cfg.quarantine_window, float(last["clients_quarantined"]))
-
-
-def test_quarantine_window_rejected_on_split_compile_paths():
-    """The split-compile program boundary threads ONE scalar median; a
-    K-slot ring cannot cross it — the combination must fail loudly at
-    build time, not silently run window=1."""
-    params, cfg = _cfg(client_update_clip=10.0, quarantine_window=4)
-    with pytest.raises(ValueError, match="fused-paths-only"):
-        engine.make_split_round_step(quad_loss, cfg)

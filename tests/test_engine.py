@@ -401,101 +401,35 @@ def test_sharded_eval_matches_unsharded():
         np.testing.assert_allclose(got_hybrid[k], ref[k], rtol=1e-5)
 
 
-@pytest.mark.parametrize("mode_kw, eng_kw", [
-    (dict(mode="sketch", k=16, num_rows=3, num_cols=1024,
-          hash_family="rotation", momentum_type="virtual", error_type="virtual"),
-     {}),
-    (dict(mode="uncompressed", d=0, momentum_type="virtual", error_type="none"),
-     dict(dp_clip=1.0, dp_noise=0.5, client_dropout=0.3)),
-    # chunked client phase under the split engine: the composition the
-    # GPT-2-scale bench relies on (BENCH_CLIENT_CHUNK + split compile)
-    (dict(mode="sketch", k=16, num_rows=3, num_cols=1024,
-          hash_family="rotation", momentum_type="virtual", error_type="virtual"),
-     dict(client_chunk=4)),
-])
-def test_split_round_step_matches_fused(mode_kw, eng_kw):
-    """The two-program split (Mosaic-isolating) round must equal the fused
-    step bit-for-bit: same rng streams, same linear-mode shortcut — including
-    under DP noise + dropout, whose sensitivity scaling crosses the program
-    boundary as the participants scalar."""
-    W = 8
-    data = _data(jax.random.PRNGKey(1), W * 4)
-    batch = jax.tree.map(lambda a: a.reshape((W, 4) + a.shape[1:]), data)
-    lr = jnp.float32(0.1)
+@pytest.mark.parametrize("where", ["cv", "gpt2", "session"])
+def test_split_compile_stub_rejects(where):
+    """`--split_compile` / `split_compile=` went in PR 29. What is left is a
+    stub that benchmark/builders/common.py still names: the parser takes the
+    flag (default false) and `resolve_defaults` exits on it, the session takes
+    the keyword and raises on it, both with the same words."""
+    words = "removed in PR 29"
+    if where == "session":
+        from commefficient_tpu.federated.api import FederatedSession
 
-    cfg, state_f, fused = _make(dict(mode_kw), wd=5e-4, **eng_kw)
-    _, state_s, _ = _make(dict(mode_kw), wd=5e-4, **eng_kw)
-    client_p, server_p = engine.make_split_round_step(mlp_loss, cfg)
-    cstep = jax.jit(client_p)
-    sstep = jax.jit(server_p, donate_argnums=(0,))
+        with pytest.raises(ValueError, match=words):
+            FederatedSession(
+                train_loss_fn=mlp_loss, eval_loss_fn=mlp_loss, params={},
+                net_state={}, mode_cfg=None, train_set=None, num_workers=8,
+                local_batch_size=2, split_compile=True)
+        return
+    from commefficient_tpu.utils.config import make_parser, resolve_defaults
 
-    for i in range(3):
-        rng = jax.random.PRNGKey(10 + i)
-        state_f, _, m_f = fused(state_f, batch, {}, lr, rng)
-        weighted, nns, m_s, nrng = cstep(state_s, batch, lr, rng)
-        state_s = sstep(state_s, weighted, nns, m_s["participants"], lr, nrng)
-        assert float(m_f["loss_sum"]) == float(m_s["loss_sum"])
-        assert float(m_f["participants"]) == float(m_s["participants"])
-    for a, b in zip(jax.tree.leaves(state_f["params"]), jax.tree.leaves(state_s["params"])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(jax.tree.leaves(state_f["mode_state"]), jax.tree.leaves(state_s["mode_state"])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_split_round_step_rejects_nonlinear_scope():
-    params = init_mlp(jax.random.PRNGKey(0))
-    d = ravel_pytree(params)[0].size
-    for kw in (
-        dict(mode="local_topk", d=d, k=8, momentum_type="none", error_type="local",
-             num_clients=4),
-        dict(mode="fedavg", d=d, num_local_iters=2, error_type="none",
-             momentum_type="none"),
-    ):
-        cfg = engine.EngineConfig(mode=ModeConfig(**kw))
-        with pytest.raises(ValueError, match="fused"):
-            engine.make_split_round_step(mlp_loss, cfg)
-
-
-def test_split_session_matches_fused_session():
-    """FederatedSession(split_compile=True) runs the same rounds as the fused
-    session — sampling, metrics, comm accounting, and params all equal."""
-    from commefficient_tpu.data.fed_dataset import FedDataset, shard_iid
-    from commefficient_tpu.federated.api import FederatedSession
-
-    rngd = np.random.RandomState(0)
-    n = 64
-    x = rngd.normal(size=(n, 10)).astype(np.float32)
-    y = rngd.randint(0, 4, size=n).astype(np.int32)
-
-    def make(split):
-        params = init_mlp(jax.random.PRNGKey(0))
-        d = ravel_pytree(params)[0].size
-        return FederatedSession(
-            train_loss_fn=mlp_loss, eval_loss_fn=mlp_loss,
-            params=jax.tree.map(jnp.copy, params), net_state={},
-            mode_cfg=ModeConfig(mode="sketch", d=d, k=16, num_rows=3,
-                                num_cols=1024, hash_family="rotation",
-                                momentum_type="virtual", error_type="virtual"),
-            train_set=FedDataset(x, y, shard_iid(n, 16, np.random.RandomState(1))),
-            num_workers=8, local_batch_size=2, seed=7, split_compile=split,
-        )
-
-    a, b = make(False), make(True)
-    for _ in range(3):
-        ma = a.run_round(0.1)
-        mb = b.run_round(0.1)
-        assert ma == mb
-    np.testing.assert_array_equal(
-        np.asarray(ravel_pytree(a.state["params"])[0]),
-        np.asarray(ravel_pytree(b.state["params"])[0]),
-    )
+    parser = make_parser(where)
+    assert parser.parse_args([]).split_compile is False
+    with pytest.raises(SystemExit, match=words):
+        resolve_defaults(parser.parse_args(["--split_compile"]))
 
 
 @pytest.mark.parametrize("chunk", [2, 4, 8])
 def test_client_chunked_reduce_matches_unchunked(chunk):
     """cfg.client_chunk scans the grads in chunks accumulating additively —
-    equal to the one-shot vmap up to fp summation order, for both the fused
-    and the split step, with dropout active."""
+    equal to the one-shot vmap up to fp summation order, with dropout
+    active."""
     W = 8
     data = _data(jax.random.PRNGKey(1), W * 4)
     batch = jax.tree.map(lambda a: a.reshape((W, 4) + a.shape[1:]), data)
@@ -503,8 +437,8 @@ def test_client_chunked_reduce_matches_unchunked(chunk):
     kw = dict(mode="sketch", k=16, num_rows=3, num_cols=1024,
               hash_family="rotation", momentum_type="virtual", error_type="virtual")
 
-    cfg0, s0, step0 = _make(dict(kw), wd=5e-4, client_dropout=0.3)
-    cfgC, sC, stepC = _make(dict(kw), wd=5e-4, client_dropout=0.3,
+    _, s0, step0 = _make(dict(kw), wd=5e-4, client_dropout=0.3)
+    _, sC, stepC = _make(dict(kw), wd=5e-4, client_dropout=0.3,
                             client_chunk=chunk)
     a, _, ma = step0(s0, batch, {}, lr, rng)
     b, _, mb = stepC(sC, batch, {}, lr, rng)
@@ -513,19 +447,6 @@ def test_client_chunked_reduce_matches_unchunked(chunk):
     np.testing.assert_allclose(
         np.asarray(ravel_pytree(a["params"])[0]),
         np.asarray(ravel_pytree(b["params"])[0]), rtol=1e-5, atol=1e-7,
-    )
-
-    # split step honors the same knob
-    client_p, server_p = engine.make_split_round_step(
-        mlp_loss, engine.EngineConfig(mode=ModeConfig(**{**kw, "d": cfg0.mode.d}),
-                                      weight_decay=5e-4, client_dropout=0.3,
-                                      client_chunk=chunk))
-    _, sS, _ = _make(dict(kw), wd=5e-4, client_dropout=0.3)
-    w, nns, ms, nrng = jax.jit(client_p)(sS, batch, lr, rng)
-    sS = jax.jit(server_p)(sS, w, nns, ms["participants"], lr, nrng)
-    np.testing.assert_allclose(
-        np.asarray(ravel_pytree(a["params"])[0]),
-        np.asarray(ravel_pytree(sS["params"])[0]), rtol=1e-5, atol=1e-7,
     )
 
 

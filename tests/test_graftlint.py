@@ -470,7 +470,8 @@ def test_g016_ring_write_is_a_declaration_not_a_loophole():
 def test_g016_scope_is_fastpath_modules_only():
     """np.stack is the serve/ slow path's bread and butter — the rule must
     stay silent outside the declared fast-path modules (the assembler's
-    stack copy is the thing the bench COMPARES against, not a bug)."""
+    stack copy is the slow path the fast path is compared against, not a
+    bug)."""
     import tempfile
 
     src = (
@@ -566,9 +567,9 @@ def test_shipped_baseline_has_no_parity_leaf_or_ckpt_entries():
     assert not banned, f"baseline grandfathers banned codes: {banned}"
 
 
-def test_clis_and_bench_are_clean():
+def test_clis_are_clean():
     paths = [os.path.join(REPO, f)
-             for f in ("cv_train.py", "gpt2_train.py", "bench.py")]
+             for f in ("cv_train.py", "gpt2_train.py", "chip_smoke.py")]
     result = Analyzer().run(paths)
     assert result.ok, [v.format() for v in result.violations]
 

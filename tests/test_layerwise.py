@@ -7,7 +7,7 @@ The bit-identity contract under test: `sketch_path="layerwise"` folds each
 layer's gradient block into the running r x c table (sketch/layerwise.py)
 instead of raveling the pytree into a flat [d] vector first, and produces
 the IDENTICAL BITS — params, server mode state, and every logged metric —
-across the fused, split, sharded (mesh == single-device reference), and
+across the fused, sharded (mesh == single-device reference), and
 checkpoint+resume paths. The foundation is csvec._sketch_vec_rotation's
 explicit slab-order left fold: per bucket both paths perform the same
 ordered float sum (boundary slabs split across two leaves contribute an
@@ -232,19 +232,6 @@ def test_layerwise_fused_bit_identical_to_ravel(name, eng_kw):
     _assert_bitwise(ref, got)
 
 
-def test_layerwise_split_bit_identical_to_ravel_and_fused():
-    params, cfg_r = _cfg({}, "ravel")
-    _, cfg_l = _cfg({}, "layerwise")
-    split = lambda c: engine.compose_split(  # noqa: E731
-        *engine.make_split_round_step(mlp_loss, c))
-    ref_split = _run_steps(split, params, cfg_r)
-    lw_split = _run_steps(split, params, cfg_l)
-    lw_fused = _run_steps(lambda c: engine.make_round_step(mlp_loss, c),
-                          params, cfg_l)
-    _assert_bitwise(ref_split, lw_split)
-    _assert_bitwise(lw_split, lw_fused)
-
-
 def test_layerwise_sharded_bit_identical_to_ravel():
     """Sharded acceptance: on the 8-device mesh the layerwise round ==
     the ravel round bit-for-bit (same program shape, same ordered table
@@ -273,24 +260,6 @@ def test_layerwise_sharded_bit_identical_to_ravel():
         np.testing.assert_allclose(
             np.asarray(ref_l[0]["mode_state"][k]),
             np.asarray(mesh_l[0]["mode_state"][k]), rtol=0, atol=1e-7)
-
-
-def test_layerwise_sharded_split_bit_identical_to_sharded_fused():
-    """The sharded split pair (table crosses the program boundary instead
-    of a [S, d] dense stack) == the sharded fused layerwise program, and
-    == the ravel sharded split, all on the same mesh."""
-    mesh = meshlib.make_mesh(8)
-    params, cfg_l = _cfg({}, "layerwise", shards=8)
-    _, cfg_r = _cfg({}, "ravel", shards=8)
-    split = lambda c: engine.compose_split(  # noqa: E731
-        *engine.make_sharded_split_round_step(mlp_loss, c, mesh))
-    lw_split = _run_steps(split, params, cfg_l, W=16)
-    rv_split = _run_steps(split, params, cfg_r, W=16)
-    lw_fused = _run_steps(
-        lambda c: engine.make_sharded_round_step(mlp_loss, c, mesh),
-        params, cfg_l, W=16)
-    _assert_bitwise(rv_split, lw_split)
-    _assert_bitwise(lw_fused, lw_split)
 
 
 def test_layerwise_dead_client_nan_inert():
@@ -385,8 +354,7 @@ def _mlp_dataset(n=64, seed=0):
     return FedDataset(x, y, shard_iid(n, 16, np.random.RandomState(1)))
 
 
-def _session(sketch_path="ravel", mesh=None, client_shards=0, split=False,
-             **kw):
+def _session(sketch_path="ravel", mesh=None, client_shards=0, **kw):
     params = init_mlp(jax.random.PRNGKey(0))
     d = ravel_pytree(params)[0].size
     return FederatedSession(
@@ -394,7 +362,7 @@ def _session(sketch_path="ravel", mesh=None, client_shards=0, split=False,
         params=jax.tree.map(jnp.copy, params), net_state={},
         mode_cfg=ModeConfig(**{**SKETCH_KW, "d": d}),
         train_set=_mlp_dataset(), num_workers=8, local_batch_size=2,
-        seed=7, mesh=mesh, client_shards=client_shards, split_compile=split,
+        seed=7, mesh=mesh, client_shards=client_shards,
         sketch_path=sketch_path, **kw,
     )
 
@@ -416,20 +384,15 @@ def test_layerwise_session_bit_identical_to_ravel_session():
 
 
 def test_layerwise_session_mesh_and_split():
-    """Layerwise over the 8-way mesh session == ravel over the same mesh,
-    and the split-compile layerwise mesh session matches both — every row
-    and the params bitwise."""
+    """Layerwise over the 8-way mesh session == ravel over the same mesh:
+    every row and the params bitwise."""
     a = _session("ravel", mesh=meshlib.make_mesh(8))
     b = _session("layerwise", mesh=meshlib.make_mesh(8))
-    c = _session("layerwise", mesh=meshlib.make_mesh(8), split=True)
     for _ in range(2):
-        ma, mb, mc = a.run_round(0.1), b.run_round(0.1), c.run_round(0.1)
-        assert ma == mb == mc
-    pa = np.asarray(ravel_pytree(a.state["params"])[0])
+        assert a.run_round(0.1) == b.run_round(0.1)
     np.testing.assert_array_equal(
-        pa, np.asarray(ravel_pytree(b.state["params"])[0]))
-    np.testing.assert_array_equal(
-        pa, np.asarray(ravel_pytree(c.state["params"])[0]))
+        np.asarray(ravel_pytree(a.state["params"])[0]),
+        np.asarray(ravel_pytree(b.state["params"])[0]))
 
 
 def test_layerwise_checkpoint_resume_bit_identical(tmp_path):

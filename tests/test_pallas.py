@@ -230,16 +230,17 @@ def test_probe_status_reports_layouts(monkeypatch):
         "probed": True, "layouts": ["c=1024,r=3", "c=2048,r=5"]}
 
 
-def test_engine_round_step_with_pallas_kernels(monkeypatch):
+@pytest.mark.parametrize("client_chunk", [0, 2])
+def test_engine_round_step_with_pallas_kernels(monkeypatch, client_chunk):
     """The EXACT composition that runs on hardware: the full federated round
-    step (client grads -> aggregate -> sketch -> virtual momentum/error ->
-    unsketch_topk) with the library routed to the Pallas kernels, pinned
-    against the oracle-engine result. COMMEFFICIENT_PALLAS_INTERPRET=1 runs
-    the kernels in the Pallas interpreter, so this passes on the CPU mesh —
-    it proves the composition traces, jits, and is numerically equal; only
-    the Mosaic/native compile of the same module needs the TPU compiler
-    (tests/test_tpu_compile.py compiles it for a described v5e;
-    chip_smoke.py runs it on the chip)."""
+    step (client grads, in one vmap or a `client_chunk` scan -> aggregate ->
+    sketch -> virtual momentum/error -> unsketch_topk) with the library
+    routed to the Pallas kernels, pinned against the oracle-engine result.
+    COMMEFFICIENT_PALLAS_INTERPRET=1 runs the kernels in the Pallas
+    interpreter, so this passes on the CPU mesh — it proves the composition
+    traces, jits, and is numerically equal; only the Mosaic/native compile
+    of the same module needs the TPU compiler (tests/test_tpu_compile.py
+    compiles it for a described v5e; chip_smoke.py runs it on the chip)."""
     from jax.flatten_util import ravel_pytree
 
     from commefficient_tpu.federated import engine
@@ -262,7 +263,8 @@ def test_engine_round_step_with_pallas_kernels(monkeypatch):
             monkeypatch.setenv("COMMEFFICIENT_PALLAS_INTERPRET", "1")
         else:
             monkeypatch.delenv("COMMEFFICIENT_PALLAS_INTERPRET", raising=False)
-        cfg = engine.EngineConfig(mode=ModeConfig(**kw))
+        cfg = engine.EngineConfig(mode=ModeConfig(**kw),
+                                  client_chunk=client_chunk)
         assert csvec._use_pallas(cfg.mode.sketch_spec) == pallas
         state = engine.init_server_state(
             cfg, jax.tree.map(jnp.copy, params), {}
@@ -275,49 +277,4 @@ def test_engine_round_step_with_pallas_kernels(monkeypatch):
         return ravel_pytree(state["params"])[0]
 
     got, want = run(pallas=True), run(pallas=False)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
-
-
-def test_split_engine_with_pallas_kernels(monkeypatch):
-    """The wedge-avoidance composition for hardware: the SPLIT round (client
-    grads | sketch server step) with the library routed to the Pallas kernels
-    — only the small server program carries Mosaic custom-calls. Pinned
-    against the fused oracle engine via the interpreter on the CPU mesh."""
-    from jax.flatten_util import ravel_pytree
-
-    from commefficient_tpu.federated import engine
-    from commefficient_tpu.modes.config import ModeConfig
-
-    from test_engine import _data, init_mlp, mlp_loss
-
-    params = init_mlp(jax.random.PRNGKey(0), din=64, dh=128)
-    d = ravel_pytree(params)[0].size
-    data = _data(jax.random.PRNGKey(1), 24, din=64)
-    batch = jax.tree.map(lambda a: a.reshape((4, 6) + a.shape[1:]), data)
-    kw = dict(
-        mode="sketch", d=d, k=32, num_rows=3, num_cols=1024,
-        hash_family="rotation", momentum_type="virtual", error_type="virtual",
-    )
-
-    def run(split_pallas: bool):
-        if split_pallas:
-            monkeypatch.setenv("COMMEFFICIENT_PALLAS_INTERPRET", "1")
-        else:
-            monkeypatch.delenv("COMMEFFICIENT_PALLAS_INTERPRET", raising=False)
-        cfg = engine.EngineConfig(mode=ModeConfig(**kw))
-        state = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-        lr = jnp.float32(0.1)
-        if split_pallas:
-            client_p, server_p = engine.make_split_round_step(mlp_loss, cfg)
-            cstep, sstep = jax.jit(client_p), jax.jit(server_p)
-            for i in range(3):
-                w, nns, met, nrng = cstep(state, batch, lr, jax.random.PRNGKey(i))
-                state = sstep(state, w, nns, met["participants"], lr, nrng)
-        else:
-            step = jax.jit(engine.make_round_step(mlp_loss, cfg))
-            for i in range(3):
-                state, _, _ = step(state, batch, {}, lr, jax.random.PRNGKey(i))
-        return ravel_pytree(state["params"])[0]
-
-    got, want = run(True), run(False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)

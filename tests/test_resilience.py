@@ -144,8 +144,8 @@ def test_eval_stall_site_sleeps_once_on_scheduled_round():
 
 
 def test_retry_counts_surface_failed_attempts():
-    """Chaos runs are benchmarkable: every failed attempt bumps the per-site
-    process counter bench.py publishes in its JSON."""
+    """Chaos runs are countable: every failed attempt bumps the per-site
+    process counter that `retry_counts()` returns."""
     from commefficient_tpu.resilience import reset_retry_counts, retry_counts
 
     reset_retry_counts()

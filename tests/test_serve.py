@@ -983,24 +983,6 @@ def test_served_payload_round_over_socket_matches_inproc():
     _assert_params_equal(a, b)
 
 
-def test_payload_session_rejects_split_compile():
-    """wire_payloads IS a two-program round; stacking --split_compile on it
-    would silently pick a different program pair — reject at build."""
-    with pytest.raises(ValueError, match="two-program"):
-        rs = np.random.RandomState(0)
-        x = rs.randn(32, 6).astype(np.float32)
-        y = np.zeros(32, np.int32)
-        train = FedDataset(x, y, shard_iid(32, 4, np.random.RandomState(1)))
-        params = {"w": jnp.zeros((6, 3)), "b": jnp.zeros(3)}
-        FederatedSession(
-            train_loss_fn=_quad_loss, eval_loss_fn=_quad_loss,
-            params=params, net_state={},
-            mode_cfg=ModeConfig(mode="sketch", d=21, k=4, num_rows=3,
-                                num_cols=8),
-            train_set=train, num_workers=2, local_batch_size=4,
-            wire_payloads=True, split_compile=True)
-
-
 def test_serve_payload_mode_requires_wire_payload_session():
     a = _tiny_session()  # announce-shaped session (wire_payloads off)
     with pytest.raises(ValueError, match="wire_payloads"):

@@ -103,7 +103,7 @@ def test_sharded_mesh_bit_identical_to_single_device(name, mode_kw, eng_kw):
     differs between a while-loop body (the reference's lax.map) and the
     inlined shard_map body, leaving ~1e-9 on a handful of sketch-table
     entries — params and metrics still come out bit-equal, and everything
-    structure-matched (hybrid vs flat mesh, split vs fused, block vs
+    structure-matched (hybrid vs flat mesh, block vs
     sequential, checkpoint resume) is pinned fully bitwise below."""
     mesh = meshlib.make_mesh(8)
     params, cfg = _cfg(mode_kw, eng_kw)
@@ -133,14 +133,25 @@ def test_sharded_mesh_bit_identical_to_single_device(name, mode_kw, eng_kw):
                                    rtol=2e-7, atol=1e-8)
 
 
-@pytest.mark.parametrize("name, mode_kw, eng_kw", MODE_CASES[:2],
-                         ids=[c[0] for c in MODE_CASES[:2]])
+# The sharded round against the plain one, over every mode case and the two
+# sketch configurations the split-compile pins ran until PR 29 (a chunked
+# client scan; the layerwise sketch path): what ROADMAP D1 (one client
+# program, one merge, one server program) will stand on.
+PLAIN_CASES = MODE_CASES + [
+    ("sketch_chunk4", dict(SKETCH_KW), dict(client_chunk=4)),
+    ("sketch_layerwise", dict(SKETCH_KW), dict(sketch_path="layerwise")),
+]
+
+
+@pytest.mark.parametrize("name, mode_kw, eng_kw", PLAIN_CASES,
+                         ids=[c[0] for c in PLAIN_CASES])
 def test_sharded_allclose_to_plain_round(name, mode_kw, eng_kw):
     """Across shard counts the round changes only by fp summation order: the
-    S=8 sharded round stays allclose to the plain (S=1) round."""
+    S=8 sharded round stays allclose to the plain (S=1) round. A chunked
+    case takes a cohort of two chunks a shard, so both sides scan."""
     params, cfg = _cfg(mode_kw, eng_kw)
     cfg1 = dataclasses.replace(cfg, client_shards=1)
-    W = 16
+    W = 16 * max(1, eng_kw.get("client_chunk", 0))
     data = _data(jax.random.PRNGKey(2), W * 4)
     batch = jax.tree.map(lambda a: a.reshape((W, 4) + a.shape[1:]), data)
     lr, rng = jnp.float32(0.1), jax.random.PRNGKey(7)
@@ -157,35 +168,6 @@ def test_sharded_allclose_to_plain_round(name, mode_kw, eng_kw):
     assert float(m_s["participants"]) == float(m_p["participants"])
     np.testing.assert_allclose(float(m_s["loss_sum"]), float(m_p["loss_sum"]),
                                rtol=1e-6)
-
-
-def test_sharded_split_bit_identical_to_sharded_fused():
-    """The Mosaic-isolating two-program sharded round (partials stay
-    device-resident across the program boundary) equals the fused shard_map
-    round bit-for-bit."""
-    mesh = meshlib.make_mesh(8)
-    params, cfg = _cfg(dict(SKETCH_KW), dict(client_dropout=0.25,
-                                             on_nonfinite="skip"))
-    W = 16
-    data = _data(jax.random.PRNGKey(3), W * 4)
-    batch = meshlib.shard_client_batch(
-        mesh, jax.tree.map(lambda a: a.reshape((W, 4) + a.shape[1:]), data))
-    lr = jnp.float32(0.1)
-
-    fused = jax.jit(engine.make_sharded_round_step(mlp_loss, cfg, mesh))
-    client_p, server_p = engine.make_sharded_split_round_step(
-        mlp_loss, cfg, mesh)
-    split = engine.compose_split(jax.jit(client_p), jax.jit(server_p))
-    s_f = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    s_s = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    for i in range(3):
-        rng = jax.random.PRNGKey(50 + i)
-        s_f, _, m_f = fused(s_f, batch, {}, lr, rng)
-        s_s, _, m_s = split(s_s, batch, {}, lr, rng)
-        for k in m_f:
-            np.testing.assert_array_equal(np.asarray(m_f[k]),
-                                          np.asarray(m_s[k]), err_msg=k)
-    np.testing.assert_array_equal(_flat(s_f), _flat(s_s))
 
 
 def test_sharded_multi_round_block_matches_sequential():
@@ -253,7 +235,7 @@ def _mlp_dataset(n=64, seed=0):
     return FedDataset(x, y, shard_iid(n, 16, np.random.RandomState(1)))
 
 
-def _session(mesh=None, client_shards=0, split=False, **kw):
+def _session(mesh=None, client_shards=0, **kw):
     params = init_mlp(jax.random.PRNGKey(0))
     d = ravel_pytree(params)[0].size
     return FederatedSession(
@@ -261,8 +243,7 @@ def _session(mesh=None, client_shards=0, split=False, **kw):
         params=jax.tree.map(jnp.copy, params), net_state={},
         mode_cfg=ModeConfig(**{**SKETCH_KW, "d": d}),
         train_set=_mlp_dataset(), num_workers=8, local_batch_size=2,
-        seed=7, mesh=mesh, client_shards=client_shards, split_compile=split,
-        **kw,
+        seed=7, mesh=mesh, client_shards=client_shards, **kw,
     )
 
 
@@ -283,17 +264,6 @@ def test_session_mesh_bit_identical_to_reference_session():
         np.asarray(ravel_pytree(b.state["params"])[0]),
     )
     assert a.comm_mb_total == b.comm_mb_total
-
-
-def test_session_split_mesh_matches_fused_mesh():
-    a = _session(mesh=meshlib.make_mesh(8), split=False)
-    b = _session(mesh=meshlib.make_mesh(8), split=True)
-    for _ in range(2):
-        assert a.run_round(0.1) == b.run_round(0.1)
-    np.testing.assert_array_equal(
-        np.asarray(ravel_pytree(a.state["params"])[0]),
-        np.asarray(ravel_pytree(b.state["params"])[0]),
-    )
 
 
 def test_session_hybrid_mesh_bit_identical_to_plain_mesh():
@@ -403,7 +373,7 @@ def test_make_mesh_from_spec():
 
 
 def test_merge_comm_bytes_headline():
-    """The comm-efficiency arithmetic bench.py's mesh section records: at
+    """The comm-efficiency arithmetic of the README's multi-chip section: at
     flagship dims the dense all-reduce costs ~d/(r*c) more than the sketch
     merge."""
     c = meshlib.merge_comm_bytes(8, r=5, c=500_000, d=6_500_000)

@@ -169,25 +169,41 @@ def test_health_in_fused_block_dispatch():
     _assert_params_equal(s, ref)
 
 
-def test_health_validation_and_split_rejection():
-    with pytest.raises(ValueError, match="health"):
-        _session(health_every=-1)
-    with pytest.raises(ValueError, match="fused-paths-only"):
-        _session(health_every=1, split_compile=True)
-    with pytest.raises(ValueError, match="sketch"):
-        rs = np.random.RandomState(0)
-        x = rs.randn(96, 6).astype(np.float32)
-        y = (x @ rs.randn(6, 3).astype(np.float32)).argmax(-1).astype(
-            np.int32)
-        FederatedSession(
-            train_loss_fn=_quad_loss, eval_loss_fn=_quad_loss,
-            params={"w": jnp.zeros((6, 3)), "b": jnp.zeros(3)},
-            net_state={},
-            mode_cfg=ModeConfig(mode="uncompressed", d=21, momentum=0.0,
-                                momentum_type="none", error_type="none"),
-            train_set=FedDataset(
-                x, y, shard_iid(96, 12, np.random.RandomState(1))),
-            num_workers=4, local_batch_size=4, health_every=1)
+def _dense_session_with_health():
+    rs = np.random.RandomState(0)
+    x = rs.randn(96, 6).astype(np.float32)
+    y = (x @ rs.randn(6, 3).astype(np.float32)).argmax(-1).astype(np.int32)
+    return FederatedSession(
+        train_loss_fn=_quad_loss, eval_loss_fn=_quad_loss,
+        params={"w": jnp.zeros((6, 3)), "b": jnp.zeros(3)}, net_state={},
+        mode_cfg=ModeConfig(mode="uncompressed", d=21, momentum=0.0,
+                            momentum_type="none", error_type="none"),
+        train_set=FedDataset(
+            x, y, shard_iid(96, 12, np.random.RandomState(1))),
+        num_workers=4, local_batch_size=4, health_every=1)
+
+
+def _cli_flags(*argv):
+    from commefficient_tpu.utils.config import make_parser, resolve_defaults
+
+    return resolve_defaults(make_parser("cv").parse_args(list(argv)))
+
+
+@pytest.mark.parametrize("exc, match, build", [
+    (ValueError, "health", lambda: _session(health_every=-1)),
+    (ValueError, "sketch", _dense_session_with_health),
+    (SystemExit, "must be >= 0",
+     lambda: _cli_flags("--mode", "sketch", "--health_every", "-1")),
+    (SystemExit, "no table to estimate from",
+     lambda: _cli_flags("--mode", "uncompressed", "--health_every", "2")),
+], ids=["session_negative", "session_dense_mode", "cli_negative",
+        "cli_dense_mode"])
+def test_health_validation_and_split_rejection(exc, match, build):
+    """`health_every` is validated where it enters: the session's keyword
+    and the trainers' flag (the `--split_compile` rejection that stood beside
+    them went with the flag in PR 29)."""
+    with pytest.raises(exc, match=match):
+        build()
 
 
 # --------------------------------------------- the recall-proxy bracket

@@ -1,8 +1,9 @@
-"""Ask the TPU compiler, without a chip: the sketch kernels and the four-device
-sharded round are compiled for a DESCRIBED v5e:2x2 topology (the installed
-libtpu compiles for a chip that is not attached). This is what interpret mode
-(tests/test_pallas.py) cannot show — Mosaic's own verdict on tiling and VMEM,
-and the SPMD partitioner's on a kernel call under a multi-device jit.
+"""Ask the TPU compiler, without a chip: the sketch kernels, the fused one-chip
+round and the four-device sharded round are compiled for a DESCRIBED v5e:2x2
+topology (the installed libtpu compiles for a chip that is not attached).
+This is what interpret mode (tests/test_pallas.py) cannot show — Mosaic's
+own verdict on tiling and VMEM, and the SPMD partitioner's on a kernel call
+under a multi-device jit.
 
 Nothing runs, so nothing here is a result or a time; `chip_smoke.py` is the
 run. On the CPU backend `pallas_kernels.eligible` takes the oracle branch, so
@@ -81,41 +82,71 @@ def _mlp_loss(params, net_state, batch, rng):
     }
 
 
+_DIN, _DH, _DOUT, _W, _B = 32, 64, 4, 8, 4
+_SKETCH = dict(mode="sketch", k=64, num_rows=3, num_cols=1024,
+               hash_family="rotation", momentum_type="virtual",
+               error_type="virtual")
+
+
+def _round_shapes(mode_kw, sharding, batch_sharding, **eng_kw):
+    """(cfg, state, batch, lr, rng) of the small MLP round as shapes on a
+    described device: small model, small supported layout — what these
+    tests guard is where the kernels land in the program, not the width."""
+    f32 = jnp.float32
+    params = {"w1": jax.ShapeDtypeStruct((_DIN, _DH), f32),
+              "b1": jax.ShapeDtypeStruct((_DH,), f32),
+              "w2": jax.ShapeDtypeStruct((_DH, _DOUT), f32),
+              "b2": jax.ShapeDtypeStruct((_DOUT,), f32)}
+    d = sum(int(np.prod(p.shape)) for p in params.values())
+    cfg = engine.EngineConfig(mode=ModeConfig(**{**mode_kw, "d": d}),
+                              weight_decay=5e-4, **eng_kw)
+
+    def on(tree, where):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=where), tree)
+
+    state = on(jax.eval_shape(
+        lambda p: engine.init_server_state(cfg, p, {}), params), sharding)
+    batch = on({"x": jax.ShapeDtypeStruct((_W, _B, _DIN), f32),
+                "y": jax.ShapeDtypeStruct((_W, _B), jnp.int32),
+                "mask": jax.ShapeDtypeStruct((_W, _B), f32)}, batch_sharding)
+    lr = jax.ShapeDtypeStruct((), f32, sharding=sharding)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding)
+    return cfg, state, batch, lr, rng
+
+
+@pytest.mark.parametrize("name, mode_kw, eng_kw, custom_calls", [
+    ("sketch", _SKETCH, {}, 2),
+    ("sketch_chunk2", _SKETCH, dict(client_chunk=2), 2),
+    ("uncompressed", dict(mode="uncompressed", momentum_type="virtual",
+                          error_type="none"), {}, 0),
+])
+def test_fused_round_compiles_on_one_chip_with_both_kernels_inlined(
+        one_chip, monkeypatch, name, mode_kw, eng_kw, custom_calls):
+    """The fused `make_round_step` as ONE program for one described v5e, with
+    the accumulate and the query kernel inlined beside the vmapped (or
+    chunk-scanned) client phase: the fact that retired `--split_compile`
+    (PR 21 ran it on the chip, PR 29 deleted the two-program fork). Exactly
+    one Mosaic call a kernel in the sketch round, none in the dense one."""
+    monkeypatch.setattr(pk, "eligible", pk.supported)
+    cfg, state, batch, lr, rng = _round_shapes(
+        mode_kw, one_chip, one_chip, **eng_kw)
+    step = jax.jit(engine.make_round_step(_mlp_loss, cfg))
+    hlo = step.lower(state, batch, {}, lr, rng).compile().as_text()
+    assert hlo.count("tpu_custom_call") == custom_calls
+
+
 def test_sharded_round_partitions_kernels_on_four_chips(topo, monkeypatch):
     """The four-device sharded round with the kernels routed: the per-device
     partial sketch sits inside the client-phase shard_map, and the replicated
     server tail's query — at jit top level — must be wrapped the same way
     (engine._kernels_replicated), or lowering dies with 'Mosaic kernels
-    cannot be automatically partitioned'. Small model, small supported
-    layout: what this guards is the partitioning, not the width."""
+    cannot be automatically partitioned'."""
     monkeypatch.setattr(pk, "eligible", pk.supported)
-    din, dh, dout, W, B = 32, 64, 4, 8, 4
-    f32 = jnp.float32
-    params = {"w1": jax.ShapeDtypeStruct((din, dh), f32),
-              "b1": jax.ShapeDtypeStruct((dh,), f32),
-              "w2": jax.ShapeDtypeStruct((dh, dout), f32),
-              "b2": jax.ShapeDtypeStruct((dout,), f32)}
-    d = sum(int(np.prod(p.shape)) for p in params.values())
-    mcfg = ModeConfig(mode="sketch", d=d, k=64, num_rows=3, num_cols=1024,
-                      hash_family="rotation", momentum_type="virtual",
-                      error_type="virtual")
-    cfg = engine.EngineConfig(mode=mcfg, weight_decay=5e-4, client_shards=4)
     mesh = Mesh(np.asarray(topo.devices).reshape(4), (meshlib.CLIENT_AXIS,))
-    rep = NamedSharding(mesh, P())
-    per_client = meshlib.client_sharding(mesh)
-
-    def on(tree, sharding):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
-
-    state = on(jax.eval_shape(
-        lambda p: engine.init_server_state(cfg, p, {}), params), rep)
-    batch = on({"x": jax.ShapeDtypeStruct((W, B, din), f32),
-                "y": jax.ShapeDtypeStruct((W, B), jnp.int32),
-                "mask": jax.ShapeDtypeStruct((W, B), f32)}, per_client)
-    lr = jax.ShapeDtypeStruct((), f32, sharding=rep)
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=rep)
-
+    cfg, state, batch, lr, rng = _round_shapes(
+        _SKETCH, NamedSharding(mesh, P()), meshlib.client_sharding(mesh),
+        client_shards=4)
     step = jax.jit(engine.make_sharded_round_step(_mlp_loss, cfg, mesh))
     hlo = step.lower(state, batch, {}, lr, rng).compile().as_text()
     # accumulate (per-device partial) + query (replicated tail), both Mosaic
