@@ -103,12 +103,13 @@ class InFlightRound:
         return len(self.lrs)
 
     def release_state(self):
-        """Drop the server-state references. The runner calls this when a
-        NEWER dispatch supersedes this one as the pipeline head: only the
-        newest pending state is ever published at a batch commit, so
-        holding every intermediate tree would pin up to max_inflight full
-        copies of params+momentum+error in HBM with no reader (the metrics
-        stay — they are the per-round scalars commit needs)."""
+        """Drop the server-state references. The runner calls this once two
+        NEWER dispatches exist: a batch commit publishes the state of the
+        last dispatch it is given, which is the newest pending one (a full
+        drain) or the one before it (a drain that keeps the newest queued),
+        so holding every intermediate tree would pin up to max_inflight
+        full copies of params+momentum+error in HBM with no reader (the
+        metrics stay — they are the per-round scalars commit needs)."""
         self.new_state = None
         self.new_client_state = None
 
@@ -1082,14 +1083,18 @@ class FederatedSession:
 
     def commit_rounds(self, infls: list[InFlightRound],
                       metrics_hosts: list) -> list[dict]:
-        """Batch commit for a drained pipeline, in dispatch order, under ONE
-        mutate_lock hold: every round's metrics/comm/round-counter
-        bookkeeping runs, but the server state is published ONCE — the
-        newest dispatch's (intermediate trees may already be released, see
-        InFlightRound.release_state). The single lock hold keeps the
-        (state, round, snapshot) triple consistent for an emergency
-        checkpoint: it observes either the pre-drain committed view or the
-        fully-drained one, never a mix."""
+        """Batch commit of the oldest in-flight dispatches, in dispatch
+        order, under ONE mutate_lock hold: everything in flight (a full
+        drain) or a prefix of it (the runner's depth-triggered drain leaves
+        the newest dispatch queued on the device). Every round's
+        metrics/comm/round-counter bookkeeping runs, but the server state,
+        client state, RNG snapshot and re-queue are published ONCE — those
+        of the LAST dispatch given (intermediate trees may already be
+        released, see InFlightRound.release_state); the head of the
+        dispatch chain stays while anything is left in flight. The single
+        lock hold keeps the (state, round, snapshot) triple consistent for
+        an emergency checkpoint: it observes the committed view of one
+        round boundary, before this commit or after it, never a mix."""
         out = []
         obs_records = []
         with self.mutate_lock:
@@ -1131,9 +1136,9 @@ class FederatedSession:
             last = infls[-1]
             if last.new_state is None:
                 raise RuntimeError(
-                    "commit_rounds: the newest in-flight dispatch has no "
+                    "commit_rounds: the last dispatch of this commit has no "
                     "state reference (release_state must only be called on "
-                    "superseded entries)"
+                    "entries with two newer dispatches behind them)"
                 )
             self.state = last.new_state
             if last.new_client_state is not None:

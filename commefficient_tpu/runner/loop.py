@@ -8,9 +8,12 @@ Overlap model (async, the default):
     main thread:      dispatch N, N+1, ...      (no per-dispatch host sync)
     device:           compute N, N+1, ...       (queued back-to-back)
     writer thread:    periodic checkpoint save  (staging + rename commit)
-    main thread @ boundary: device_get of each pending dispatch's metrics in
-        dispatch order, a ready stamp after each -> commit in dispatch
-        order -> eval / log / checkpoint
+    main thread @ depth reached: device_get of every pending dispatch's
+        metrics but the newest's, in dispatch order, a ready stamp after
+        each -> commit in dispatch order; the newest stays queued, so the
+        device has its next round while the host commits and dispatches
+    main thread @ boundary: the same over every pending dispatch (a full
+        drain) -> eval / log / checkpoint
 
 What stays synchronous, deliberately:
 
@@ -18,9 +21,11 @@ What stays synchronous, deliberately:
   snapshot) in dispatch order under the session's mutate_lock — an
   emergency checkpoint from the watchdog's timer thread always captures a
   consistent committed view.
-- **Eval**: runs only at a drained boundary (the pipeline is empty, so
-  `session.state` is the exact committed params — and, with buffer
-  donation on, the only state guaranteed live).
+- **Eval**: runs only at a fully drained boundary (the pipeline is empty,
+  so `session.state` is the exact committed params — and, with buffer
+  donation on, the only state guaranteed live). Between boundaries a drain
+  that the depth triggers keeps the newest dispatch queued; every save,
+  eval and exit drains that one too first.
 - **Emergency + preemption + final saves**: the moments where "the save
   completed" must hold before the next action (abort, exit 75, process
   end). The async writer is DRAINED before the preemption save and before
@@ -43,6 +48,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import itertools
 import os
 import sys
 import threading
@@ -64,7 +70,7 @@ from .writer import AsyncCheckpointWriter
 
 
 DEFAULT_MAX_INFLIGHT = 4  # auto-tune's starting point until a round is timed
-AUTO_INFLIGHT_LO, AUTO_INFLIGHT_HI = 2, 16
+AUTO_INFLIGHT_LO, AUTO_INFLIGHT_HI = 3, 16
 
 
 def _process_count() -> int:
@@ -72,6 +78,12 @@ def _process_count() -> int:
     multi-host loop without lying to the rest of jax (orbax checkpointing
     also reads jax.process_count and would break under a global patch)."""
     return jax.process_count()
+
+
+def _finished(infl) -> bool:
+    """Whether a dispatch's round program has already run to its end, asked
+    without waiting (its metrics are outputs of that one program)."""
+    return jax.tree.leaves(infl.metrics)[0].is_ready()
 
 
 # graftlint: drain-point — deliberate one-shot sync probe at loop start;
@@ -99,9 +111,13 @@ def auto_inflight(rtt_ms: float, round_ms: float,
     ~target_overhead of the work it amortizes: each drain costs one RTT (the
     batched device_get), spread over the rounds committed in it, so depth
     >= rtt / (target * round) bounds the sync tax at ~target. Clamped to
-    [2, 16]: 2 keeps dispatch/commit overlapped even on zero-RTT local
-    backends; 16 bounds how much work a preemption's grace window must wait
-    out (the same concern the fixed default had)."""
+    [3, 16]. 3 because a drain that the depth triggers keeps the newest
+    dispatch queued (the device never waits for the host across it): at 3
+    it still reads two dispatches, so the sync is spread over two rounds
+    even on a zero-RTT local backend and both ready-stamp intervals (first
+    and chained) keep getting observations; at 2 it would read one. 16
+    bounds how much work a preemption's grace window must wait out (the
+    same concern the fixed default had)."""
     if round_ms <= 0:
         return DEFAULT_MAX_INFLIGHT
     import math
@@ -124,7 +140,9 @@ class RunnerConfig:
     sync_loop: bool = False
     # async only: drain when this many rounds are dispatched-uncommitted,
     # even between boundaries — bounds how much work a preemption's grace
-    # window has to wait out, and how stale the halt check can run.
+    # window has to wait out, and how stale the halt check can run. Such a
+    # drain commits all but the newest dispatch, which stays queued on the
+    # device (a depth of 1 has nothing to keep and drains fully).
     # 0 (default) = auto-tune: measure the host<->device RTT once at loop
     # start, then re-derive the depth from the observed per-round time at
     # every drain (auto_inflight) — a slow host link gets a deep chain, a
@@ -181,6 +199,9 @@ class RunStats:
     wall_s: float = 0.0
     nonfinite_rounds: int = 0
     drains: int = 0
+    # of those, the drains the depth triggered that left the newest
+    # dispatch queued on the device (0 under --sync_loop or a depth of 1)
+    drains_kept: int = 0
     evals: int = 0
     sync_checkpoints: int = 0
     async_checkpoints: int = 0
@@ -406,34 +427,47 @@ def run_loop(
     idle_mark: list = [None]  # [perf_counter at drain end] | [None]
     idle_acc = [0.0, 0, 0.0]  # sum_ms, n, max_ms
     # the round's own clock (always on, like the phase histograms): the
-    # drain stamps each pending dispatch as its metrics come back. A
-    # dispatch queued behind its predecessor gives the device's own time
-    # for a round (chained); the first of a drain adds whatever the device
-    # waited across the drain (first); and the part of that wait in which
-    # the host had not yet handed the device its next round is bubble_host.
+    # drain stamps each dispatch it reads as its metrics come back. A
+    # dispatch read behind its predecessor gives the device's own time for
+    # a round (chained); the first of a drain adds whatever the device
+    # waited across the drain before (first: nothing, when that drain kept
+    # a round queued); and bubble_host is the part of such a wait in which
+    # the host had not yet handed the device its next round.
     # No stamp is carried across run_loop calls.
     chained_hist = reg.histogram("runner_round_interval_chained_ms")
     first_hist = reg.histogram("runner_round_interval_first_ms")
     bubble_host_hist = reg.histogram("runner_bubble_host_ms")
     ready_mark: list = [None]  # [perf_counter of the last ready stamp]
-    host_mark: list = [None]  # the same, until the next dispatch returns
+    # from a drain to the return of the next dispatch call: the time from
+    # which the device is known to have had nothing queued. A full drain's
+    # last ready stamp; after a kept drain None, unless the kept dispatch is
+    # seen finished before that call is made (the host was late after all)
+    after_drain = [False]
+    host_mark: list = [None]
 
     def note_dispatched():
         """Called at each dispatch site once the dispatch has returned and
         its `dispatch` phase is observed: closes the host's part of the wait
-        the last drain opened (first dispatch after a drain only)."""
-        if host_mark[0] is not None:
+        the last drain opened (first dispatch after a drain only; 0 when
+        the device had the kept round to run all the while)."""
+        if after_drain[0]:
             bubble_host_hist.observe(
-                (time.perf_counter() - host_mark[0]) * 1e3)
+                0.0 if host_mark[0] is None
+                else (time.perf_counter() - host_mark[0]) * 1e3)
+            after_drain[0] = False
             host_mark[0] = None
 
     def note_idle():
         """Called at each dispatch site BEFORE the dispatch: resolves the
         commit-to-dispatch gap the last drain opened (first dispatch after
-        a drain only)."""
+        a drain only), and looks once, without waiting, whether the round
+        that drain kept queued is already done."""
         if idle_mark[0] is None:
             return
-        ms = (time.perf_counter() - idle_mark[0]) * 1e3
+        now = time.perf_counter()
+        if host_mark[0] is None and pending and _finished(pending[-1]):
+            host_mark[0] = now  # the kept round is done: the queue is empty
+        ms = (now - idle_mark[0]) * 1e3
         idle_mark[0] = None
         idle_hist.observe(ms)
         idle_gauge.set(ms)
@@ -453,35 +487,42 @@ def run_loop(
     last_drain_t = time.perf_counter()
     first_drain = True
 
-    # graftlint: drain-point — THE drain point: device_get of every pending
-    # dispatch's metrics, one dispatch at a time
-    def drain(watch: bool = True):
-        """Commit every pending dispatch: read each one's metrics back in
-        dispatch order, stamping each as it arrives (the host is parked
-        here for the device time of those rounds, so the last read returns
-        when one batched read would), then in-order publication + metric
-        folding. In auto mode the wall time between drains (boundary work
-        included — an overestimate only ever tunes the depth DOWN toward
-        the safe floor) feeds the next in-flight depth; the FIRST interval
-        is discarded — it carries the round step's jit compile (tens of
-        seconds), which would seed the EMA ~1000x high and pin the depth at
-        the floor for many drains."""
+    # graftlint: drain-point — THE drain point: device_get of the pending
+    # dispatches' metrics, one dispatch at a time
+    def drain(watch: bool = True, keep: int = 0):
+        """Commit the pending dispatches, all but the newest `keep` (0 or 1):
+        read each one's metrics back in dispatch order, stamping each as it
+        arrives (the host is parked here for the device time of those
+        rounds, so the last read returns when one batched read would), then
+        in-order publication + metric folding. keep=1 is the drain the depth
+        triggers: the device finds the kept round program queued when the
+        last one read ends, and the commit, the next prepared round and its
+        dispatch all pass while it runs. Everything that reads the committed
+        state (save, eval, exit) drains with keep=0 first. In auto mode the
+        wall time between drains (boundary work included — an overestimate
+        only ever tunes the depth DOWN toward the safe floor) feeds the next
+        in-flight depth; the FIRST interval is discarded — it carries the
+        round step's jit compile (tens of seconds), which would seed the EMA
+        ~1000x high and pin the depth at the floor for many drains."""
         nonlocal pending_rounds, last_m, nonfinite_total
         nonlocal eff_inflight, ema_round_ms, last_drain_t, first_drain
         if not pending:
             return
-        committed = pending_rounds
+        if len(pending) == 1:
+            keep = 0  # nothing older to commit: a depth of 1, or one block
+        read = list(itertools.islice(pending, len(pending) - keep))
+        committed = sum(fl.num_rounds for fl in read)
         first = session.round  # oldest uncommitted round index
-        # the drain legitimately waits out every queued dispatch, so the
-        # watchdog threshold scales by the round count and the recorded
+        # the drain legitimately waits out every dispatch it reads, so the
+        # watchdog threshold scales by their round count and the recorded
         # time is normalized back to a per-round figure (true median)
         t_drain0 = time.perf_counter()
         hosts, stamps = [], []
-        with (watchdog.round(first, rounds=pending_rounds)
+        with (watchdog.round(first, rounds=committed)
               if watch else contextlib.nullcontext()):
             with tracer.span("runner", "drain", round_first=first,
                              rounds=committed):
-                for fl in pending:
+                for fl in read:
                     hosts.append(jax.device_get(fl.metrics))
                     stamps.append(time.perf_counter())
         phase_hist["drain"].observe((stamps[-1] - t_drain0) * 1e3)
@@ -505,13 +546,13 @@ def run_loop(
                     ts_us, tracer.us_at(stamp) - ts_us, round_first=d_first,
                     rounds=d_n, sketch_path=sketch_path)
             prev = stamp
-        dispatch_marks.clear()
-        ready_mark[0] = host_mark[0] = stamps[-1]
+        ready_mark[0] = stamps[-1]
+        after_drain[0] = True
+        host_mark[0] = None if keep else stamps[-1]
         t_commit0 = time.perf_counter()
         with tracer.span("runner", "commit", round_first=first,
                          rounds=committed):
-            for i, m in enumerate(session.commit_rounds(list(pending),
-                                                        hosts)):
+            for i, m in enumerate(session.commit_rounds(read, hosts)):
                 rnd_i = first + i
                 last_m = m
                 nf = int(m.get("nonfinite_rounds", 0))
@@ -543,10 +584,14 @@ def run_loop(
                     reg.gauge("model_moe_expert_load_max").set(
                         m["moe_load_max_sum"] / max(m["moe_load_max_count"], 1))
         phase_hist["commit"].observe((time.perf_counter() - t_commit0) * 1e3)
-        pending.clear()
-        pending_rounds = 0
+        for _ in read:
+            pending.popleft()
+            dispatch_marks.popleft()
+        pending_rounds -= committed
         reg.counter("runner_rounds_total").inc(committed)
         reg.counter("runner_drains_total").inc()
+        if keep:
+            reg.counter("runner_drains_kept_total").inc()
         if profile is not None:
             profile.on_committed(session.round)
         on_committed = getattr(src, "on_committed", None)
@@ -623,8 +668,8 @@ def run_loop(
                         phase_hist["dispatch"].observe(
                             (time.perf_counter() - t_d0) * 1e3)
                         note_dispatched()
-                        if len(pending) > 1:
-                            pending[-2].release_state()  # superseded head
+                        if len(pending) > 2:
+                            pending[-3].release_state()  # ends no drain now
                         pending_rounds += len(lrs)
                         if cfg.sync_loop:
                             drain(watch=False)
@@ -657,8 +702,8 @@ def run_loop(
                             phase_hist["dispatch"].observe(
                                 (time.perf_counter() - t_d0) * 1e3)
                             note_dispatched()
-                            if len(pending) > 1:
-                                pending[-2].release_state()  # superseded
+                            if len(pending) > 2:
+                                pending[-3].release_state()  # as above
                             pending_rounds += 1
                             if cfg.sync_loop:
                                 drain(watch=False)
@@ -675,14 +720,17 @@ def run_loop(
                 # together (single process: just the local flag)
                 preempt_now = (pre.triggered if process_count == 1
                                else preemption.coordinated(pre.triggered))
-                if (pending_rounds
-                        and (preempt_now
-                             or pending_rounds >= eff_inflight
-                             or rnd >= cfg.total_rounds
-                             or rnd % eval_every == 0
-                             or (cfg.checkpoint_every
-                                 and rnd % cfg.checkpoint_every == 0))):
+                # a boundary (something is about to read the committed
+                # state, or the run ends) drains everything; the depth alone
+                # drains all but the newest dispatch, which stays queued
+                if (preempt_now
+                        or rnd >= cfg.total_rounds
+                        or rnd % eval_every == 0
+                        or (cfg.checkpoint_every
+                            and rnd % cfg.checkpoint_every == 0)):
                     drain()
+                elif pending_rounds >= eff_inflight:
+                    drain(keep=1)
                 if preempt_now:
                     tracer.instant("resilience", "preempt_boundary",
                                    round=session.round)
@@ -699,6 +747,7 @@ def run_loop(
                     _postmortem("preemption")
                     sys.exit(EXIT_RESUMABLE)
                 if nonfinite_total and cfg.on_nonfinite == "halt":
+                    drain()  # the round a kept drain left queued
                     shutdown()
                     if save_ckpt:
                         save_ckpt()
@@ -711,8 +760,10 @@ def run_loop(
                 if slo is not None and slo.halted:
                     # the session's commit hook fed the SLO engine at the
                     # drain above; a latched halt exits through the SAME
-                    # clean sequence the non-finite halt uses — committed
-                    # state saved, writer drained, loud one-line verdict
+                    # clean sequence the non-finite halt uses — everything
+                    # dispatched committed, committed state saved, writer
+                    # drained, loud one-line verdict
+                    drain()
                     shutdown()
                     if save_ckpt:
                         save_ckpt()
@@ -775,6 +826,7 @@ def run_loop(
     stats.rounds = session.round - start_round
     stats.nonfinite_rounds = int(mark.delta("runner_nonfinite_rounds_total"))
     stats.drains = int(mark.delta("runner_drains_total"))
+    stats.drains_kept = int(mark.delta("runner_drains_kept_total"))
     stats.evals = int(mark.delta("runner_evals_total"))
     stats.sync_checkpoints = int(mark.delta("runner_ckpt_sync_total"))
     stats.async_checkpoints = int(mark.delta("runner_ckpt_async_total"))

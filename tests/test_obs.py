@@ -579,35 +579,66 @@ STAMP_HISTS = ("runner_round_interval_chained_ms",
                "runner_round_interval_first_ms", "runner_bubble_host_ms")
 
 
-@pytest.mark.parametrize("depth", [2, 1])
-def test_ready_stamps_count_against_drains_and_rounds(depth):
-    """The drain stamps each pending dispatch as its metrics come back: a
-    dispatch behind its predecessor observes `chained`, the first of a
-    drain observes `first` and the first dispatch after a drain
+@pytest.mark.parametrize("depth,drains,kept", [
+    (3, (2, 1), (1, 0)), (2, (3, 2), (2, 1)), (1, (4, 3), (0, 0))])
+def test_ready_stamps_count_against_drains_and_rounds(depth, drains, kept):
+    """The drain stamps each dispatch it reads as its metrics come back: a
+    dispatch behind its predecessor in the drain observes `chained`, the
+    first of a drain observes `first` and the first dispatch after a drain
     `bubble_host`, except in the first drain of a call, which has no stamp
-    before it (none is carried across run_loop calls). Reading the metrics
-    one dispatch at a time changes no result: state and rows equal the
-    sync loop's."""
+    before it (none is carried across run_loop calls). A drain that the
+    depth triggers leaves the newest dispatch for the next drain to read
+    (depth 3: rounds 0-2 dispatched, 0 and 1 read, 2 read with 3 at the
+    end of the call; depth 2 reads one at a time, so nothing is chained
+    until the call's last, full drain), and counts in
+    runner_drains_kept_total. Reading the metrics one dispatch at a time
+    changes no result: state and rows equal the sync loop's."""
     reg = obreg.default()
     ref = _tiny_session()
     rows_ref = (_run_logged(ref, _loop_cfg(4, sync_loop=True))[1]
                 + _run_logged(ref, _loop_cfg(7, sync_loop=True))[1])
 
     before = {n: reg.histogram(n).count for n in STAMP_HISTS}
+    kept_before = reg.counter("runner_drains_kept_total").value
     s = _tiny_session()
     seg1, rows1 = _run_logged(s, _loop_cfg(4, max_inflight=depth))
     seg2, rows2 = _run_logged(s, _loop_cfg(7, max_inflight=depth))
     got = {n: reg.histogram(n).count - before[n] for n in STAMP_HISTS}
 
-    assert (seg1.drains, seg2.drains) == ((2, 2) if depth == 2 else (4, 3))
-    rounds, drains = 7, seg1.drains + seg2.drains
-    assert got["runner_round_interval_chained_ms"] == rounds - drains
-    assert got["runner_round_interval_first_ms"] == drains - 2
-    assert got["runner_bubble_host_ms"] == drains - 2
+    assert (seg1.drains, seg2.drains) == drains
+    assert (seg1.drains_kept, seg2.drains_kept) == kept
+    assert (reg.counter("runner_drains_kept_total").value - kept_before
+            == sum(kept))
+    rounds, n_drains = 7, sum(drains)
+    assert got["runner_round_interval_chained_ms"] == rounds - n_drains
+    assert got["runner_round_interval_first_ms"] == n_drains - 2
+    assert got["runner_bubble_host_ms"] == n_drains - 2
     for n in STAMP_HISTS:
         assert reg.histogram(n).count == 0 or reg.histogram(n).percentile(0) >= 0
     _assert_params_equal(ref, s)
     assert rows1 + rows2 == rows_ref and len(rows_ref) == 2
+
+
+def test_bubble_host_is_zero_while_the_kept_round_runs(monkeypatch):
+    """After a kept drain the host's part of the bubble is 0 unless the
+    kept round is seen finished before the next dispatch call is made; then
+    it runs from that look to the call's return. After a full drain it runs
+    from the drain's last ready stamp, as it always did."""
+    import commefficient_tpu.runner.loop as loop_mod
+
+    hist = obreg.default().histogram("runner_bubble_host_ms")
+
+    def observed(ready):
+        monkeypatch.setattr(loop_mod, "_finished", lambda infl: ready)
+        n0, sum0 = hist.count, hist.sum
+        stats = run_loop(_tiny_session(), FedOptimizer(lambda _: LR, 1),
+                         _loop_cfg(9, max_inflight=3))
+        assert (stats.drains, stats.drains_kept) == (4, 3)
+        assert hist.count - n0 == 3  # one after each kept drain
+        return hist.sum - sum0
+
+    assert observed(ready=False) == 0.0
+    assert observed(ready=True) > 0.0
 
 
 def test_device_track_spans_neither_overlap_nor_end_together(tmp_path):
@@ -658,12 +689,17 @@ def test_loop_spans_are_mirrored_inside_a_profile_window(tmp_path,
         6, max_inflight=2, profile_rounds="2:3", profile_dir=str(tmp_path)))
     assert obtrace.get().event_count() == 0  # the buffer stayed disarmed
     loop = [(n, kw) for n, kw in made if n.startswith("runner/")]
+    # depth 2 reads one dispatch a drain and keeps the newest queued: the
+    # capture opens at round 2's dispatch (round 1 still in flight) and
+    # closes at the drain that commits round 3, after round 4's dispatch
     assert [(n, kw["round"]) for n, kw in loop if "round" in kw] == [
         ("runner/prepare", 2), ("runner/dispatch", 2),
-        ("runner/prepare", 3), ("runner/dispatch", 3)]
+        ("runner/prepare", 3), ("runner/dispatch", 3),
+        ("runner/prepare", 4), ("runner/dispatch", 4)]
     assert [(n, kw["round_first"], kw["rounds"]) for n, kw in loop
-            if "round_first" in kw] == [("runner/drain", 2, 2),
-                                        ("runner/commit", 2, 2)]
+            if "round_first" in kw] == [
+        (f"runner/{what}", rnd, 1) for rnd in (1, 2, 3)
+        for what in ("drain", "commit")]
     assert {n for n, _ in made} - {n for n, _ in loop} <= {
         "federated/prepare_round"}
 

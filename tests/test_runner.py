@@ -368,3 +368,178 @@ def test_evaluate_refuses_inflight_pipeline(tiny_cv):
         s.evaluate(test_set, 32)
     s.commit_round(infl)
     s.evaluate(test_set, 32)  # drained: fine
+
+
+# ------------------------------- the drain that keeps one round queued
+
+
+def _spy_commits(session, log, read_state=False):
+    """Record (round after the commit, dispatches still in flight[, the
+    committed params on the host]) at every commit_rounds call."""
+    inner = session.commit_rounds
+
+    def commit_rounds(infls, hosts):
+        out = inner(infls, hosts)
+        entry = (session.round, session._inflight)
+        if read_state:
+            entry += (jax.device_get(session.state["params"]),)
+        log.append(entry)
+        return out
+
+    session.commit_rounds = commit_rounds
+
+
+def _drive(session, test_set, cfgs):
+    """run_loop once a config, with an eval and a row sink: (stats of each
+    call, rows less time_s)."""
+    from commefficient_tpu.federated.api import FedOptimizer
+    from commefficient_tpu.runner import run_loop
+
+    rows, stats = [], []
+    for cfg in cfgs:
+        stats.append(run_loop(
+            session, FedOptimizer(lambda _: LR, 1), cfg,
+            eval_fn=lambda: session.evaluate(test_set, 32),
+            build_row=lambda **kw: kw, logger=rows))
+    for r in rows:
+        r.pop("time_s")
+    return stats, rows
+
+
+@pytest.mark.parametrize("depth", [3, 2, 1])
+def test_kept_drain_loop_bit_identical_to_sync(tiny_cv, tmp_path, depth):
+    """Two consecutive run_loop calls over an eval boundary (every 5) and
+    a checkpoint boundary (every 7): a drain the depth triggers commits all
+    but the newest dispatch and leaves exactly that one in flight, every
+    boundary drain leaves none, and the state published at EVERY commit —
+    a kept drain's too, read back here with donation off — the rows and
+    the final state equal the --sync_loop run's bit for bit."""
+    from commefficient_tpu.obs import registry as obreg
+    from commefficient_tpu.runner import RunnerConfig
+
+    def cfgs(ckdir, **kw):
+        return [RunnerConfig(total_rounds=t, eval_every=5, checkpoint_every=7,
+                             checkpoint_dir=str(tmp_path / ckdir), **kw)
+                for t in (9, 16)]
+
+    # --checkpoint_dir arms emergency saves, so the state is not donated
+    ref, test_set = cv_train.build(_args(("--checkpoint_dir", "x")))
+    ref_log = []
+    _spy_commits(ref, ref_log, read_state=True)
+    _, rows_ref = _drive(ref, test_set, cfgs("ref", sync_loop=True))
+    by_round = {rnd: params for rnd, _, params in ref_log}
+    assert sorted(by_round) == list(range(1, 17))
+
+    reg = obreg.default()
+    mark = reg.mark()
+    s, _ = cv_train.build(_args(("--checkpoint_dir", "x")))
+    log = []
+    _spy_commits(s, log, read_state=True)
+    stats, rows = _drive(s, test_set, cfgs("ck", max_inflight=depth))
+
+    kept = [e for e in log if e[1] == 1]
+    full = [e for e in log if e[1] == 0]
+    assert len(kept) + len(full) == len(log)  # never more than one left
+    assert sum(st.drains for st in stats) == len(log) == int(
+        mark.delta("runner_drains_total"))
+    assert sum(st.drains_kept for st in stats) == len(kept) == int(
+        mark.delta("runner_drains_kept_total"))
+    # boundaries at 5, 7, 9 (end of call 1), 10, 14, 15, 16: fully drained
+    assert [e[0] for e in full if e[0] in (5, 7, 9, 10, 14, 15, 16)] == [
+        5, 7, 9, 10, 14, 15, 16]
+    if depth == 1:
+        assert not kept and len(full) == 16
+    else:
+        assert kept and not set(e[0] for e in full) - {5, 7, 9, 10, 14, 15, 16}
+    for rnd, _, params in log:
+        for x, y in zip(jax.tree.leaves(params),
+                        jax.tree.leaves(by_round[rnd])):
+            np.testing.assert_array_equal(x, y)
+    assert rows == rows_ref and [r["rnd"] for r in rows] == [5, 9, 10, 15, 16]
+    _assert_params_equal(ref, s)
+    assert s._inflight == 0 and s._head_state is None
+
+
+@pytest.mark.chaos
+@pytest.mark.parametrize("fault,on_nonfinite,exit_code,rounds_done", [
+    ("preempt@3", "skip", EXIT_RESUMABLE, 4),
+    ("nonfinite@1", "halt", None, 3),
+], ids=["preempt", "nonfinite_halt"])
+def test_exit_while_a_round_is_kept_queued_saves_drained_state(
+        tiny_cv, fault, on_nonfinite, exit_code, rounds_done):
+    """Depth 3, no boundary before round 6: rounds 0-2 are dispatched, a
+    kept drain commits 0 and 1 and leaves 2 queued. A SIGTERM at round 3's
+    dispatch, or the non-finite round 1 that this drain commits under
+    --on_nonfinite halt, must commit the kept round (and whatever followed
+    it) before the exit save: the save sees nothing in flight, and its
+    state is the sync loop's after as many rounds."""
+    from commefficient_tpu.federated.api import FedOptimizer
+    from commefficient_tpu.runner import RunnerConfig, run_loop
+
+    s, _ = cv_train.build(_args((
+        "--fault_plan", fault, "--on_nonfinite", on_nonfinite,
+        "--checkpoint_dir", "x")))
+    log, seen = [], []
+    _spy_commits(s, log)
+
+    def save_ckpt():
+        seen.append(((s.round, s._inflight),
+                     jax.device_get(s.state["params"])))
+        return "saved"
+
+    with pytest.raises(SystemExit) as ei:
+        run_loop(s, FedOptimizer(lambda _: LR, 1),
+                 RunnerConfig(total_rounds=6, eval_every=6, max_inflight=3,
+                              on_nonfinite=on_nonfinite),
+                 save_ckpt=save_ckpt)
+    if exit_code is None:
+        assert "non-finite" in str(ei.value.code)
+    else:
+        assert ei.value.code == exit_code
+    assert log[0] == (2, 1)  # the kept drain came first, one round queued
+    assert log[-1] == (rounds_done, 0)
+    assert [view for view, _ in seen] == [(rounds_done, 0)]
+
+    # the reference never sees the signal; a poisoned round it does see
+    ref, _ = cv_train.build(_args((
+        *(() if "preempt" in fault else ("--fault_plan", fault)),
+        "--on_nonfinite", on_nonfinite)))
+    for _ in range(rounds_done):
+        ref.run_round(LR)
+    for x, y in zip(jax.tree.leaves(seen[0][1]),
+                    jax.tree.leaves(jax.device_get(ref.state["params"]))):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_commit_rounds_publishes_the_prefix_it_is_given(tiny_cv):
+    """commit_rounds over a prefix of what is in flight publishes the
+    prefix's LAST state, RNG snapshot and re-queue, keeps the head of the
+    dispatch chain for the dispatch left in flight, and with donation off
+    that published state is a live copy; committing the rest leaves the
+    session equal to one that was always fully drained."""
+    flags = ("--fault_plan", "client_drop@1:clients=0+1",
+             "--checkpoint_dir", "x")  # a drop fills the re-queue; no donation
+    s, _ = cv_train.build(_args(flags))
+    i1, i2, i3 = (s.dispatch_round(s.prepare_round(r), LR) for r in range(3))
+    i1.release_state()  # two newer dispatches: what the loop releases
+    assert i2.requeue and i2.requeue != i1.requeue
+    out = s.commit_rounds([i1, i2], jax.device_get([i1.metrics, i2.metrics]))
+    assert len(out) == 2 and s.round == 2 and s._inflight == 1
+    assert s.state is i2.new_state and s._head_state is i3.new_state
+    assert s.rng_snapshot is i2.snapshot
+    assert s._requeue_committed == i2.requeue
+    assert s._requeue_ages_committed == i2.requeue_ages
+
+    b, _ = cv_train.build(_args(flags))
+    mb = [b.run_round(LR) for _ in range(2)]
+    assert [m["loss_sum"] for m in out] == [m["loss_sum"] for m in mb]
+    _assert_params_equal(s, b)  # readable while round 2 is still in flight
+    assert b._requeue_committed == s._requeue_committed
+
+    out += s.commit_rounds([i3], [jax.device_get(i3.metrics)])
+    mb.append(b.run_round(LR))
+    assert s.round == 3 and s._inflight == 0 and s._head_state is None
+    assert [m["loss_sum"] for m in out] == [m["loss_sum"] for m in mb]
+    _assert_params_equal(s, b)
+    assert s._requeue_committed == b._requeue_committed
+    np.testing.assert_array_equal(s.rng_snapshot[0][1], b.rng_snapshot[0][1])

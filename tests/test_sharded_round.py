@@ -386,8 +386,9 @@ def test_merge_comm_bytes_headline():
 def test_auto_inflight_policy():
     from commefficient_tpu.runner import auto_inflight
 
-    # local backend: sub-ms RTT stays at the floor
-    assert auto_inflight(0.1, 50.0) == 2
+    # local backend: sub-ms RTT stays at the floor (3: a drain the depth
+    # triggers keeps the newest dispatch queued and still reads two)
+    assert auto_inflight(0.1, 50.0) == 3
     # slow host link: 70 ms RTT over a 50 ms round wants a deep chain
     assert auto_inflight(70.0, 50.0) == 14
     # clamped at the preemption-grace ceiling
