@@ -1005,13 +1005,29 @@ def _advance_quarantine_full(cfg: EngineConfig, qstate: dict, norms, lnorms,
     return new_q
 
 
+# The collections of net_state that a loss only reads (`buffers`: a router's
+# selection bias, models/glm4_moe_lite.py). Every client hands them back as
+# they came, and the mean of W equal float32 copies need not be the copy: the
+# merges below hand them on untouched, bit for bit.
+READ_ONLY_COLLECTIONS = ("buffers",)
+
+
+def _merge_collections(merge, returned, net_state) -> Any:
+    """`merge(client results, previous)` leaf by leaf over net_state, but for
+    its read-only collections, which stay as they were."""
+    merged = jax.tree.map(merge, returned, net_state)
+    if isinstance(net_state, dict):
+        merged.update({k: net_state[k] for k in READ_ONLY_COLLECTIONS if k in net_state})
+    return merged
+
+
 @jax.named_scope("cohort_reduce")
 def _merge_net_state(nstates, net_state, part) -> Any:
     """Mutable model collections (BN stats): average the SURVIVING clients'
     results; with no survivors, keep the previous stats. mask_rows keeps a
     quarantined client's NaN stats out of the live average."""
     n_live = jnp.maximum(part.sum(), 1.0)
-    return jax.tree.map(
+    return _merge_collections(
         lambda s, prev: jnp.where(
             part.sum() > 0, modes.mask_rows(part, s).sum(0) / n_live, prev
         ),
@@ -1166,7 +1182,7 @@ def _finalize_client_reduce(mcfg: ModeConfig, wsum, ns_sum, m_sum, net_state, pa
     the participants count."""
     n_live = jnp.maximum(part.sum(), 1.0)
     weighted = wsum if mcfg.agg_op == "sum" else wsum / n_live
-    new_net_state = jax.tree.map(
+    new_net_state = _merge_collections(
         lambda s, prev: jnp.where(part.sum() > 0, s / n_live, prev),
         ns_sum, net_state,
     )
@@ -1547,7 +1563,7 @@ def _merged_survivor_finalize(ns_sum, m_sum, part, net_state):
     layerwise round, the sharded tail and the payload merge cannot drift
     apart."""
     n_live = jnp.maximum(part.sum(), 1.0)
-    new_net_state = jax.tree.map(
+    new_net_state = _merge_collections(
         lambda s, prev: jnp.where(part.sum() > 0, s / n_live, prev),
         ns_sum, net_state,
     )

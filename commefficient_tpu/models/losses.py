@@ -126,6 +126,8 @@ def make_lm_loss(model, train: bool, moe_aux_coef: float = 0.0,
     by MoEMLP, averaged over MoE layers. `model_metrics` adds what the model
     sows under its `metrics` collection (sums, and counts to divide them by:
     the engine sums metrics over clients), each name summed over the layers.
+    `net_state` holds the collections the model reads beside its parameters
+    (`buffers`: a router's selection bias); it is handed on as it came.
     """
 
     def loss_fn(params, net_state, batch, rng):
@@ -136,18 +138,19 @@ def make_lm_loss(model, train: bool, moe_aux_coef: float = 0.0,
         )
         moe_aux = jnp.float32(0.0)
         sown = {}
+        variables = {"params": params, **net_state}
         if model_metrics:
             logits, sown = model.apply(
-                {"params": params}, batch["input_ids"], mutable=["metrics"], **kwargs)
+                variables, batch["input_ids"], mutable=["metrics"], **kwargs)
         elif moe_aux_coef > 0:
             logits, inter = model.apply(
-                {"params": params}, batch["input_ids"],
+                variables, batch["input_ids"],
                 mutable=["intermediates"], **kwargs,
             )
             auxs = jax.tree.leaves(inter)
             moe_aux = sum(jnp.asarray(a).mean() for a in auxs) / max(len(auxs), 1)
         else:
-            logits = model.apply({"params": params}, batch["input_ids"], **kwargs)
+            logits = model.apply(variables, batch["input_ids"], **kwargs)
         # shift: predict token t+1 from prefix ..t
         logits = logits[:, :-1]
         labels = batch["labels"][:, 1:]

@@ -32,6 +32,9 @@ import jax.numpy as jnp
 
 from ..ops import gated_delta, moe
 
+# the blocks this model names with `jax.named_scope` (obs/profiler.BLOCK_SCOPES
+# holds every model's)
+SCOPES = ("gdn", "gated_attn", "moe_route", "moe_experts", "moe_shared", "lm_head")
 
 @dataclasses.dataclass(frozen=True)
 class Qwen3NextConfig:
@@ -216,7 +219,8 @@ class SparseMoE(nn.Module):
                    "down": _weight(self, "experts_down", (G, F, C))}
         tokens = x.reshape(B * T, C)
         y, counts = moe.topk_moe_ffn(tokens, router, experts,
-                                     (cfg.experts_held_first, G), cfg.num_experts_per_tok)
+                                     (cfg.experts_held_first, G), cfg.num_experts_per_tok,
+                                     route=moe.topk_route)
         self.sow("metrics", "moe_assignments", counts["assignments"])
         self.sow("metrics", "moe_assignments_held", counts["assignments_held"])
         self.sow("metrics", "moe_load_max_sum", counts["expert_load_max"])

@@ -113,13 +113,25 @@ def dense_oracle(x, router_w, expert_params, expert_fn):
 # experts would have added is left out (no code stands in for other chips or
 # their exchange). The token-to-expert assignments are sorted by expert, held
 # ones first, and the held experts' matmuls run grouped over the sorted rows
-# (`jax.lax.ragged_dot`: on a TPU a tiled kernel), BLOCK_ROWS assignments at a
+# (`jax.lax.ragged_dot`: on a TPU a tiled kernel), a block of assignments at a
 # time, for as many blocks as assignments landed here: a loop whose trip count
 # is data, so the cost follows the assignments that land here and not E, and
 # no assignment can be dropped however unevenly the router spreads them.
 # ---------------------------------------------------------------------------
 
-BLOCK_ROWS = 1024  # at 2,048 tokens, top-10 of 512 and 16 held: 640 land here
+BLOCK_ROWS = 1024  # the unit a block is sized in
+
+
+def held_block_rows(assignments: int, held: int, experts: int) -> int:
+    """Rows of one block of sorted assignments: whole units of BLOCK_ROWS that
+    hold one and a half times what a uniform router sends to the `held` of
+    `experts` experts. A pass over a block costs the held experts' weights
+    (read forward, recomputed and backward, their gradient added) whatever
+    its rows, so a typical client and layer should take one pass: at 2,048
+    tokens, top-10 of 512 and 16 held 640 land here (1,024 rows), at top-4
+    of 64 and 8 held 1,024 (2,048 rows: with 1,024 about half the clients and
+    layers took two passes and a round's time followed the seed by 2%)."""
+    return BLOCK_ROWS * max(1, math.ceil(1.5 * assignments * held / experts / BLOCK_ROWS))
 
 
 def _sequential_vmap(fn):
@@ -230,25 +242,45 @@ def _held_experts(block_rows: int):
     return held
 
 
+def _router_logits(x, router_w):
+    """The one matmul that runs at `highest` precision: at a TPU's default a
+    float32 matmul rounds its inputs to bfloat16, and which expert comes
+    k-th and which next is a discrete outcome."""
+    return jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
 def topk_route(x, router_w, k: int):
     """x [T, D], router_w [D, E] -> (experts [T, k] int32, weights [T, k]):
     softmax over all E in float32, the k largest, weights renormalised to
-    sum 1. The one matmul runs at `highest` precision: at a TPU's default a
-    float32 matmul rounds its inputs to bfloat16, and which expert comes
-    10th and which 11th is a discrete outcome."""
-    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    top, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    sum 1 (Qwen3-Next's rule, and `topk_moe_ffn`'s when it is given none)."""
+    top, experts = jax.lax.top_k(jax.nn.softmax(_router_logits(x, router_w), axis=-1), k)
     return experts.astype(jnp.int32), top / top.sum(-1, keepdims=True)
 
 
+def sigmoid_topk_route(x, router_w, k: int, bias=None, scale: float = 1.0):
+    """The `noaux_tc` rule with one group (DeepSeek-V3's, GLM-4.7's): every
+    expert scored by sigmoid on its own, the k largest of score + `bias`
+    chosen ([E], a buffer that balances load and that no gradient reaches:
+    the choice is discrete), and the UNBIASED scores of the chosen
+    renormalised (over their sum + 1e-20) and multiplied by `scale`."""
+    scores = jax.nn.sigmoid(_router_logits(x, router_w))
+    _, experts = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    top = jnp.take_along_axis(scores, experts, axis=-1)
+    return experts.astype(jnp.int32), top / (top.sum(-1, keepdims=True) + 1e-20) * scale
+
+
 def topk_moe_ffn(x, router_w, expert_params, held: tuple[int, int], k: int,
-                 block_rows: int = BLOCK_ROWS):
+                 block_rows: int | None = None, route: Callable = topk_route):
     """Top-k expert layer over tokens x [T, D], for the experts held here.
 
     `expert_params` = {"gate": [G, D, F], "up": [G, D, F], "down": [G, F, D]}
     are the G = held[1] experts with ids held[0] .. held[0] + G - 1 of the
     E = router_w.shape[1] the router scores; E(x) = down(silu(gate x) * up x).
+    `route(x, router_w, k) -> (experts [T, k], weights [T, k])` is the model's
+    routing rule (`topk_route`, or `sigmoid_topk_route` with its bias and
+    scale bound); everything after it is one path for every rule.
+    `block_rows` defaults to `held_block_rows` of the shapes.
     Returns (y [T, D], counts): y = sum over a token's chosen experts that
     are held here of weight * E(x); counts = {"assignments": T * k,
     "assignments_held", "expert_load_max"} as float32 scalars, and "experts",
@@ -256,8 +288,10 @@ def topk_moe_ffn(x, router_w, expert_params, held: tuple[int, int], k: int,
     """
     T, D = x.shape
     first, G = held
+    if block_rows is None:
+        block_rows = held_block_rows(T * k, G, router_w.shape[1])
     with jax.named_scope("moe_route"):
-        experts, weights = topk_route(x, router_w, k)
+        experts, weights = route(x, router_w, k)
         local = experts.reshape(-1) - first
         here = (local >= 0) & (local < G)
         key = jnp.where(here, local, G)  # absent experts sort last
@@ -276,11 +310,12 @@ def topk_moe_ffn(x, router_w, expert_params, held: tuple[int, int], k: int,
     return y, counts
 
 
-def topk_dense_oracle(x, router_w, expert_params, held: tuple[int, int], k: int):
+def topk_dense_oracle(x, router_w, expert_params, held: tuple[int, int], k: int,
+                      route: Callable = topk_route):
     """Every held expert over ALL tokens, selected after: what topk_moe_ffn
     must equal (O(G T D F); tests only)."""
     first, G = held
-    experts, weights = topk_route(x, router_w, k)
+    experts, weights = route(x, router_w, k)
     ids = first + jnp.arange(G)
     gate = (weights[:, :, None] * (experts[:, :, None] == ids[None, None, :])).sum(1)  # [T, G]
     every = jax.vmap(lambda g, u, d: (jax.nn.silu(x @ g) * (x @ u)) @ d)(

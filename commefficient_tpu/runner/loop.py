@@ -583,6 +583,10 @@ def run_loop(
                         int(m["moe_assignments_held"]))
                     reg.gauge("model_moe_expert_load_max").set(
                         m["moe_load_max_sum"] / max(m["moe_load_max_count"], 1))
+                if "moe_bias_flips" in m:
+                    # tokens whose chosen experts the selection bias changed
+                    reg.gauge("model_moe_bias_flips_share").set(
+                        m["moe_bias_flips"] / max(m["moe_bias_tokens"], 1))
         phase_hist["commit"].observe((time.perf_counter() - t_commit0) * 1e3)
         for _ in read:
             pending.popleft()

@@ -582,10 +582,13 @@ def make_parser(task: str = "cv") -> argparse.ArgumentParser:
         p.add_argument("--model_config", default="",
                        help="a configuration file (JSON) whose `model` block "
                             "names another architecture to train in GPT-2's "
-                            "place: model_type qwen3_next (Gated DeltaNet + "
-                            "gated attention + top-k experts, "
-                            "models/qwen3_next.py), built offline from that "
-                            "block's keys")
+                            "place, by its model_type: qwen3_next (Gated "
+                            "DeltaNet + gated attention + top-k experts, "
+                            "models/qwen3_next.py) or glm4_moe_lite (latent "
+                            "attention, a dense first layer, experts chosen "
+                            "by biased sigmoid scores, "
+                            "models/glm4_moe_lite.py), built offline from "
+                            "that block's keys")
         p.add_argument("--init_from", default="",
                        help="HF GPT-2 checkpoint dir (config.json + "
                             "pytorch_model.bin) to fine-tune from; the wte is "

@@ -61,7 +61,7 @@ def build(args, fault_plan=None, retry_policy=None):
         mc_hard_negatives=args.mc_hard_negatives,
     )
     args.num_clients = train_set.num_clients
-    model_metrics = False
+    model_metrics, net_state = False, {}
     if args.model_config:
         if (args.init_from or args.moe_experts > 0 or args.mc_coef > 0
                 or args.model_parallel > 1 or args.attn_impl != "dense"
@@ -72,17 +72,25 @@ def build(args, fault_plan=None, retry_policy=None):
                 "--attn_impl ring, --eval_f1, --dtype bfloat16")
         import json
 
-        from commefficient_tpu.models.qwen3_next import Qwen3NextConfig, Qwen3NextLM
+        from commefficient_tpu.federated.engine import READ_ONLY_COLLECTIONS
+        from commefficient_tpu.models import from_model_block
 
         with open(args.model_config) as f:
-            cfg = Qwen3NextConfig.from_model_block(json.load(f)["model"])
+            block = json.load(f)["model"]
+        try:
+            cfg, model = from_model_block(block)
+        except ValueError as e:
+            raise SystemExit(f"--model_config {args.model_config}: {e}") from None
         if tok.vocab_size > cfg.vocab_size:
             raise SystemExit(
                 f"the tokenizer's {tok.vocab_size} ids do not fit the "
                 f"configuration's vocabulary of {cfg.vocab_size}")
-        model = Qwen3NextLM(cfg)
         ids0 = jnp.zeros((1, args.seq_len), dtype=jnp.int32)
-        params = model.init(jax.random.PRNGKey(args.seed), ids0, train=False)["params"]
+        variables = model.init(jax.random.PRNGKey(args.seed), ids0, train=False)
+        params = variables["params"]
+        # what the model reads beside its parameters (a router's selection
+        # bias) is the session's net_state: outside d, and no round changes it
+        net_state = {k: variables[k] for k in READ_ONLY_COLLECTIONS if k in variables}
         model_metrics = True  # the expert layers' counters
         init_note = f"  model_config={args.model_config}"
     elif args.init_from:
@@ -133,7 +141,7 @@ def build(args, fault_plan=None, retry_policy=None):
         params = model.init(jax.random.PRNGKey(args.seed), ids0, train=False)["params"]
         init_note = ""
     d = ravel_pytree(params)[0].size
-    named = "Qwen3Next" if args.model_config else f"GPT2({args.model_size})"
+    named = type(model).__name__ if args.model_config else f"GPT2({args.model_size})"
     print(f"model: {named}  d={d:,}  vocab={cfg.vocab_size}  "
           f"clients={train_set.num_clients}  mode={args.mode}{init_note}", flush=True)
 
@@ -188,7 +196,7 @@ def build(args, fault_plan=None, retry_policy=None):
         train_loss_fn=train_loss,
         eval_loss_fn=eval_loss,
         params=params,
-        net_state={},
+        net_state=net_state,
         mode_cfg=mode_cfg,
         train_set=train_set,
         num_workers=args.num_workers,

@@ -264,3 +264,146 @@ def test_grouped_rows_past_the_last_group_are_zero():
     w = jnp.stack([jnp.full((4, 3), 1.0), jnp.full((4, 3), 2.0)])
     out = moe._grouped(x, w, jnp.asarray([3, 4], jnp.int32))
     np.testing.assert_array_equal(np.asarray(out[:, 0]), [4, 4, 4, 8, 8, 8, 8, 0, 0, 0])
+
+
+# ------------------------------------------------------------------
+# the routing rule as an argument (PR 31): sigmoid scores, a selection bias
+# that chooses and does not weigh, a scale
+
+import functools
+
+
+def _sigmoid_rule(bias, scale=1.8):
+    return functools.partial(moe.sigmoid_topk_route, bias=bias, scale=scale)
+
+
+def test_sigmoid_rule_selects_by_biased_scores_and_weighs_by_unbiased():
+    router, _ = _topk_params(jax.random.PRNGKey(10), 1)
+    x = jax.random.normal(jax.random.PRNGKey(11), (TK_T, TK_D))
+    bias = 0.5 * jax.random.normal(jax.random.PRNGKey(12), (TK_E,))
+    s = jax.nn.sigmoid(x @ router)
+    chosen, weights = moe.sigmoid_topk_route(x, router, TK_K, bias=bias, scale=1.8)
+    np.testing.assert_array_equal(np.asarray(chosen), np.asarray(jax.lax.top_k(s + bias, TK_K)[1]))
+    top = jnp.take_along_axis(s, chosen, axis=-1)
+    np.testing.assert_allclose(np.asarray(weights),
+                               np.asarray(1.8 * top / (top.sum(-1, keepdims=True) + 1e-20)), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 1.8, rtol=1e-6)
+    # no bias, no scale: the k largest scores, weights summing to 1
+    plain, w1 = moe.sigmoid_topk_route(x, router, TK_K)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(jax.lax.top_k(s, TK_K)[1]))
+    np.testing.assert_allclose(np.asarray(w1.sum(-1)), 1.0, rtol=1e-6)
+    assert (np.sort(np.asarray(plain), -1) != np.sort(np.asarray(chosen), -1)).any()
+
+
+def test_a_planted_bias_changes_the_choice_and_not_the_weights_formula():
+    """A bias of +10 on expert 7 puts it in every token's choice (scores lie
+    in (0, 1)); its weight is still its own unbiased score's share."""
+    router, _ = _topk_params(jax.random.PRNGKey(13), 1)
+    x = jax.random.normal(jax.random.PRNGKey(14), (TK_T, TK_D))
+    s = jax.nn.sigmoid(x @ router)
+    before, _ = moe.sigmoid_topk_route(x, router, TK_K)
+    assert not bool((before == 7).any(-1).all())
+    planted = jnp.zeros((TK_E,)).at[7].set(10.0)
+    chosen, weights = moe.sigmoid_topk_route(x, router, TK_K, bias=planted, scale=1.8)
+    assert bool((chosen[:, 0] == 7).all())  # s + 10 is the largest of every row
+    top = jnp.take_along_axis(s, chosen, axis=-1)
+    np.testing.assert_allclose(np.asarray(weights[:, 0]),
+                               np.asarray(1.8 * s[:, 7] / top.sum(-1)), rtol=1e-6)
+    # the other k - 1 are the best of the rest, as before the bias
+    rest = jax.lax.top_k(s.at[:, 7].set(-1.0), TK_K - 1)[1]
+    np.testing.assert_array_equal(np.asarray(chosen[:, 1:]), np.asarray(rest))
+    # the bias gets no gradient: the choice is discrete and the weights do not read it
+    g = jax.grad(lambda b: moe.sigmoid_topk_route(x, router, TK_K, bias=b, scale=1.8)[1].sum())(planted)
+    assert float(jnp.abs(g).max()) == 0.0
+
+
+@pytest.mark.parametrize("held, block_rows", [((0, 4), 16), ((5, 4), 1024), ((8, 4), 40)])
+def test_topk_moe_matches_dense_oracle_under_the_sigmoid_rule(held, block_rows):
+    router, experts = _topk_params(jax.random.PRNGKey(15), held[1])
+    x = jax.random.normal(jax.random.PRNGKey(16), (TK_T, TK_D))
+    rule = _sigmoid_rule(0.5 * jax.random.normal(jax.random.PRNGKey(17), (TK_E,)))
+    y, counts = moe.topk_moe_ffn(x, router, experts, held, TK_K, block_rows, route=rule)
+    want = moe.topk_dense_oracle(x, router, experts, held, TK_K, route=rule)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=1e-5, atol=1e-5)
+    chosen, _ = rule(x, router, TK_K)
+    np.testing.assert_array_equal(np.asarray(counts["experts"]), np.asarray(chosen))
+    here = (chosen >= held[0]) & (chosen < held[0] + held[1])
+    assert float(counts["assignments_held"]) == int(here.sum())
+    # another function than under the softmax rule
+    assert float(jnp.abs(y - moe.topk_moe_ffn(x, router, experts, held, TK_K, block_rows)[0]).max()) > 1e-3
+
+    def grads(fn):
+        return jax.grad(lambda x, r, e: (fn(x, r, e) ** 2).sum(), argnums=(0, 1, 2))(
+            x, router, experts)
+
+    got = grads(lambda x, r, e: moe.topk_moe_ffn(x, r, e, held, TK_K, block_rows, route=rule)[0])
+    ref = grads(lambda x, r, e: moe.topk_dense_oracle(x, r, e, held, TK_K, route=rule))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+
+def test_sigmoid_rule_under_vmap_over_clients_inside_the_client_chunk_scan():
+    held, W, C = (5, 4), 6, 2
+    router, experts = _topk_params(jax.random.PRNGKey(18), held[1])
+    xs = jax.random.normal(jax.random.PRNGKey(19), (W, TK_T, TK_D))
+    rule = _sigmoid_rule(0.5 * jax.random.normal(jax.random.PRNGKey(20), (TK_E,)))
+
+    def client_grad(x, fn):
+        return jax.grad(lambda r, e: (fn(x, r, e, held, TK_K, route=rule) ** 2).sum(),
+                        argnums=(0, 1))(router, experts)
+
+    ffn = lambda *a, **kw: moe.topk_moe_ffn(*a[:5], 32, **kw)[0]  # noqa: E731
+
+    def body(acc, xb):
+        g = jax.vmap(lambda x: client_grad(x, ffn))(xb)
+        return jax.tree.map(lambda a, b: a + b.sum(0), acc, g), None
+
+    init = jax.tree.map(jnp.zeros_like, (router, experts))
+    got, _ = jax.jit(lambda xs: jax.lax.scan(body, init, xs))(xs.reshape(W // C, C, TK_T, TK_D))
+    want = init
+    for x in xs:
+        want = jax.tree.map(jnp.add, want, client_grad(x, moe.topk_dense_oracle))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
+def _parent_topk_moe_ffn(x, router_w, expert_params, held, k, block_rows=moe.BLOCK_ROWS):
+    """ops/moe.topk_moe_ffn as it stood before the routing rule became an
+    argument (PR 30's text, the softmax rule written in place)."""
+    T, D = x.shape
+    first, G = held
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        top, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        experts, weights = experts.astype(jnp.int32), top / top.sum(-1, keepdims=True)
+        local = experts.reshape(-1) - first
+        here = (local >= 0) & (local < G)
+        key = jnp.where(here, local, G)
+        order = jnp.argsort(key, stable=True)
+        group_sizes = (key[:, None] == jnp.arange(G)[None, :]).sum(0).astype(jnp.int32)
+        pad = (-T * k) % block_rows
+        token = jnp.pad(order // k, (0, pad)).astype(jnp.int32)
+        weight = jnp.pad(weights.reshape(-1).astype(x.dtype)[order], (0, pad))
+    with jax.named_scope("moe_experts"):
+        y = moe._held_experts(block_rows)(x, expert_params, token, weight, group_sizes)
+    return y, {"assignments_held": group_sizes.sum().astype(jnp.float32)}
+
+
+def test_qwen3_nexts_expert_layer_lowers_to_the_same_text_as_before():
+    """Qwen3-Next passes no rule and gets the softmax rule: the layer and its
+    gradient lower to the text they lowered to before `route` was an argument."""
+    held = (5, 4)
+    router, experts = _topk_params(jax.random.PRNGKey(21), held[1])
+    x = jax.random.normal(jax.random.PRNGKey(22), (TK_T, TK_D))
+
+    def lowered(ffn):
+        def layer(x, r, e):
+            return jax.value_and_grad(lambda x, r, e: (ffn(x, r, e, held, TK_K, 32)[0] ** 2).sum(),
+                                      argnums=(0, 1, 2))(x, r, e)
+
+        return jax.jit(layer).lower(x, router, experts).as_text()
+
+    assert lowered(moe.topk_moe_ffn) == lowered(_parent_topk_moe_ffn)
+    assert lowered(functools.partial(moe.topk_moe_ffn, route=moe.topk_route)) == lowered(
+        _parent_topk_moe_ffn)

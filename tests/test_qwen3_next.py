@@ -15,6 +15,7 @@ import pytest
 
 from benchmark import counting_qwen3next
 from benchmark.reference import qwen3_next as ref
+from commefficient_tpu.models import qwen3_next
 from commefficient_tpu.models.losses import make_lm_loss
 from commefficient_tpu.models.qwen3_next import TINY, Qwen3NextConfig, Qwen3NextLM
 from commefficient_tpu.obs import profiler
@@ -151,7 +152,7 @@ def _scoped_words(tiny):
 
 def test_forward_and_backward_operations_carry_their_blocks_name(tiny):
     names = _scoped_words(tiny)
-    for block in profiler.BLOCK_SCOPES:
+    for block in qwen3_next.SCOPES:  # BLOCK_SCOPES holds every model's
         assert any(profiler.phase_of(n, profiler.BLOCK_SCOPES) == block for n in names), block
         assert any(profiler.phase_of(n, profiler.BLOCK_SCOPES) == block and "transpose" in n
                    for n in names), block

@@ -197,3 +197,66 @@ def test_chunked_delta_rule_compiles_for_v5e_at_the_published_heads(one_chip):
     compiled = jax.jit(grad).lower(q, q, q, f32(1, 2048, 32), f32(1, 2048, 32)).compile()
     need = compiled.memory_analysis().temp_size_in_bytes
     assert need < 2 * 1024 ** 3, need
+
+
+# --- GLM-4.7-Flash's two blocks at the published widths (PR 31) ---
+
+
+def _glm_block_shapes(module, x, one_chip):
+    """(variables of `module` as shapes on the described chip, x likewise)."""
+    on = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    variables = jax.eval_shape(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros(x.shape)))
+    return on({k: v for k, v in variables.items() if k in ("params", "buffers")}), on(x)
+
+
+def test_latent_attention_compiles_for_v5e_at_the_published_widths(one_chip):
+    """One client's sequence of 2,048 tokens through the latent-attention
+    block (latents 768 and 512, 20 heads of 192 + 64 and 256), forward and
+    backward: the T x T scores of 20 heads are 336 MB a copy, and with them
+    recomputed the gradient keeps under four copies alive."""
+    from commefficient_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig, LatentAttention
+
+    cfg = Glm4MoeLiteConfig()
+    block = LatentAttention(cfg)
+    variables, x = _glm_block_shapes(block, jax.ShapeDtypeStruct((1, 2048, 2048), jnp.float32),
+                                     one_chip)
+    assert sum(int(np.prod(v.shape)) for v in jax.tree.leaves(variables)) == 21_759_232
+    grad = jax.grad(lambda v, x: block.apply(v, x).sum(), argnums=(0, 1))
+    compiled = jax.jit(grad).lower(variables, x).compile()
+    scores = 20 * 2048 * 2048 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * scores
+
+
+def test_glm_expert_layer_compiles_to_the_ragged_dot_kernel_under_the_sigmoid_rule(one_chip):
+    """One client's 2,048 tokens through the expert block (sigmoid top-4 of
+    64 with the selection bias, 8 experts of width 1,536 held, the shared
+    expert), forward and backward under the engine's vmap: the grouped
+    products reach the TPU's ragged-dot kernel unbatched, as Qwen3-Next's do,
+    and no dense product over all the held experts' rows is made. The router's
+    product (the one matmul at `highest` precision) is made once, though the
+    block routes a second time without the bias for its `moe_bias_flips` sum."""
+    from commefficient_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig, SparseMoE
+
+    cfg = Glm4MoeLiteConfig(n_routed_experts=8)
+    block = SparseMoE(cfg)
+    variables, x = _glm_block_shapes(block, jax.ShapeDtypeStruct((1, 2048, 2048), jnp.float32),
+                                     one_chip)
+    assert variables["buffers"]["e_score_correction_bias"].shape == (64,)
+
+    def client_grad(x, variables):
+        return jax.grad(lambda p: block.apply({**variables, "params": p}, x[None]).sum())(
+            variables["params"])
+
+    text = jax.jit(jax.vmap(client_grad, in_axes=(0, None))).lower(x, variables).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and line.lstrip().startswith("%ragged-dot-none")]
+    assert len(calls) >= 6, len(calls)
+    assert f"f32[8,{2048 * 4},2048]" not in text  # the dense fallback's expanded rows
+    # one block holds one and a half times the 1,024 assignments a uniform
+    # router sends here, in whole units of 1,024 rows (moe.held_block_rows)
+    assert any("f32[2048,1536]" in line for line in calls) and not any(
+        "f32[1024,1536]" in line for line in calls)
+    router = [line for line in text.splitlines()
+              if "operand_precision={highest,highest}" in line and " f32[2048,64]" in line]
+    assert len(router) == 1 and "moe_route" in router[0], router
