@@ -34,11 +34,13 @@ class ModeConfig:
     hash_family: str = "rotation"  # sketch bucket-hash family (see CSVecSpec);
     # "rotation" is the TPU-fast default, "random" the reference-like one
     topk_impl: str = "exact"  # server/client top-k selection: "exact"
-    # (lax.top_k), "approx" (lax.approx_max_k, TPU PartialReduce lowering
-    # at topk_recall; exact elsewhere), or "oversample" (approx preselect
-    # of 4k candidates + exact refine — near-exact at PartialReduce
-    # speed; csvec.topk_abs). Approx dodges the TPU sort-based top_k at d
-    # in the millions. Accuracy impact: the paper-scale 2x2 seed
+    # (lax.top_k's result; from csvec.TOPK_SELECT_MIN_N elements on by a
+    # counted threshold and a compaction, csvec.select_topk_abs, not by
+    # the full sort the TPU lowers lax.top_k to), "approx"
+    # (lax.approx_max_k, TPU PartialReduce lowering at topk_recall; exact
+    # elsewhere), or "oversample" (approx preselect of 4k candidates +
+    # exact refine — near-exact at PartialReduce speed; csvec.topk_abs).
+    # Accuracy impact of approx: the paper-scale 2x2 seed
     # replication put exact-vs-approx@0.99 within seed variance
     # (single-seed orderings inverted across seeds — results/README.md),
     # so any recall cost is below that study's resolution; "oversample"

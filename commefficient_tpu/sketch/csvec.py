@@ -16,6 +16,9 @@ design rather than accident:
 - **Static shapes throughout**: `unsketch_topk` returns exactly-`k` results by
   merging per-block `lax.top_k` candidates in the scan carry, so the whole
   thing jits and vmaps.
+- **Exact top-k without a sort of d** (`select_topk_abs`): on the single-shot
+  path the k largest of millions of estimates are found by a counted
+  threshold and a two-level compaction, and only those k are sorted.
 
 Estimate semantics match the reference: the estimate of coordinate `i` is the
 median over the `r` rows of `sign[row, i] * table[row, bucket[row, i]]`, and
@@ -388,6 +391,133 @@ def query_all(spec: CSVecSpec, table: jnp.ndarray) -> jnp.ndarray:
 # (approx_max_k's misses concentrate at the selection boundary)
 TOPK_OVERSAMPLE = 4
 
+# impl="exact" selects (select_topk_abs) where n is at least this and at
+# least TOPK_SELECT_MIN_N_PER_K * k, and sorts (lax.top_k) below. Measured on
+# a v5e at k = 50,000 (PERF.md section 6, PR 32): n = 200,000 sort 0.255 ms /
+# selection 0.418, n = 350,000 sort 0.465 / selection 0.405. The selection's
+# floor is its k-sized steps, 7 ns a slot (two gathers of k rows of 128 keys,
+# three passes over them, the sort of k pairs), against the sort's 1.3-2 ns
+# an element; they also hold k x 128 keys, so a k near n would cost more
+# memory than the sort it replaces.
+TOPK_SELECT_MIN_N = 350_000
+TOPK_SELECT_MIN_N_PER_K = 7
+
+_LANES = 128  # a row of the selection's two-level prefix: one lane tile
+# value bits a counting pass settles (2^bits - 1 counts a pass): on a v5e at
+# n = 6,573,130 the whole selection takes 0.79 / 0.69 / 0.76 / 0.88 ms at
+# 1 / 2 / 3 / 4 bits (a pass is 11 us at 2 bits once x sits in VMEM, 52 us at
+# 4 bits, bound by the compares)
+_THRESHOLD_BITS = 2
+
+
+def _kth_largest_key(keys: jnp.ndarray, k: int) -> jnp.ndarray:
+    """The k-th largest of non-negative int32 `keys`, by counting: the
+    value's 31 bits from the top, _THRESHOLD_BITS a pass; a pass counts the
+    keys at or above every candidate prefix in one read (sibling reductions
+    of one fusion) and keeps the largest that still has k."""
+    t = jnp.int32(0)
+    hi = 31
+    while hi > 0:
+        shift = max(hi - _THRESHOLD_BITS, 0)
+        digit = jnp.int32(0)
+        for j in range(1, 1 << (hi - shift)):
+            at_or_above = jnp.sum(keys >= (t | jnp.int32(j << shift)),
+                                  dtype=jnp.int32)
+            digit += (at_or_above >= k).astype(jnp.int32)
+        t = t | (digit << shift)
+        hi = shift
+    return t
+
+
+def _slot_rows(incl: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """For output slots 0..k-1 of a compaction whose rows hold `incl[r]`
+    selected elements up to and including row r (non-decreasing, last = k):
+    the row each slot falls in and the slot's rank inside that row. A
+    two-level search with no scatter and no k-long chain of gathers: rows
+    are grouped 128 to a block, a slot finds its block by comparing with
+    every block's last count, gathers that block's 128 counts as ONE row
+    (a row gather costs the chip a fifth of a scalar gather) and finds its
+    row by comparing again."""
+    pad = -incl.shape[0] % _LANES
+    blocks = jnp.pad(incl, (0, pad), constant_values=k).reshape(-1, _LANES)
+    last = blocks[:, -1]
+    slot = jnp.arange(k, dtype=jnp.int32)[:, None]
+    passed = last[None, :] <= slot  # blocks that end at or before the slot
+    blk = jnp.sum(passed, axis=1, dtype=jnp.int32)
+    counts = blocks[blk]  # [k, 128]
+    before = counts <= slot
+    row = blk * _LANES + jnp.sum(before, axis=1, dtype=jnp.int32)
+    # selected elements in all rows before `row`: the largest count passed
+    start = jnp.maximum(jnp.max(jnp.where(passed, last[None, :], 0), axis=1),
+                        jnp.max(jnp.where(before, counts, 0), axis=1))
+    return row, slot[:, 0] - start
+
+
+def select_topk_abs(x: jnp.ndarray, k: int) -> jnp.ndarray:
+    """`jax.lax.top_k(jnp.abs(x), k)[1]` element for element (ties and
+    non-finite values included) without sorting x: find the k-th largest
+    magnitude by counting, compact what lies above it, sort those k.
+
+    `lax.top_k` lowers to a full key-value sort on the TPU: 13.0 ms at
+    n = 6,573,130, k = 50,000 on a v5e, 0.5% of what the bytes allow; this
+    takes 0.69 ms there (PERF.md section 6, PR 32). Every n-sized step is an
+    elementwise pass or a row reduction; the rest is k-sized (k x 128 keys
+    at the widest) or n/128-sized. One-dimensional x (callers vmap).
+
+    1. Keys: |x| as its int32 bit pattern, which orders as the value does
+       for non-negative floats; -0.0 becomes +0.0 and a NaN sorts above inf,
+       as in lax.top_k's total order.
+    2. Threshold t: the k-th largest key (_kth_largest_key).
+    3. Exactly k with lax.top_k's tie rule (its sort is stable: value
+       descending, then index ascending): every key > t and the first
+       k - #(key > t) keys == t in index order, i.e. those at or before the
+       flat index `cut`.
+    4. Compaction over rows of 128: per-row counts, one prefix over the
+       n/128 rows, the row and in-row rank of each output slot
+       (_slot_rows), a gather of those k rows, the lane from an in-row
+       prefix (a product with a triangle of ones: exact, the counts are at
+       most 128).
+    5. lax.top_k's order: sort the k selected by key descending, index
+       ascending."""
+    (n,) = x.shape
+    keys = jax.lax.bitcast_convert_type(
+        jnp.abs(x).astype(jnp.float32), jnp.int32)
+    t = _kth_largest_key(keys, k)
+    # padding keys are -1: below every real key, so never selected
+    rows = jnp.pad(keys, (0, -n % _LANES), constant_values=-1).reshape(
+        -1, _LANES)
+    lane = jax.lax.iota(jnp.int32, _LANES)
+    upper = (lane[:, None] <= lane[None, :]).astype(jnp.bfloat16)
+
+    def prefix(mask):  # inclusive count along the lanes
+        return jnp.dot(mask.astype(jnp.bfloat16), upper,
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    # the tie rule: `need` of the keys == t are taken, lowest index first;
+    # `cut` is the flat index of the last one
+    need = k - jnp.sum(rows > t, dtype=jnp.int32)
+    ties = jnp.cumsum(jnp.sum(rows == t, axis=1, dtype=jnp.int32))
+    tie_row = jnp.sum(ties < need, dtype=jnp.int32)  # the row `cut` lies in
+    in_row = prefix(
+        jax.lax.dynamic_index_in_dim(rows, tie_row, keepdims=False) == t)
+    need_in_row = need - (ties[tie_row] - in_row[-1])
+    cut = tie_row * _LANES + jnp.sum(in_row < need_in_row, dtype=jnp.int32)
+
+    def selected(keys_, index):
+        return (keys_ > t) | ((keys_ == t) & (index <= cut))
+
+    index = jax.lax.iota(jnp.int32, rows.size).reshape(rows.shape)
+    incl = jnp.cumsum(jnp.sum(selected(rows, index), axis=1, dtype=jnp.int32))
+    row, rank = _slot_rows(incl, k)
+    picked = rows[row]  # [k, 128]: the row each output slot falls in
+    picked_index = row[:, None] * _LANES + lane[None, :]
+    col = jnp.sum(prefix(selected(picked, picked_index)) <= rank[:, None],
+                  axis=1, dtype=jnp.int32)  # lane of the row's rank-th selected
+    key = jnp.sum(jnp.where(lane[None, :] == col[:, None], picked, 0), axis=1)
+    # ~key ascending is key descending; the index breaks ties as top_k does
+    _, idx = jax.lax.sort((~key, row * _LANES + col), num_keys=2)
+    return idx
+
 
 def topk_abs(
     x: jnp.ndarray, k: int, approx: bool = False, recall: float = 0.95,
@@ -396,8 +526,13 @@ def topk_abs(
     """Indices of the k largest-|.| entries. Single home for the top-k
     selection branch (ModeConfig.topk_impl / topk_recall):
 
-    - "exact": `lax.top_k` (sort-based — a wall at d in the millions on
-      TPU: 442 ms at d=124M vs 4.4 ms approx, r5 server_split).
+    - "exact": the k largest, ties by lowest index, in `lax.top_k`'s
+      order. From max(TOPK_SELECT_MIN_N, TOPK_SELECT_MIN_N_PER_K * k)
+      elements on by `select_topk_abs`
+      (threshold and compaction: 0.69 ms at n = 6.57M, k = 50,000 on a
+      v5e), below it by `lax.top_k` itself, which the TPU lowers to a full
+      sort of x (13.0 ms there; 442 ms at d = 124M, r5 server_split). Both
+      return the same array.
     - "approx": `lax.approx_max_k` (TPU PartialReduce at `recall`; exact
       lowering elsewhere). Accuracy impact at paper scale is within seed
       variance for recall 0.99 (2x2 seed replication inverted the
@@ -423,6 +558,9 @@ def topk_abs(
             return cand[sub]
     if impl == "approx":
         _, idx = jax.lax.approx_max_k(jnp.abs(x), k, recall_target=recall)
+    elif (x.shape[0] >= max(TOPK_SELECT_MIN_N, TOPK_SELECT_MIN_N_PER_K * k)
+          and x.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)):
+        idx = select_topk_abs(x, k)  # the same array, without the sort of x
     else:
         _, idx = jax.lax.top_k(jnp.abs(x), k)
     return idx.astype(jnp.int32)

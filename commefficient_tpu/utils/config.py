@@ -31,7 +31,8 @@ def make_parser(task: str = "cv") -> argparse.ArgumentParser:
                         "(default), random = reference-like per-coordinate hashing")
     p.add_argument("--topk_impl", default="exact",
                    choices=["exact", "approx", "oversample"],
-                   help="top-k selection: exact (lax.top_k), approx "
+                   help="top-k selection: exact (lax.top_k's result, by "
+                        "threshold and compaction where d is large), approx "
                         "(lax.approx_max_k, TPU-fast at --topk_recall; "
                         "paper-scale accuracy impact within seed variance "
                         "at recall 0.99 — results/README.md), or oversample "

@@ -2,6 +2,7 @@
 linearity, seed-determinism, block-count invariance, heavy-hitter recovery,
 unbiasedness of single-coordinate estimates, sparse==dense sketching."""
 
+import re
 from dataclasses import replace as dataclasses_replace
 
 import jax
@@ -290,3 +291,161 @@ def test_mask_transmitted_matches_unfused():
         V_f, E_f = csvec_mod.mask_transmitted(spec, V, E, idx, vals)
         np.testing.assert_array_equal(np.asarray(V_ref), np.asarray(V_f), err_msg=family)
         np.testing.assert_array_equal(np.asarray(E_ref), np.asarray(E_f), err_msg=family)
+
+
+# --- exact top-k by threshold and compaction (select_topk_abs) -------------
+# Called directly, so that toy sizes reach it whatever TOPK_SELECT_MIN_N says.
+# Every case compares with jax.lax.top_k(jnp.abs(x), k)[1] ELEMENT FOR
+# ELEMENT: the order is what mask_transmitted's scatter-adds see.
+
+def _topk_ref(x, k):
+    return np.asarray(jax.lax.top_k(jnp.abs(x), k)[1])
+
+
+def _select_cases():
+    rng = np.random.RandomState(11)
+    normal = rng.randn(5000).astype(np.float32)
+    eight = (rng.choice(np.arange(1, 9), 5000) * rng.choice([-1, 1], 5000)
+             ).astype(np.float32)
+    zeros = np.zeros(3000, np.float32)
+    zeros[::3] = -0.0
+    # T falls on a tie that has to be split: 40 elements of magnitude 2 among
+    # 30 larger ones; k = 45 takes the 30 and the FIRST 15 of the 40 by index
+    split = rng.uniform(-1, 1, 4000).astype(np.float32)
+    split[rng.choice(4000, 70, replace=False)] = np.r_[
+        rng.uniform(3, 9, 30), np.full(40, 2.0)] * rng.choice([-1, 1], 70)
+    nonfinite = rng.randn(4000).astype(np.float32)
+    nonfinite[[5, 2000]] = np.inf
+    nonfinite[77] = -np.inf
+    nonfinite[[100, 3999]] = np.nan
+    nonfinite[3000] = -np.nan
+    return {
+        "normal": (normal, 777),
+        "eight_magnitudes": (eight, 1234),
+        "all_equal": (np.full(3000, -2.5, np.float32), 100),
+        "all_zero_with_negative_zero": (zeros, 500),
+        "tie_split_at_threshold": (split, 45),
+        "k_1": (normal, 1),
+        "k_n": (normal[:1280], 1280),
+        "k_n_minus_1": (normal[:1280], 1279),
+        "n_not_multiple_of_128": (normal[:1000], 137),
+        "n_below_128": (normal[:100], 7),
+        "n_1": (normal[:1], 1),
+        "inf_and_nan": (nonfinite, 50),
+        "nan_at_the_threshold": (nonfinite, 3),
+        "bfloat16": (normal.astype(jnp.bfloat16), 300),
+    }
+
+
+_SELECT_CASES = _select_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_SELECT_CASES))
+def test_select_topk_abs_equals_lax_top_k(case):
+    x, k = _SELECT_CASES[case]
+    x = jnp.asarray(x)
+    got = np.asarray(csvec_mod.select_topk_abs(x, k))
+    np.testing.assert_array_equal(got, _topk_ref(x, k))
+    assert got.dtype == np.int32 and got.shape == (k,)
+
+
+@pytest.mark.parametrize("bits", [1, 2, 3, 4, 5])
+def test_select_topk_abs_any_bits_a_pass(bits, monkeypatch):
+    """The threshold is the same k-th largest key however many value bits a
+    counting pass settles (31 is a multiple of none of 2, 3, 4, 5, so the
+    last, shorter pass is exercised). Called unjitted: the constant is read
+    while tracing, and a cached trace would not see the patch."""
+    monkeypatch.setattr(csvec_mod, "_THRESHOLD_BITS", bits)
+    x, k = _SELECT_CASES["eight_magnitudes"]
+    np.testing.assert_array_equal(
+        np.asarray(csvec_mod.select_topk_abs(jnp.asarray(x), k)),
+        _topk_ref(jnp.asarray(x), k))
+
+
+def test_select_topk_abs_under_jit():
+    x, k = _SELECT_CASES["tie_split_at_threshold"]
+    got = jax.jit(csvec_mod.select_topk_abs, static_argnums=1)(jnp.asarray(x), k)
+    np.testing.assert_array_equal(np.asarray(got), _topk_ref(jnp.asarray(x), k))
+
+
+def test_select_topk_abs_under_vmap_rows_with_different_thresholds():
+    """local_topk runs it per client under vmap: three rows whose k-th
+    magnitudes differ by orders of magnitude, one of them all ties."""
+    rng = np.random.RandomState(12)
+    xs = rng.randn(3, 3000).astype(np.float32) * np.array(
+        [[1.0], [1e-3], [1e4]], np.float32)
+    xs[1] = np.sign(xs[1]) * 0.25
+    xs = jnp.asarray(xs)
+    got = jax.jit(jax.vmap(lambda v: csvec_mod.select_topk_abs(v, 200)))(xs)
+    want = jax.vmap(lambda v: jax.lax.top_k(jnp.abs(v), 200)[1])(xs)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_topk_abs_exact_above_the_constant_equals_lax_top_k():
+    """Through topk_abs at an n where it takes the selection, on ties."""
+    n, k = csvec_mod.TOPK_SELECT_MIN_N + 77, 5000
+    rng = np.random.RandomState(13)
+    x = jnp.asarray((rng.choice(np.arange(1, 200), n) * rng.choice([-1, 1], n))
+                    .astype(np.float32))
+    got = jax.jit(lambda v: csvec_mod.topk_abs(v, k, impl="exact"))(x)
+    np.testing.assert_array_equal(np.asarray(got), _topk_ref(x, k))
+
+
+def _lowered(fn, n):
+    low = jax.jit(fn).lower(jax.ShapeDtypeStruct((n,), jnp.float32))
+    return low.as_text(), low.compile().as_text()
+
+
+@pytest.mark.parametrize("n,k,selects", [
+    (csvec_mod.TOPK_SELECT_MIN_N, 1000, True),
+    (csvec_mod.TOPK_SELECT_MIN_N - 1, 1000, False),  # below the constant
+    (csvec_mod.TOPK_SELECT_MIN_N,  # k rows of 128 would outweigh the sort
+     csvec_mod.TOPK_SELECT_MIN_N // csvec_mod.TOPK_SELECT_MIN_N_PER_K + 1,
+     False),
+], ids=["at_the_constant", "below_it", "k_too_near_n"])
+def test_topk_abs_exact_chooses_by_static_shape(n, k, selects):
+    text, _ = _lowered(lambda v: csvec_mod.topk_abs(v, k, impl="exact"), n)
+    assert ("chlo.top_k" not in text) == selects
+
+
+def test_exact_topk_lowers_to_no_sort_scan_or_scatter_over_n():
+    """The guard that keeps the full sort from coming back (in the manner of
+    test_linear_round_program_holds_no_client_stack): above the constant the
+    exact branch holds no sort, no top-k, no cumulative reduction and no
+    scatter over n elements, in the lowered text or the compiled one. What
+    it does sort is the k selected; what it scans is the n/128 row counts."""
+    n, k = csvec_mod.TOPK_SELECT_MIN_N + 77, 1000
+    rows = -(-n // 128)
+    n_sized = (f"{n}", f"{rows * 128}", f"{rows}x128", f"{rows},128")
+    text, compiled = _lowered(
+        lambda v: csvec_mod.topk_abs(v, k, impl="exact"), n)
+    assert f"tensor<{n}xf32>" in text and f"f32[{n}]" in compiled
+    assert "chlo.top_k" not in text and "stablehlo.scatter" not in text
+    sorts = re.findall(r'"stablehlo\.sort"\(.*?\) -> \((.*?)\)', text, re.S)
+    assert sorts == [f"tensor<{k}xi32>, tensor<{k}xi32>"], sorts
+    scans = re.findall(
+        r'"stablehlo\.reduce_window"\(.*?\) -> tensor<(\w+)>', text, re.S)
+    assert scans and all(s == f"{rows}xi32" for s in scans), scans
+    # the CPU compiles lax.top_k to a custom call that names its n-long
+    # operand without its shape, so it is refused by its target; the TPU
+    # lowers it to a sort over f32[n], which the scan of the lines refuses
+    assert 'custom_call_target="TopK"' not in compiled
+    for line in compiled.splitlines():
+        if re.search(r" (sort|reduce-window|scatter)\(", line):
+            assert not any(s in line for s in n_sized), line
+
+
+def test_approx_topk_lowers_as_before():
+    """The bypass: impl="approx" is lax.approx_max_k's own lowering, word for
+    word, and holds nothing of the selection (no key bitcast, no sort of the
+    k selected, no row gather, no prefix product)."""
+    n, k = csvec_mod.TOPK_SELECT_MIN_N + 77, 1000
+    text, _ = _lowered(
+        lambda v: csvec_mod.topk_abs(v, k, impl="approx", recall=0.99), n)
+    plain, _ = _lowered(
+        lambda v: jax.lax.approx_max_k(
+            jnp.abs(v), k, recall_target=0.99)[1].astype(jnp.int32), n)
+    assert "@ApproxTopK" in text and text == plain
+    for op in ("bitcast_convert", "sort", "gather", "dot_general",
+               "reduce_window"):
+        assert f"stablehlo.{op}" not in text, op
