@@ -7,6 +7,7 @@ import importlib
 FAMILIES = {
     "qwen3_next": ("qwen3_next", "Qwen3NextConfig", "Qwen3NextLM"),
     "glm4_moe_lite": ("glm4_moe_lite", "Glm4MoeLiteConfig", "Glm4MoeLiteLM"),
+    "lfm2_moe": ("lfm2_moe", "Lfm2MoeConfig", "Lfm2MoeLM"),
 }
 
 

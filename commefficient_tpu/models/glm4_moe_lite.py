@@ -152,6 +152,41 @@ class LatentAttention(nn.Module):
         return y.reshape(B, T, H * dv) @ _weight(self, "o_proj", (H * dv, C))
 
 
+def biased_experts(module, tokens, *, held: tuple[int, int], width: int, k: int,
+                   router_experts: int, bias_name: str, scale: float, eps: float = 1e-20):
+    """The routed experts `module` holds of a layer whose router chooses by
+    sigmoid score + a selection bias (`ops/moe.sigmoid_topk_route`), for
+    tokens [N, C]: the router and the experts' weights as the module's
+    parameters, the bias as its buffer `bias_name`, the expert counters sown
+    under `metrics` and the choices under `intermediates`. Returns the held
+    experts' part of the result, [N, C]."""
+    N, C = tokens.shape
+    first, G = held
+    router = _weight(module, "router", (C, router_experts))
+    bias = module.variable("buffers", bias_name, lambda: 0.01 * jax.random.normal(
+        module.make_rng("params"), (router_experts,), jnp.float32)).value
+    experts = {"gate": _weight(module, "experts_gate", (G, C, width)),
+               "up": _weight(module, "experts_up", (G, C, width)),
+               "down": _weight(module, "experts_down", (G, width, C))}
+    rule = functools.partial(moe.sigmoid_topk_route, bias=bias, scale=scale, eps=eps)
+    y, counts = moe.topk_moe_ffn(tokens, router, experts, held, k, route=rule)
+    with jax.named_scope("moe_route"):
+        # the tokens whose chosen set the bias changed: the compiler
+        # shares the router's product with the rule's own (pinned in
+        # tests/test_tpu_compile.py), so this is one more top-k of
+        # [T, E] and two sorts of [T, k]
+        unbiased, _ = moe.sigmoid_topk_route(tokens, router, k)
+        flips = (jnp.sort(unbiased, -1) != jnp.sort(counts["experts"], -1)).any(-1)
+    module.sow("metrics", "moe_assignments", counts["assignments"])
+    module.sow("metrics", "moe_assignments_held", counts["assignments_held"])
+    module.sow("metrics", "moe_load_max_sum", counts["expert_load_max"])
+    module.sow("metrics", "moe_load_max_count", jnp.float32(1.0))
+    module.sow("metrics", "moe_bias_flips", flips.sum().astype(jnp.float32))
+    module.sow("metrics", "moe_bias_tokens", jnp.float32(N))
+    module.sow("intermediates", "moe_choices", counts["experts"])
+    return y
+
+
 class SparseMoE(nn.Module):
     """The routed experts held here (ops/moe.py) plus the shared expert."""
 
@@ -161,32 +196,13 @@ class SparseMoE(nn.Module):
     def __call__(self, x):
         cfg = self.cfg
         B, T, C = x.shape
-        G, F, k = cfg.n_routed_experts, cfg.moe_intermediate_size, cfg.num_experts_per_tok
-        router = _weight(self, "router", (C, cfg.router_num_experts))
-        bias = self.variable("buffers", "e_score_correction_bias", lambda: 0.01 * jax.random.normal(
-            self.make_rng("params"), (cfg.router_num_experts,), jnp.float32)).value
-        experts = {"gate": _weight(self, "experts_gate", (G, C, F)),
-                   "up": _weight(self, "experts_up", (G, C, F)),
-                   "down": _weight(self, "experts_down", (G, F, C))}
+        F = cfg.moe_intermediate_size
         tokens = x.reshape(B * T, C)
-        rule = functools.partial(moe.sigmoid_topk_route, bias=bias,
-                                 scale=cfg.routed_scaling_factor)
-        y, counts = moe.topk_moe_ffn(tokens, router, experts, (cfg.experts_held_first, G), k,
-                                     route=rule)
-        with jax.named_scope("moe_route"):
-            # the tokens whose chosen set the bias changed: the compiler
-            # shares the router's product with the rule's own (pinned in
-            # tests/test_tpu_compile.py), so this is one more top-k of
-            # [T, E] and two sorts of [T, k]
-            unbiased, _ = moe.sigmoid_topk_route(tokens, router, k)
-            flips = (jnp.sort(unbiased, -1) != jnp.sort(counts["experts"], -1)).any(-1)
-        self.sow("metrics", "moe_assignments", counts["assignments"])
-        self.sow("metrics", "moe_assignments_held", counts["assignments_held"])
-        self.sow("metrics", "moe_load_max_sum", counts["expert_load_max"])
-        self.sow("metrics", "moe_load_max_count", jnp.float32(1.0))
-        self.sow("metrics", "moe_bias_flips", flips.sum().astype(jnp.float32))
-        self.sow("metrics", "moe_bias_tokens", jnp.float32(B * T))
-        self.sow("intermediates", "moe_choices", counts["experts"])
+        y = biased_experts(self, tokens, held=(cfg.experts_held_first, cfg.n_routed_experts),
+                           width=F, k=cfg.num_experts_per_tok,
+                           router_experts=cfg.router_num_experts,
+                           bias_name="e_score_correction_bias",
+                           scale=cfg.routed_scaling_factor)
         with jax.named_scope("moe_shared"):
             y = y + swiglu(self, tokens, F * cfg.n_shared_experts, "shared_")
         return y.reshape(B, T, C)

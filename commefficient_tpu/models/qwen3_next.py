@@ -171,6 +171,18 @@ class GatedDeltaNet(nn.Module):
         return o.reshape(B, T, value_dim) @ w_out
 
 
+@jax.checkpoint
+def grouped_causal_attention(q, k, v):
+    """Causal softmax attention of q [B, T, KV, n, hd] over k, v [B, T, KV, hd]:
+    each key/value head serves its n query heads. Unfused T x T scores,
+    recomputed in the backward pass (two copies of them are kept otherwise)."""
+    T, hd = q.shape[1], q.shape[-1]
+    att = jnp.einsum("bqgnd,bkgd->bgnqk", q, k) * hd ** -0.5
+    causal = jnp.tril(jnp.ones((T, T), bool))
+    att = jax.nn.softmax(jnp.where(causal, att, jnp.finfo(att.dtype).min), axis=-1)
+    return jnp.einsum("bgnqk,bkgd->bqgnd", att, v)
+
+
 class GatedAttention(nn.Module):
     cfg: Qwen3NextConfig
 
@@ -189,16 +201,7 @@ class GatedAttention(nn.Module):
         q = rotary(q, cfg.rope_theta, cfg.rotary_dim).reshape(B, T, KV, H // KV, hd)
         k = rotary(k, cfg.rope_theta, cfg.rotary_dim)
 
-        # each key/value head serves H / KV query heads; unfused T x T scores,
-        # recomputed in the backward pass (two copies of them are kept otherwise)
-        @jax.checkpoint
-        def attend(q, k, v):
-            att = jnp.einsum("bqgnd,bkgd->bgnqk", q, k) * hd ** -0.5
-            causal = jnp.tril(jnp.ones((T, T), bool))
-            att = jax.nn.softmax(jnp.where(causal, att, jnp.finfo(att.dtype).min), axis=-1)
-            return jnp.einsum("bgnqk,bkgd->bqgnd", att, v)
-
-        y = attend(q, k, v).reshape(B, T, H, hd)
+        y = grouped_causal_attention(q, k, v).reshape(B, T, H, hd)
         y = (y * jax.nn.sigmoid(gate)).reshape(B, T, H * hd)
         return y @ _weight(self, "o_proj", (H * hd, C))
 

@@ -48,11 +48,11 @@ MODULES_LINE = "XLA Modules"
 SCOPE_STAT = "tf_op"
 OTHER = "other"
 # The kinds of block a model may name inside its forward pass
-# (models/qwen3_next.py and models/glm4_moe_lite.py do): the second
+# (models/qwen3_next.py, glm4_moe_lite.py and lfm2_moe.py do): the second
 # reduction's scopes. Backward operations carry their block's name as
 # transpose(jvp(<name>)).
 BLOCK_SCOPES = ("gdn", "gated_attn", "moe_route", "moe_experts", "moe_shared",
-                "lm_head", "mla", "dense_mlp")
+                "lm_head", "mla", "dense_mlp", "short_conv", "gqa_attn")
 _WORD = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 
 

@@ -258,16 +258,17 @@ def topk_route(x, router_w, k: int):
     return experts.astype(jnp.int32), top / top.sum(-1, keepdims=True)
 
 
-def sigmoid_topk_route(x, router_w, k: int, bias=None, scale: float = 1.0):
-    """The `noaux_tc` rule with one group (DeepSeek-V3's, GLM-4.7's): every
-    expert scored by sigmoid on its own, the k largest of score + `bias`
-    chosen ([E], a buffer that balances load and that no gradient reaches:
-    the choice is discrete), and the UNBIASED scores of the chosen
-    renormalised (over their sum + 1e-20) and multiplied by `scale`."""
+def sigmoid_topk_route(x, router_w, k: int, bias=None, scale: float = 1.0,
+                       eps: float = 1e-20):
+    """The `noaux_tc` rule with one group (DeepSeek-V3's, GLM-4.7's; LFM2's
+    with `eps` 1e-6): every expert scored by sigmoid on its own, the k largest
+    of score + `bias` chosen ([E], a buffer that balances load and that no
+    gradient reaches: the choice is discrete), and the UNBIASED scores of the
+    chosen renormalised (over their sum + `eps`) and multiplied by `scale`."""
     scores = jax.nn.sigmoid(_router_logits(x, router_w))
     _, experts = jax.lax.top_k(scores if bias is None else scores + bias, k)
     top = jnp.take_along_axis(scores, experts, axis=-1)
-    return experts.astype(jnp.int32), top / (top.sum(-1, keepdims=True) + 1e-20) * scale
+    return experts.astype(jnp.int32), top / (top.sum(-1, keepdims=True) + eps) * scale
 
 
 def topk_moe_ffn(x, router_w, expert_params, held: tuple[int, int], k: int,

@@ -186,7 +186,7 @@ def test_every_family_is_built_from_its_committed_model_block(name):
         config = json.load(f)
     block = config.get("model", {})
     if block.get("model_type") not in models.FAMILIES:
-        with pytest.raises(ValueError, match="glm4_moe_lite, qwen3_next"):
+        with pytest.raises(ValueError, match="glm4_moe_lite, lfm2_moe, qwen3_next"):
             models.from_model_block(block)
         return
     cfg, model = models.from_model_block(block)
@@ -198,6 +198,7 @@ def test_every_family_is_built_from_its_committed_model_block(name):
 
 @pytest.mark.parametrize("block, why", [
     ({"model_type": "qwen3_next"}, "not glm4_moe_lite"),
+    ({"model_type": "lfm2_moe"}, "not glm4_moe_lite"),
     ({"model_type": "glm4_moe_lite", "n_group": 8}, "only n_group = 1"),
     ({"model_type": "glm4_moe_lite", "rope_scaling": {"type": "yarn"}}, "only rope_scaling"),
     ({"model_type": "glm4_moe_lite", "num_key_value_heads": 4}, "every query head"),
@@ -215,10 +216,11 @@ def test_forward_and_backward_operations_carry_their_blocks_name(tiny):
     text = jax.jit(jax.grad(lambda p: loss_fn(p, {"buffers": buffers}, batches[0], None)[0])).lower(
         params).as_text(debug_info=True)
     names = re.findall(r'loc\("([^"]*)"', text)
-    from commefficient_tpu.models import glm4_moe_lite, qwen3_next
+    from commefficient_tpu.models import glm4_moe_lite, lfm2_moe, qwen3_next
 
     # the profiler's second reduction knows every model's blocks and no other
-    assert set(profiler.BLOCK_SCOPES) == set(glm4_moe_lite.SCOPES) | set(qwen3_next.SCOPES)
+    assert set(profiler.BLOCK_SCOPES) == (
+        set(glm4_moe_lite.SCOPES) | set(qwen3_next.SCOPES) | set(lfm2_moe.SCOPES))
     assert len(set(profiler.BLOCK_SCOPES)) == len(profiler.BLOCK_SCOPES)
     for block in glm4_moe_lite.SCOPES:
         assert any(profiler.phase_of(n, profiler.BLOCK_SCOPES) == block for n in names), block
@@ -261,6 +263,6 @@ def test_gpt2_train_builds_the_model_and_no_round_changes_the_bias(tmp_path, cap
     counted = reg.counter("model_moe_assignments_total").value - before
     assert counted >= 3 * 3 * 24 * cfg.num_experts_per_tok
     assert 0 <= reg.gauge("model_moe_bias_flips_share").value <= 1
-    with pytest.raises(SystemExit, match="glm4_moe_lite, qwen3_next"):
+    with pytest.raises(SystemExit, match="glm4_moe_lite, lfm2_moe, qwen3_next"):
         path.write_text(json.dumps({"model": dict(block, model_type="llama")}))
         gpt2_train.main(argv)

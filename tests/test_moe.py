@@ -407,3 +407,69 @@ def test_qwen3_nexts_expert_layer_lowers_to_the_same_text_as_before():
     assert lowered(moe.topk_moe_ffn) == lowered(_parent_topk_moe_ffn)
     assert lowered(functools.partial(moe.topk_moe_ffn, route=moe.topk_route)) == lowered(
         _parent_topk_moe_ffn)
+
+
+def test_sigmoid_rule_with_lfm2s_eps_and_scale_against_the_written_out_rule():
+    """LFM2's rule: the chosen scores over (their sum + 1e-6), times 1. Beside
+    scores near 1/2 the 1e-6 is a few ulps; where every score is tiny it is
+    most of the divisor, and GLM's 1e-20 none of it."""
+    router, _ = _topk_params(jax.random.PRNGKey(23), 1)
+    x = jax.random.normal(jax.random.PRNGKey(24), (TK_T, TK_D))
+    bias = 0.5 * jax.random.normal(jax.random.PRNGKey(25), (TK_E,))
+    for shift in (0.0, -14.0):  # scores near 1/2, then near 1e-6
+        x_s = x.at[:, 0].set(1.0)
+        r_s = router.at[0].set(shift)
+        s = jax.nn.sigmoid(jnp.dot(x_s, r_s, precision="highest"))
+        chosen, weights = moe.sigmoid_topk_route(x_s, r_s, TK_K, bias=bias, scale=1.0, eps=1e-6)
+        np.testing.assert_array_equal(np.asarray(chosen),
+                                      np.asarray(jax.lax.top_k(s + bias, TK_K)[1]))
+        top = jnp.take_along_axis(s, chosen, axis=-1)
+        np.testing.assert_allclose(np.asarray(weights),
+                                   np.asarray(top / (top.sum(-1, keepdims=True) + 1e-6)), rtol=1e-6)
+        _, glm = moe.sigmoid_topk_route(x_s, r_s, TK_K, bias=bias)
+        np.testing.assert_allclose(np.asarray(glm.sum(-1)), 1.0, rtol=1e-6)
+        if shift:
+            assert float(top.sum(-1).min()) < 1e-5
+            assert float(weights.sum(-1).min()) < 0.9  # the 1e-6 shows
+        else:
+            np.testing.assert_allclose(np.asarray(weights), np.asarray(glm), rtol=2e-6)
+
+
+def _parent_sigmoid_topk_route(x, router_w, k: int, bias=None, scale: float = 1.0, eps=1e-20):
+    """ops/moe.sigmoid_topk_route as it was before `eps` was an argument: the
+    one value an older family may hand it is the constant it had."""
+    assert eps == 1e-20
+    scores = jax.nn.sigmoid(moe._router_logits(x, router_w))
+    _, experts = jax.lax.top_k(scores if bias is None else scores + bias, k)
+    top = jnp.take_along_axis(scores, experts, axis=-1)
+    return experts.astype(jnp.int32), top / (top.sum(-1, keepdims=True) + 1e-20) * scale
+
+
+@pytest.mark.parametrize("family", ["glm4_moe_lite", "qwen3_next"])
+def test_an_older_familys_expert_layer_lowers_to_the_text_it_lowered_to_before_eps(
+        family, monkeypatch):
+    """GLM-4.7-Flash passes no `eps` and Qwen3-Next no sigmoid rule at all:
+    each model's own expert layer and its gradient lower to the same text with
+    the rule as it was written before the argument put in its place."""
+    import importlib
+
+    module = importlib.import_module(f"commefficient_tpu.models.{family}")
+    layer = module.SparseMoE(module.TINY)
+    x = jax.random.normal(jax.random.PRNGKey(26), (2, TK_T, module.TINY.hidden_size))
+    variables = layer.init(jax.random.PRNGKey(27), x)
+    rest = {k: v for k, v in variables.items() if k != "params"}
+
+    def lowered():
+        fn = jax.value_and_grad(lambda p, x: (layer.apply({"params": p, **rest}, x) ** 2).sum(),
+                                argnums=(0, 1))
+        return jax.jit(fn).lower(variables["params"], x).as_text()
+
+    now, calls = lowered(), []
+
+    def parent(*args, **kw):
+        calls.append(kw)
+        return _parent_sigmoid_topk_route(*args, **kw)
+
+    monkeypatch.setattr(moe, "sigmoid_topk_route", parent)
+    assert lowered() == now
+    assert bool(calls) == (family == "glm4_moe_lite")  # the rule was in the layer's path
