@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from ..data.fed_dataset import FedDataset, prefetch_iter
 from ..modes import modes
 from ..modes.config import ModeConfig
+from ..obs import registry as obreg
 from ..obs import trace as obtrace
 from ..parallel import mesh as meshlib
 from ..resilience import retry as rtry
@@ -478,6 +479,14 @@ class FederatedSession:
         else:
             self._step = jax.jit(engine.make_round_step(train_loss_fn, self.cfg),
                                  donate_argnums=self._state_donation())
+        # which client phase the round program above was built with: one
+        # backward pass for the cohort, or one a client (the CLIs' start-up
+        # line and the gauge say so; decided at trace time, so a gauge says
+        # all a hit share could)
+        fused = (not self._table_round
+                 and engine.cohort_backward_fused(self.cfg))
+        self.cohort_backward = "fused" if fused else "per-client"
+        obreg.default().gauge("engine_cohort_backward_fused").set(int(fused))
         self._eval = jax.jit(engine.make_eval_step(eval_loss_fn))
         if self.client_state is not None:
             gather = lambda st, ids: jax.tree.map(lambda a: a[ids], st)  # noqa: E731

@@ -76,12 +76,16 @@ class EngineConfig:
     # a zero aggregate (momentum still decays, the round still counts).
     client_dropout: float = 0.0
     # HBM ceiling for large models (SURVEY.md §7 hard part (e)): > 0 runs
-    # the per-client grads as a lax.scan over chunks of this many clients,
-    # accumulating the weighted reduce additively — W full [d] gradients
-    # never coexist in memory, so GPT-2-scale rounds can sample far larger
-    # cohorts per chip. Linearity makes the chunk accumulation exact;
-    # applies to linear grad modes without client-local state (elsewhere
-    # the per-client wires are needed all at once and the knob is ignored).
+    # the client phase as a lax.scan over chunks of this many clients,
+    # accumulating the weighted reduce additively, so GPT-2-scale rounds can
+    # sample far larger cohorts per chip. With no per-client transform armed
+    # (client_update_clip, dp_clip both 0) no per-client gradient exists at
+    # all — one backward pass a chunk writes the reduced tree — and the knob
+    # is sized by the chunk's ACTIVATIONS alone; with one armed it also
+    # bounds the per-client gradient trees alive at once (W full gradients
+    # never coexist). Linearity makes the chunk accumulation exact; applies
+    # to linear grad modes without client-local state (elsewhere the
+    # per-client wires are needed all at once and the knob is ignored).
     client_chunk: int = 0
     # Non-finite-update guard (resilience/): "skip" detects NaN/Inf in the
     # aggregated wire (or the new mutable collections) INSIDE the compiled
@@ -105,8 +109,9 @@ class EngineConfig:
     # tests), while different shard counts differ at fp-reassociation level.
     client_shards: int = 1
     # How the round's sketch table is built (mode=sketch only). Both paths
-    # share ONE cohort reduce (_weighted_client_reduce): per-client
-    # gradients stay a pytree and are summed over clients leaf by leaf, so
+    # share ONE cohort reduce (_weighted_client_reduce): the reduced
+    # gradient is a pytree (one backward pass for the cohort; under a
+    # quarantine or dp_clip per-client trees summed leaf by leaf), so
     # neither writes a [W, d] / [chunk, d] stack of flat gradients, and
     # quarantine/dp_clip client norms are folded from per-leaf partial sums
     # on both. What differs is only where the sketch is folded and how the
@@ -1078,8 +1083,25 @@ def _ravel_reduced(wsum_tree):
     return ravel_pytree(wsum_tree)[0]
 
 
+def _needs_client_gradients(cfg: EngineConfig) -> bool:
+    """Whether a transform is armed that must see one client's gradient on
+    its own: the quarantine's norm screen or the DP clip."""
+    return cfg.client_update_clip > 0 or cfg.dp_clip > 0
+
+
+def cohort_backward_fused(cfg: EngineConfig) -> bool:
+    """Whether the round's client phase is ONE backward pass for the whole
+    cohort (`_weighted_client_reduce`'s fused path) and not one a client: a
+    linear grad mode on the compress-once shortcut with no transform armed
+    that needs a single client's gradient. The non-linear modes, the
+    weight-delta modes and the per-client-table round never had the
+    shortcut."""
+    return (supports_sharded_round(cfg.mode) and not uses_table_round(cfg)
+            and not _needs_client_gradients(cfg))
+
+
 def _weighted_client_reduce(
-    cfg: EngineConfig, grad_client_tree: Callable,
+    cfg: EngineConfig, loss_fn: Callable,
     params, net_state, batch, client_rngs, part,
     *, qmed=None, nan_safe: bool = False, lmed=None, ravel: bool = True,
 ):
@@ -1094,28 +1116,50 @@ def _weighted_client_reduce(
     medians; a client over ANY leaf's screen is quarantined exactly like a
     scalar-screen rejection).
 
-    Per-client updates stay a PYTREE of [W, ...leaf] gradients
-    (`_make_grad_client_tree`) through the screen, the clip and the mask,
-    and the weighted sum is taken per leaf: sum_i w_i ravel(g_i) ==
-    ravel(sum_i w_i g_i), so only the reduced tree is raveled (`ravel`, the
-    flat [d] `wsum` every caller but sketch_path="layerwise" takes) and no
-    [W, d] or [chunk, d] stack of flat gradients is ever written.
+    Two paths, chosen by what the configuration asks for:
+
+    - FUSED (no quarantine, no dp_clip): sum_i w_i grad L_i == grad sum_i
+      w_i L_i, so the forward pass is one vmap over the clients (per-client
+      batch statistics, dropout keys and metrics as ever) and ONE reverse
+      pass with the cotangent w on the [W] losses gives the reduced tree
+      directly. The params are unbatched under the vmap, so each weight
+      gradient contracts the client axis together with the example axis:
+      no per-client gradient exists at all, in HBM or anywhere, and weight
+      decay is added once to the reduced tree (wd * sum_i w_i * theta).
+    - PER-CLIENT (client_update_clip > 0 or dp_clip > 0: a screen or a clip
+      that must see g_i alone): updates stay a PYTREE of [W, ...leaf]
+      gradients (`_make_grad_client_tree`) through the screen, the clip and
+      the mask, and the weighted sum is taken per leaf.
+
+    Either way sum_i w_i ravel(g_i) == ravel(sum_i w_i g_i), so only the
+    reduced tree is raveled (`ravel`, the flat [d] `wsum` every caller but
+    sketch_path="layerwise" takes) and no [W, d] or [chunk, d] stack of flat
+    gradients is ever written.
 
     One vmap when cfg.client_chunk is 0; otherwise a lax.scan over chunks of
-    client_chunk clients (each chunk vmapped) that carries the tree of sums,
-    so at most client_chunk clients' per-leaf gradients coexist in HBM
-    (SURVEY.md §7 hard part (e)). Linearity of the weighted sum makes
-    chunking exact up to fp summation order — which is also what lets the
-    quarantine run per chunk against the replicated running-median threshold
-    (`qmed`, from server state): the verdict never needs the other chunks'
-    norms.
+    client_chunk clients (each chunk vmapped) that carries the tree of sums:
+    on the fused path that bounds the ACTIVATIONS alive at once (the carry
+    is one reduced tree whatever the chunk), on the per-client path also the
+    client_chunk per-leaf gradients (SURVEY.md §7 hard part (e)). Linearity
+    of the weighted sum makes chunking exact up to fp summation order —
+    which is also what lets the quarantine run per chunk against the
+    replicated running-median threshold (`qmed`, from server state): the
+    verdict never needs the other chunks' norms.
 
     nan_safe switches the 0/1 weighting from multiply to modes.mask_rows so
     a masked client carrying NaN/Inf (poisoned update, zeroed dead-client
     batch) still contributes an exact zero; it is forced on whenever the
     quarantine is armed, and value-identical to the multiply form on finite
-    data."""
+    data. A zero cotangent times a NaN is a NaN, so the fused path under
+    nan_safe also zeroes the floating leaves of a masked client's batch
+    BEFORE the forward pass: whatever its rows held, the round is the round
+    a zeroed batch yields, bit for bit."""
     nan_safe = nan_safe or cfg.client_update_clip > 0
+    fused = not _needs_client_gradients(cfg)
+    if nan_safe:
+        rows = modes.mask_rows
+    else:
+        rows = lambda w, a: a * modes.bcast(w, a)  # noqa: E731
 
     @jax.named_scope("cohort_reduce")
     def reduce(updates, nstates, metrics, cpart):
@@ -1128,21 +1172,39 @@ def _weighted_client_reduce(
                 bad = bad | _quarantine_layer_mask(cfg, lnorms_c, lmed)
             cpart = cpart * (1.0 - bad.astype(cpart.dtype))
         updates = _clip_updates_tree(cfg, updates)
-        if nan_safe:
-            rows = lambda a: modes.mask_rows(cpart, a)  # noqa: E731
-        else:
-            rows = lambda a: a * modes.bcast(cpart, a)  # noqa: E731
         wsum, ns_sum, m_sum = jax.tree.map(
-            lambda a: rows(a).sum(axis=0), (updates, nstates, metrics))
+            lambda a: rows(cpart, a).sum(axis=0), (updates, nstates, metrics))
         return wsum, ns_sum, m_sum, cpart, norms_c, lnorms_c
 
-    def chunk(cb, crngs, cpart):
+    def per_client_chunk(cb, crngs, cpart):
+        grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
         with jax.named_scope("client_grad"):
             updates, nstates, metrics = jax.vmap(
                 lambda b, r: grad_client_tree(params, net_state, b, r)
             )(cb, crngs)
         return reduce(updates, nstates, metrics, cpart)
 
+    def fused_chunk(cb, crngs, cpart):
+        if nan_safe:
+            cb = jax.tree.map(
+                lambda a: (jnp.where(modes.bcast(cpart, a) > 0, a, 0)
+                           if jnp.issubdtype(a.dtype, jnp.floating) else a),
+                cb)
+
+        def cohort_loss(p):
+            losses, aux = jax.vmap(
+                lambda b, r: loss_fn(p, net_state, b, r))(cb, crngs)
+            return rows(cpart, losses).sum(), aux
+
+        with jax.named_scope("client_grad"):
+            wsum, aux = jax.grad(cohort_loss, has_aux=True)(params)
+        with jax.named_scope("cohort_reduce"):
+            ns_sum, m_sum = jax.tree.map(
+                lambda a: rows(cpart, a).sum(axis=0),
+                (aux["net_state"], aux["metrics"]))
+        return wsum, ns_sum, m_sum, cpart, None, None
+
+    chunk = fused_chunk if fused else per_client_chunk
     W = part.shape[0]
     C = cfg.client_chunk
     if not C or C >= W:
@@ -1169,9 +1231,16 @@ def _weighted_client_reduce(
         out = acc + (pe.reshape(W),
                      None if norms is None else norms.reshape(W),
                      None if lnorms is None else lnorms.reshape(W, -1))
+    wsum, rest = out[0], out[1:]
+    if fused and cfg.weight_decay:
+        # sum_i w_i (g_i + wd * theta) == g + wd * (sum_i w_i) * theta: once,
+        # on the reduced tree, where the per-client path adds it W times
+        with jax.named_scope("cohort_reduce"):
+            decay = cfg.weight_decay * rest[2].sum()
+            wsum = jax.tree.map(lambda g, p: g + decay * p, wsum, params)
     if ravel:
-        out = (_ravel_reduced(out[0]),) + out[1:]
-    return out
+        wsum = _ravel_reduced(wsum)
+    return (wsum,) + rest
 
 
 @jax.named_scope("cohort_reduce")
@@ -1234,12 +1303,13 @@ def _make_grad_client(loss_fn: Callable, cfg: EngineConfig) -> Callable:
 
 
 def _make_grad_client_tree(loss_fn: Callable, cfg: EngineConfig) -> Callable:
-    """One client's contribution for the linear grad modes
-    (`_weighted_client_reduce`): the gradient stays a pytree of per-layer
-    leaves — nothing is raveled per client. Weight decay applies per leaf,
-    client-side and unconditionally like `_make_grad_client`'s
-    `gflat + wd * pflat` (same per-coordinate arithmetic, so wd == 0 keeps
-    the identical ±0.0 additions)."""
+    """One client's contribution for the linear grad modes on
+    `_weighted_client_reduce`'s PER-CLIENT path (quarantine or dp_clip armed;
+    otherwise no per-client gradient is computed at all): the gradient
+    stays a pytree of per-layer leaves — nothing is raveled per client.
+    Weight decay applies per leaf, client-side and unconditionally like
+    `_make_grad_client`'s `gflat + wd * pflat` (same per-coordinate
+    arithmetic, so wd == 0 keeps the identical ±0.0 additions)."""
 
     def grad_client(params, net_state, cbatch, rng):
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
@@ -1313,7 +1383,6 @@ def make_round_step(
     mcfg = cfg.mode
     _robust_scope_check(cfg)
     grad_client = _make_grad_client(loss_fn, cfg)
-    grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
     layerwise = cfg.sketch_path == "layerwise"
     layer_q = (cfg.client_update_clip > 0
                and cfg.quarantine_scope == "layer")
@@ -1381,10 +1450,12 @@ def make_round_step(
             # client — exactly equal, much cheaper. Participation weighting
             # folds into the same reduction (survivor mean = sum(part·u) /
             # count(part); sum drops the /), and the reduce itself may run
-            # chunked (cfg.client_chunk) so W full gradients never coexist.
+            # chunked (cfg.client_chunk). With no per-client transform armed
+            # the same linearity is taken one step earlier: one backward
+            # pass of the masked sum of losses, no per-client gradient.
             wsum, ns_sum, m_sum, part_eff, norms, lnorms = (
                 _weighted_client_reduce(
-                    cfg, grad_client_tree, params, net_state, batch,
+                    cfg, loss_fn, params, net_state, batch,
                     client_rngs, part, qmed=qmed, nan_safe=valid is not None,
                     lmed=lmed, ravel=not layerwise,
                 ))
@@ -1730,7 +1801,6 @@ def make_sharded_round_step(
             "sharded round needs client_shards > 1 (or a mesh with > 1 "
             "client shard); use make_round_step for the unsharded round"
         )
-    grad_client_tree = _make_grad_client_tree(loss_fn, cfg)
     layerwise = cfg.sketch_path == "layerwise"
     quarantine = cfg.client_update_clip > 0
     layer_q = quarantine and cfg.quarantine_scope == "layer"
@@ -1749,7 +1819,7 @@ def make_sharded_round_step(
             part_l = part_l * valid_l
         wsum, ns_sum, m_sum, part_eff_l, norms_l, lnorms_l = (
             _weighted_client_reduce(
-                cfg, grad_client_tree, params, net_state, batch_l, rngs_l,
+                cfg, loss_fn, params, net_state, batch_l, rngs_l,
                 part_l, qmed=qmed, nan_safe=valid_l is not None, lmed=lmed,
                 ravel=not layerwise,
             ))

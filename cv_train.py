@@ -144,6 +144,7 @@ def build(args, fault_plan=None, retry_policy=None):
         donate_state=not (args.checkpoint_dir
                           and not args.no_emergency_checkpoint),
     )
+    print(f"cohort backward: {session.cohort_backward}", flush=True)
     return session, test_set
 
 

@@ -255,6 +255,7 @@ def build(args, fault_plan=None, retry_policy=None):
             "silently degrade ring attention to dense; check --seq_parallel "
             "and the device count"
         )
+    print(f"cohort backward: {session.cohort_backward}", flush=True)
     return session, valid_set, {"model": model, "tok": tok}
 
 

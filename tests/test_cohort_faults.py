@@ -206,32 +206,44 @@ def test_masked_round_mesh_bit_identical_to_single_device():
         rtol=2e-7)
 
 
-def test_masked_client_garbage_is_inert():
+@pytest.mark.parametrize("clip", [4.0, 0.0], ids=["per_client", "fused"])
+def test_masked_client_garbage_is_inert(clip):
     """A dead client's batch content must not matter — NaN rows behind a zero
-    validity mask produce the identical round a zeroed batch does (the
-    degrade path's contract: failed loads hand the engine zeros, but nothing
-    may depend on that)."""
+    validity mask produce the identical round a zeroed batch does, and the
+    round its own rows behind that mask do (the degrade path's contract:
+    failed loads hand the engine zeros, but nothing may depend on that).
+    On both client phases: with the quarantine armed (per-client gradients,
+    NaN-safe by the mask-and-sum) and with it off (one backward pass for the
+    cohort, where a zero cotangent would not stop a NaN: the masked rows
+    are zeroed before the forward pass)."""
     W = 8
-    params, cfg = _cfg(client_update_clip=4.0)  # quarantine armed = NaN-safe
+    params, cfg = _cfg(client_update_clip=clip)
+    assert engine.cohort_backward_fused(cfg) == (clip == 0.0)
     batch = _batch(jax.random.PRNGKey(4), W)
     valid = np.ones(W, np.float32)
     valid[3] = 0.0
-    poisoned = {k: np.array(v, copy=True) for k, v in
-                jax.tree.map(np.asarray, batch).items()}
-    poisoned["x"][3] = np.nan
     lr, rng = jnp.float32(0.1), jax.random.PRNGKey(11)
-
     step = jax.jit(engine.make_round_step(quad_loss, cfg))
-    s_a = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    s_b = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    s_a, _, m_a = step(s_a, _with_valid(batch, valid), {}, lr, rng)
-    s_b, _, m_b = step(
-        s_b, _with_valid({k: jnp.asarray(v) for k, v in poisoned.items()},
-                         valid), {}, lr, rng)
-    np.testing.assert_array_equal(_flat(s_a), _flat(s_b))
-    for k in m_a:
-        np.testing.assert_array_equal(np.asarray(m_a[k]), np.asarray(m_b[k]),
-                                      err_msg=k)
+
+    def round_with(fill):
+        rows = {k: np.array(v, copy=True) for k, v in
+                jax.tree.map(np.asarray, batch).items()}
+        if fill is not None:
+            rows["x"][3] = fill
+        state = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
+        state, _, m = step(
+            state, _with_valid({k: jnp.asarray(v) for k, v in rows.items()},
+                               valid), {}, lr, rng)
+        return state, m
+
+    s_a, m_a = round_with(None)
+    assert np.isfinite(_flat(s_a)).all()
+    for fill in (np.nan, 0.0):
+        s_b, m_b = round_with(fill)
+        np.testing.assert_array_equal(_flat(s_a), _flat(s_b))
+        for k in m_a:
+            np.testing.assert_array_equal(np.asarray(m_a[k]), np.asarray(m_b[k]),
+                                          err_msg=k)
 
 
 # ----------------------------------------------------------------- quarantine

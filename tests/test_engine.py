@@ -765,18 +765,44 @@ def test_linear_round_program_holds_no_client_stack(mode_kw, chunk):
             assert f"f32[{rows},{d}]" not in text
 
 
-def _one_at_a_time_reduce(cfg, params, batch, rngs, part, qmed, lmed):
+def bn_mlp_loss(params, net_state, batch, rng):
+    """`mlp_loss` with a batch norm on the hidden layer: the statistics are
+    the CLIENT's own batch's, and the new running statistics come back as a
+    mutable collection."""
+    pre = batch["x"] @ params["w1"] + params["b1"]
+    mean, var = pre.mean(0), pre.var(0)
+    h = jnp.tanh((pre - mean) * jax.lax.rsqrt(var + 1e-5))
+    logits = h @ params["w2"] + params["b2"]
+    per_ex = -jnp.take_along_axis(
+        jax.nn.log_softmax(logits), batch["y"][:, None], axis=1)[:, 0]
+    mask = batch["mask"]
+    stats = net_state["batch_stats"]
+    new_stats = {"mean": 0.9 * stats["mean"] + 0.1 * mean,
+                 "var": 0.9 * stats["var"] + 0.1 * var}
+    return (per_ex * mask).sum() / jnp.maximum(mask.sum(), 1.0), {
+        "net_state": {"batch_stats": new_stats},
+        "metrics": {"loss_sum": (per_ex * mask).sum(), "count": mask.sum(),
+                    "correct": ((logits.argmax(-1) == batch["y"]) * mask).sum()},
+    }
+
+
+def _one_at_a_time_reduce(cfg, params, batch, rngs, part, qmed, lmed,
+                          loss=mlp_loss, net_state=None):
     """What `_weighted_client_reduce` must equal: every client's FLAT
     gradient computed alone (no vmap, no tree), then the screen, the clip
-    and the masked sum in numpy, in the order the engine documents."""
+    and the masked sum in numpy, in the order the engine documents. The
+    fifth value is the surviving clients' sum of the mutable collections."""
+    net_state = {} if net_state is None else net_state
     pflat, _ = ravel_pytree(params)
     segs = engine._leaf_segments(params)
     W = part.shape[0]
-    flats = []
+    flats, nstates = [], []
     for i in range(W):
         cb = jax.tree.map(lambda a: a[i], batch)
-        g = jax.grad(lambda p: mlp_loss(p, {}, cb, rngs[i])[0])(params)
+        g, aux = jax.grad(lambda p: loss(p, net_state, cb, rngs[i]),
+                          has_aux=True)(params)
         flats.append(np.asarray(ravel_pytree(g)[0] + cfg.weight_decay * pflat))
+        nstates.append(np.asarray(ravel_pytree(aux["net_state"])[0]))
     flats = np.stack(flats)
     norms = np.sqrt((flats ** 2).sum(1))
     lnorms = np.stack([np.sqrt((flats[:, o:o + n] ** 2).sum(1)) for o, n in segs], 1)
@@ -791,18 +817,27 @@ def _one_at_a_time_reduce(cfg, params, batch, rngs, part, qmed, lmed):
         part_eff = part_eff * (1.0 - bad)
     if cfg.dp_clip > 0:
         flats = flats * np.minimum(1.0, cfg.dp_clip / np.maximum(norms, 1e-12))[:, None]
-    wsum = sum((flats[i] for i in range(W) if part_eff[i] > 0),
-               np.zeros_like(flats[0]))
-    return wsum, part_eff, norms, lnorms
+    live = [i for i in range(W) if part_eff[i] > 0]
+    wsum = sum((flats[i] for i in live), np.zeros_like(flats[0]))
+    ns_sum = sum((nstates[i] for i in live), np.zeros_like(nstates[0]))
+    return wsum, part_eff, norms, lnorms, ns_sum
+
+
+# the cases of the test below that arm a per-client transform and so take
+# `_weighted_client_reduce`'s per-client path; the others take the fused one
+PER_CLIENT_CASES = ("quarantine_cohort", "quarantine_layer", "dp_clip")
 
 
 @pytest.mark.parametrize("case", [
     "plain", "valid_mask_nan", "quarantine_cohort", "quarantine_layer",
-    "dp_clip", "client_chunk"])
+    "dp_clip", "client_chunk", "batch_norm", "random_mask", "client_chunk_nan"])
 def test_leafwise_reduce_equals_one_client_at_a_time(case):
-    """sum_i w_i ravel(g_i) == ravel(sum_i w_i g_i): the per-leaf reduce
-    against per-client flat gradients computed one client at a time, under
-    every transform that sits between the gradient and the sum."""
+    """sum_i w_i ravel(g_i) == ravel(sum_i w_i g_i) == ravel(grad sum_i w_i
+    L_i): the cohort reduce against per-client flat gradients computed one
+    client at a time, on both of its paths (PER_CLIENT_CASES take the
+    per-leaf reduce of per-client gradients, the others the one backward
+    pass of the masked sum of losses), under every transform that sits
+    between the gradient and the sum."""
     W = 8
     params = init_mlp(jax.random.PRNGKey(0))
     d = ravel_pytree(params)[0].size
@@ -813,6 +848,7 @@ def test_leafwise_reduce_equals_one_client_at_a_time(case):
     rngs = jax.random.split(jax.random.PRNGKey(5), W)
     part = np.ones(W, np.float32)
     eng_kw, nan_safe, qmed, lmed = {}, False, None, None
+    loss, net_state = mlp_loss, {}
     if case == "valid_mask_nan":
         part[2] = 0.0
         batch["x"] = batch["x"].at[2].set(jnp.nan)
@@ -825,12 +861,31 @@ def test_leafwise_reduce_equals_one_client_at_a_time(case):
     elif case == "client_chunk":
         eng_kw = dict(client_chunk=2)
         part[6] = 0.0
+    elif case == "batch_norm":
+        # per-client statistics under the one vmap, a mutable collection
+        # back, and two clients masked
+        loss = bn_mlp_loss
+        net_state = {"batch_stats": {"mean": jnp.full(16, 0.25), "var": jnp.ones(16)}}
+        part[[1, 4]] = 0.0
+        nan_safe = True
+    elif case == "random_mask":
+        # the dropout simulation's mask with no validity mask riding the
+        # batch: the multiply form of the weighting
+        part = np.asarray(engine.participation_mask(
+            jax.random.PRNGKey(11), W, 0.4))
+        assert 0 < part.sum() < W
+    elif case == "client_chunk_nan":
+        eng_kw = dict(client_chunk=4)
+        part[5] = 0.0
+        batch["x"] = batch["x"].at[5].set(jnp.nan)
+        nan_safe = True
     cfg = engine.EngineConfig(mode=ModeConfig(**_ucfg(d=d)), weight_decay=5e-4, **eng_kw)
+    assert (case in PER_CLIENT_CASES) != engine.cohort_backward_fused(cfg)
 
     if cfg.client_update_clip > 0:
         # thresholds between the two largest finite norms: the screen
         # rejects exactly one healthy client beside the poisoned one
-        _, _, norms0, lnorms0 = _one_at_a_time_reduce(
+        _, _, norms0, lnorms0, _ = _one_at_a_time_reduce(
             cfg, params, batch, rngs, part, 0.0, None)
         between = lambda v: float(np.sort(v[np.isfinite(v)])[-2:].mean()) / 2.0  # noqa: E731
         if case == "quarantine_cohort":
@@ -839,12 +894,12 @@ def test_leafwise_reduce_equals_one_client_at_a_time(case):
             qmed = 0.0
             lmed = np.zeros(lnorms0.shape[1], np.float32)
             lmed[2] = between(lnorms0[:, 2])
-    want, want_part, want_norms, want_lnorms = _one_at_a_time_reduce(
-        cfg, params, batch, rngs, part, qmed, lmed)
+    want, want_part, want_norms, want_lnorms, want_ns = _one_at_a_time_reduce(
+        cfg, params, batch, rngs, part, qmed, lmed, loss, net_state)
 
-    got, _, m_sum, part_eff, norms, lnorms = jax.jit(
+    got, ns_sum, m_sum, part_eff, norms, lnorms = jax.jit(
         lambda b, r, p: engine._weighted_client_reduce(
-            cfg, engine._make_grad_client_tree(mlp_loss, cfg), params, {}, b, r, p,
+            cfg, loss, params, net_state, b, r, p,
             qmed=None if qmed is None else jnp.float32(qmed), nan_safe=nan_safe,
             lmed=None if lmed is None else jnp.asarray(lmed)))(
         batch, rngs, jnp.asarray(part))
@@ -853,7 +908,10 @@ def test_leafwise_reduce_equals_one_client_at_a_time(case):
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6,
                                atol=1e-6 * np.abs(want).max())
     assert float(m_sum["count"]) == 4.0 * want_part.sum()
-    if case == "valid_mask_nan":
+    if net_state:
+        np.testing.assert_allclose(np.asarray(ravel_pytree(ns_sum)[0]), want_ns,
+                                   rtol=1e-6, atol=1e-6)
+    if case in ("valid_mask_nan", "client_chunk_nan"):
         assert want_part.sum() == W - 1
     if cfg.client_update_clip > 0:
         assert want_part.sum() == W - 2 and want_part[5] == 0
@@ -864,3 +922,31 @@ def test_leafwise_reduce_equals_one_client_at_a_time(case):
             np.testing.assert_allclose(np.asarray(lnorms)[live], want_lnorms[live], rtol=1e-5)
     else:
         assert norms is None and lnorms is None
+
+
+@pytest.mark.parametrize("chunk", [0, 2])
+@pytest.mark.parametrize("eng_kw,per_client", [
+    ({}, False),
+    (dict(client_update_clip=2.0), True),
+    (dict(client_update_clip=2.0, quarantine_scope="layer"), True),
+    (dict(dp_clip=0.05), True),
+], ids=["no_transform", "quarantine", "quarantine_layer", "dp_clip"])
+def test_cohort_backward_path_by_lowered_text(eng_kw, per_client, chunk):
+    """Which path engaged, read from the round program's lowered text
+    (before the compiler reorders dimensions): with no per-client transform
+    armed no array of shape (clients,) + shape of the largest leaf exists,
+    because the one backward pass contracts the client axis into the weight
+    gradient; a quarantine or a DP clip, which must see one client's
+    gradient alone, brings the [W, ...leaf] stack back."""
+    W = 4
+    data = _data(jax.random.PRNGKey(1), W * 4)
+    batch = jax.tree.map(lambda a: a.reshape((W, 4) + a.shape[1:]), data)
+    batch[engine.VALID_KEY] = jnp.ones(W)
+    cfg, state, step = _make(_ucfg(momentum_type="virtual", momentum=0.9),
+                             wd=5e-4, client_chunk=chunk, **eng_kw)
+    assert engine.cohort_backward_fused(cfg) != per_client
+    text = step.lower(state, batch, {}, jnp.float32(0.1),
+                      jax.random.PRNGKey(0)).as_text()
+    leaf = max(jax.tree.leaves(state["params"]), key=lambda a: a.size)   # w1, 10 x 16
+    stack = f"tensor<{chunk or W}x{'x'.join(map(str, leaf.shape))}xf32>"
+    assert (stack in text) == per_client

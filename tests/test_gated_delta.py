@@ -77,6 +77,36 @@ def test_vmap_over_clients_inside_a_scan_equals_one_client_at_a_time():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-6)
 
 
+def test_grad_of_a_vmap_over_clients_inside_a_scan_equals_one_client_at_a_time():
+    """engine._weighted_client_reduce's fused path with --client_chunk: one
+    gradient of the masked sum of the clients' losses with respect to a
+    SHARED weight (a per-channel scale of the keys here), the vmap over
+    clients inside the grad, inside lax.scan; a masked client's inputs (NaN
+    here) are zeroed before the forward pass and add nothing."""
+    W, C = 4, 2
+    per_client = [_inputs(10 + i, 1, 29, 2, 8, 8) for i in range(W)]
+    stacked = tuple(jnp.stack(a) for a in zip(*per_client))
+    live = jnp.asarray([1., 0., 1., 1.])
+    scale = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(3), per_client[0][1].shape[-1:])
+
+    def client_loss(s, q, k, v, g, beta):
+        return (gd.chunk_gated_delta_rule(q, k * s, v, g, beta, chunk=8) ** 2).sum()
+
+    def body(acc, chunk):
+        xs, w = chunk
+        xs = tuple(jnp.where(w.reshape((-1,) + (1,) * (a.ndim - 1)) > 0, a, 0) for a in xs)
+        return acc + jax.grad(lambda s: jnp.where(
+            w > 0, jax.vmap(lambda *a: client_loss(s, *a))(*xs), 0).sum())(scale), None
+
+    poisoned = tuple(a.at[1].set(jnp.nan) for a in stacked)
+    xs = tuple(a.reshape((W // C, C) + a.shape[1:]) for a in poisoned)
+    got, _ = jax.jit(lambda xs, w: jax.lax.scan(body, jnp.zeros_like(scale), (xs, w)))(
+        xs, live.reshape(W // C, C))
+    want = sum(jax.grad(client_loss)(scale, *a) for a, w in zip(per_client, live) if w > 0)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-6)
+
+
 def test_a_caller_that_keeps_the_named_inverses_solves_once():
     """models/qwen3_next.py recomputes the rule in the backward pass under a
     policy that keeps INVERSE: the compiled gradient then holds one triangular

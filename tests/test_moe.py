@@ -259,6 +259,43 @@ def test_topk_moe_under_vmap_over_clients_inside_a_scan():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
 
 
+def test_topk_moe_under_grad_of_a_vmap_over_clients_inside_a_scan():
+    """engine._weighted_client_reduce's fused path with --client_chunk: ONE
+    gradient of the masked sum of the clients' losses, the vmap over clients
+    inside it (weights unbatched, so their cotangents come back summed over
+    the chunk), inside lax.scan. The held experts' custom_vmap / custom_vjp
+    leaves now sit under grad-of-vmap and not vmap-of-grad; a masked client's
+    rows (NaN here) are zeroed before the forward pass and add nothing."""
+    held, W, C = (5, 4), 6, 2
+    router, experts = _topk_params(jax.random.PRNGKey(8), held[1])
+    xs = jax.random.normal(jax.random.PRNGKey(9), (W, TK_T, TK_D))
+    live = jnp.asarray([1., 1., 0., 1., 0., 1.])
+    poisoned = xs.at[2].set(jnp.nan)
+
+    def client_loss(x, r, e):
+        return (moe.topk_moe_ffn(x, r, e, held, TK_K, 32)[0] ** 2).sum()
+
+    def body(acc, chunk):
+        xb, wb = chunk
+        xb = jnp.where(wb[:, None, None] > 0, xb, 0)
+        g = jax.grad(lambda r, e: jnp.where(
+            wb > 0, jax.vmap(lambda x: client_loss(x, r, e))(xb), 0).sum(),
+            argnums=(0, 1))(router, experts)
+        return jax.tree.map(jnp.add, acc, g), None
+
+    init = jax.tree.map(jnp.zeros_like, (router, experts))
+    got, _ = jax.jit(lambda xs, w: jax.lax.scan(body, init, (xs, w)))(
+        poisoned.reshape(W // C, C, TK_T, TK_D), live.reshape(W // C, C))
+    want = init
+    for x, w in zip(xs, live):
+        if w > 0:
+            want = jax.tree.map(jnp.add, want, jax.grad(
+                lambda r, e: client_loss(x, r, e), argnums=(0, 1))(router, experts))
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5)
+
+
 def test_grouped_rows_past_the_last_group_are_zero():
     x = jnp.ones((10, 4))
     w = jnp.stack([jnp.full((4, 3), 1.0), jnp.full((4, 3), 2.0)])

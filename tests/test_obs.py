@@ -750,6 +750,28 @@ def test_lowered_round_step_names_its_phases(mode, path, lacks):
     assert _lowered_scopes(mode, path) == set(ROUND_PHASES) - set(lacks)
 
 
+@pytest.mark.parametrize("extra,fused", [
+    ((), True),
+    (("--client_chunk", "1"), True),
+    (("--client_update_clip", "4"), False),
+    (("--dp_clip", "1.0"), False),
+    (("--mode", "local_topk", "--k", "8", "--error_type", "none"), False),
+], ids=["plain", "chunked", "quarantine", "dp_clip", "local_topk"])
+def test_start_up_line_and_gauge_say_which_cohort_backward(tiny_cv, capsys,
+                                                           extra, fused):
+    """The choice between one backward pass for the cohort and one a client
+    is made when the session builds its round program: the trainer's
+    start-up line and the gauge `engine_cohort_backward_fused` say which."""
+    from commefficient_tpu.utils.config import make_parser, resolve_defaults
+
+    args = resolve_defaults(make_parser("cv").parse_args(_argv(extra)))
+    session, _ = cv_train.build(args)
+    word = "fused" if fused else "per-client"
+    assert session.cohort_backward == word
+    assert f"cohort backward: {word}\n" in capsys.readouterr().out
+    assert obreg.default().gauge("engine_cohort_backward_fused").value == int(fused)
+
+
 def test_capture_summary_by_phase():
     """Hand-built device planes in the shape load_device_planes gives:
     nesting counts once (a while's body goes to its own phases, the rest of
