@@ -1016,7 +1016,7 @@ class FederatedSession:
                   if self._head_client_state is not None else self.client_state)
         ids_dev = jnp.asarray(prep.ids)
         rows = self._gather(cstate, ids_dev) if cstate is not None else {}
-        with self._mesh_ctx():
+        with self._mesh_ctx(), obtrace.span("session", "launch", round=prep.rnd):
             new_state, new_rows, metrics = self._step(
                 state, batch, rows, jnp.float32(lr), prep.sub
             )
@@ -1063,7 +1063,8 @@ class FederatedSession:
         if self.mesh is not None:
             stacked = meshlib.shard_stacked_client_batch(self.mesh, stacked)
         state = self._head_state if self._head_state is not None else self.state
-        with self._mesh_ctx():
+        with self._mesh_ctx(), obtrace.span(
+                "session", "launch", round_first=preps[0].rnd, rounds=len(lrs)):
             new_state, ms = self._multi(
                 state, stacked, jnp.asarray(lrs, jnp.float32),
                 jnp.stack([p.sub for p in preps]),

@@ -11,14 +11,18 @@ Three pieces, one contract (host-only, sync-free, bit-transparent):
   (one per pending dispatch, taken as its metrics come back) — tracing
   never adds a host sync to the round path, and a traced run is pinned
   bit-identical to an untraced one. Inside a profiler capture the spans
-  are also `jax.profiler.TraceAnnotation`s, on the profiler's clock.
+  and the instants (the drain's ready stamps among them: `runner/ready`)
+  are also `jax.profiler.TraceAnnotation`s, on the profiler's clock;
+  `complete` and `instant_signal_safe` are not mirrored.
 - ``obs.registry`` — process-wide counter/gauge/histogram/meter registry;
   the single source of truth RunStats, serve's /metrics snapshot, and
   bench's resilience/serve/obs blocks read from.
 - ``obs.profiler`` — a ``jax.profiler`` capture window around whole rounds
   (``--profile_rounds START:END``), degrading to a loud no-op where the
-  profiler is unavailable; after the capture, its summary of device time
-  by the round program's named phases (gauges and one stderr line).
+  profiler is unavailable; after the capture, its summaries: device time
+  by the round program's named phases and by the model's blocks, and every
+  execution of the round program paired with the loop's own marks of that
+  round (gauges and one stderr line each).
 
 The contract is machine-enforced: graftlint G009 bans obs API calls inside
 compiled scope (jit/shard_map bodies in the parity modules) — a span or a

@@ -3,7 +3,7 @@
 Host-side spans (`span`, a context manager), point events (`instant`), and
 DEFERRED spans (`complete`, emitted after the fact with an explicit start
 timestamp) on named tracks — runner, device, writer, serve-ingest,
-assembler, federated, resilience. The runner uses `complete` for the
+assembler, federated, resilience, session. The runner uses `complete` for the
 device phase: a dispatch records only a host timestamp, and its span is
 emitted at the runner's existing `drain()` boundary, from the later of
 that timestamp and the previous dispatch's ready stamp to its own ready
@@ -16,7 +16,12 @@ While a `ProfileWindow` capture runs (`profiling(True)`), `span` also
 enters a `jax.profiler.TraceAnnotation("<track>/<name>", **args)`, armed
 or not, so the loop's phases land on the `/host:CPU` plane of the
 profiler's own trace, on the clock of the device's operations. `instant`
-and `complete` are not mirrored.
+is mirrored the same way, as an annotation entered and left at once: the
+runner's ready stamps (`runner/ready`) are in the capture that way, and
+`obs/profiler.summarize_launches` pairs them with the round program's
+executions. Spans of one round carry its index (`round`, or `round_first`
+and `rounds`). `complete` is not mirrored (it is emitted after the fact),
+nor is `instant_signal_safe` (a signal handler enters nothing).
 
 Disabled (the default) the tracer is a near-zero-cost no-op: one attribute
 check per call site. `configure(trace_path=..., jsonl_path=...)` arms it —
@@ -46,9 +51,16 @@ from . import export
 # canonical track order (chrome-trace tid assignment; unknown tracks get
 # the next free id at first use)
 TRACKS = ("runner", "device", "writer", "serve-ingest", "gauntlet",
-          "assembler", "federated", "resilience")
+          "assembler", "federated", "resilience", "session")
 
 EVENT_SCHEMA_VERSION = 1
+
+
+def _annotation(track: str, name: str, args: dict):
+    """The mirror of a span or an instant in a running profiler capture."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(f"{track}/{name}", **args)
 
 
 class Tracer:
@@ -197,12 +209,8 @@ class Tracer:
         if not self._live:
             yield
             return
-        if self._profiling:
-            import jax
-
-            mirror = jax.profiler.TraceAnnotation(f"{track}/{name}", **args)
-        else:
-            mirror = contextlib.nullcontext()
+        mirror = (_annotation(track, name, args) if self._profiling
+                  else contextlib.nullcontext())
         with mirror:
             if not self.enabled:
                 yield
@@ -225,10 +233,15 @@ class Tracer:
 
     def instant(self, track: str, name: str, **args) -> None:
         """Point event (fault injections, retries, preemption, admission
-        decisions)."""
-        if not self.enabled:
+        decisions, the runner's ready stamps). Mirrored into a running
+        capture as `span` is: an annotation entered and left at once."""
+        if not self._live:
             return
-        self._emit("i", track, name, self.now_us(), None, args)
+        if self._profiling:
+            with _annotation(track, name, args):
+                pass
+        if self.enabled:
+            self._emit("i", track, name, self.now_us(), None, args)
 
     def instant_signal_safe(self, track: str, name: str, **args) -> None:
         """Instant that SKIPS the JSONL sink: for signal handlers, which

@@ -522,9 +522,12 @@ def run_loop(
               if watch else contextlib.nullcontext()):
             with tracer.span("runner", "drain", round_first=first,
                              rounds=committed):
-                for fl in read:
+                for fl, (_, d_first, d_n) in zip(read, dispatch_marks):
                     hosts.append(jax.device_get(fl.metrics))
                     stamps.append(time.perf_counter())
+                    # the stamp itself, on a running capture's clock
+                    tracer.instant("runner", "ready", round_first=d_first,
+                                   rounds=d_n)
         phase_hist["drain"].observe((stamps[-1] - t_drain0) * 1e3)
         # each dispatch recorded only a host timestamp; its device-phase
         # span runs from the later of that and the previous ready stamp (it

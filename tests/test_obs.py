@@ -501,7 +501,7 @@ def test_profile_rounds_spec_validation():
 def test_profile_window_start_stop_at_round_boundaries(tmp_path, monkeypatch):
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
+                        lambda d, **kw: calls.append(("start", d)))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append(("stop",)))
     pw = ProfileWindow.parse("1:2", str(tmp_path))
@@ -525,7 +525,7 @@ def test_profile_window_block_overlap_and_resume_past(tmp_path, monkeypatch,
     silently arming at the wrong rounds."""
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append("start"))
+                        lambda d, **kw: calls.append("start"))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append("stop"))
     pw = ProfileWindow.parse("5:6", str(tmp_path))
@@ -545,7 +545,7 @@ def test_profile_window_block_overlap_and_resume_past(tmp_path, monkeypatch,
 
 
 def test_profile_window_degrades_to_loud_noop(tmp_path, monkeypatch, capsys):
-    def boom(d):
+    def boom(d, **kw):
         raise RuntimeError("no profiler on this backend")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)
@@ -660,14 +660,20 @@ def test_device_track_spans_neither_overlap_nor_end_together(tmp_path):
 
 class _RecordingAnnotation:
     made: list = []
+    log: list = []  # ("enter" | "exit", name) as the loop's thread did them
 
     def __init__(self, name, **kw):
+        self.name = name
         self.made.append((name, kw))
 
     def __enter__(self):
+        if threading.current_thread() is threading.main_thread():
+            self.log.append(("enter", self.name))  # not the prefetch thread's
         return self
 
     def __exit__(self, *exc):
+        if threading.current_thread() is threading.main_thread():
+            self.log.append(("exit", self.name))
         return False
 
 
@@ -675,10 +681,14 @@ def test_loop_spans_are_mirrored_inside_a_profile_window(tmp_path,
                                                          monkeypatch):
     """While a ProfileWindow capture runs, every tracer span also enters a
     jax.profiler.TraceAnnotation("<track>/<name>", **args), with --trace
-    off; outside one nothing is constructed."""
+    off, and every instant enters one and leaves it at once; outside a
+    capture nothing is constructed. The drain marks each dispatch it reads
+    (`runner/ready`, the ready stamp itself), and the session's jit call is
+    a span of its own inside the loop's dispatch."""
     made = _RecordingAnnotation.made = []
+    log = _RecordingAnnotation.log = []
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RecordingAnnotation)
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     opt = FedOptimizer(lambda _: LR, 1)
 
@@ -694,19 +704,58 @@ def test_loop_spans_are_mirrored_inside_a_profile_window(tmp_path,
     # closes at the drain that commits round 3, after round 4's dispatch
     assert [(n, kw["round"]) for n, kw in loop if "round" in kw] == [
         ("runner/prepare", 2), ("runner/dispatch", 2),
+        ("runner/commit_round", 1),
         ("runner/prepare", 3), ("runner/dispatch", 3),
-        ("runner/prepare", 4), ("runner/dispatch", 4)]
+        ("runner/commit_round", 2),
+        ("runner/prepare", 4), ("runner/dispatch", 4),
+        ("runner/commit_round", 3)]
+    # one ready mark a dispatch read, inside the drain that read it
     assert [(n, kw["round_first"], kw["rounds"]) for n, kw in loop
             if "round_first" in kw] == [
         (f"runner/{what}", rnd, 1) for rnd in (1, 2, 3)
-        for what in ("drain", "commit")]
+        for what in ("drain", "ready", "commit")]
+    i = log.index(("enter", "runner/ready"))
+    assert log[i - 1:i + 3] == [
+        ("enter", "runner/drain"), ("enter", "runner/ready"),
+        ("exit", "runner/ready"), ("exit", "runner/drain")]
+    # the jit call alone, inside the loop's dispatch span, with its round
+    assert [kw for n, kw in made if n == "session/launch"] == [
+        {"round": 2}, {"round": 3}, {"round": 4}]
+    i = log.index(("enter", "session/launch"))
+    assert log[i - 1:i + 3] == [
+        ("enter", "runner/dispatch"), ("enter", "session/launch"),
+        ("exit", "session/launch"), ("exit", "runner/dispatch")]
     assert {n for n, _ in made} - {n for n, _ in loop} <= {
-        "federated/prepare_round"}
+        "federated/prepare_round", "session/launch"}
 
     made.clear()  # the window closed: the mirror is off again
     with obtrace.span("runner", "prepare", round=9):
         pass
+    obtrace.instant("runner", "ready", round_first=9, rounds=1)
     assert made == []
+
+
+def test_block_dispatch_launch_span_names_its_rounds(tmp_path, monkeypatch):
+    """A fused block's jit call is one `session/launch` with the block's
+    first round and its size, and the drain reads it back as one ready
+    mark of as many rounds."""
+    made = _RecordingAnnotation.made = []
+    _RecordingAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RecordingAnnotation)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    run_loop(_tiny_session(), FedOptimizer(lambda _: LR, 1), _loop_cfg(
+        8, max_inflight=4, rounds_per_dispatch=2, profile_rounds="2:5",
+        profile_dir=str(tmp_path)))
+    launches = [kw for n, kw in made if n == "session/launch"]
+    assert launches and all(kw["rounds"] == 2 for kw in launches)
+    assert [kw["round_first"] for kw in launches] == list(
+        range(launches[0]["round_first"], launches[0]["round_first"]
+              + 2 * len(launches), 2))
+    readies = [kw for n, kw in made if n == "runner/ready"]
+    assert readies and all(kw["rounds"] == 2 for kw in readies)
+    assert {kw["round_first"] for kw in readies} <= {
+        kw["round_first"] for kw in launches} | {0}
 
 
 # ------------------------------------------- named phases, capture summary
@@ -882,7 +931,7 @@ def test_profile_window_never_shows_an_earlier_captures_summary(
     name no phase) the readers see no reading, not the last capture's."""
     from commefficient_tpu.obs import profiler
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     rounds = obreg.default().gauge("profile_traced_rounds")
     rounds.set(12)  # what an earlier capture of this process published
@@ -898,6 +947,261 @@ def test_profile_window_never_shows_an_earlier_captures_summary(
         ("XLA Ops", [("%fusion", 0, 10, "")])])]
     with pytest.raises(ValueError, match="names a phase"):
         profiler.summarize(unscoped, ("apply",))
+
+
+# ------------------------------------ the launch summary (one clock)
+
+MS = 1e6  # the planes are in ns
+
+
+def _launch_capture(rounds, step, device_step, *, head=0.1, busy=30.0,
+                    tail=0.2, lag=0.5, late=(), undispatched=(), shift=None,
+                    first_run_id=None):
+    """Hand-built planes, host events and run ids of `rounds` executions of
+    one round program. On the device line execution i starts at i *
+    device_step (plus `shift[round]` for it and every later one) and runs
+    head + busy + tail; the host stamps it ready `lag` after its module's
+    end on a clock on which executions are `step` apart. The dispatch of a
+    round returns 5 ms into its predecessor, or 1 ms after its predecessor's
+    last operation for the rounds in `late`; rounds in `undispatched` have
+    no dispatch span. With `first_run_id` the module events carry the
+    runtime's ids, four apart, each dispatched round's launch holds the
+    runtime's enqueue of its id (behind that of a small program's), and the
+    host completes each execution 0.3 ms after its module's end."""
+    modules, ops, host, run_ids = [], [], [], {}
+    moved, last_op = 0.0, None
+    for i, rnd in enumerate(rounds):
+        moved += (shift or {}).get(rnd, 0.0)
+        start = i * device_step + moved
+        first = start + head
+        modules.append(("jit_step(1)", start * MS, (head + busy + tail) * MS, ""))
+        ops += [("%fusion.1", first * MS, busy / 2 * MS, ""),
+                ("%fusion.2", (first + busy / 2) * MS, busy / 2 * MS, ""),
+                ("%inner", (first + 1.0) * MS, 2.0 * MS, "")]  # nested: once
+        ready = i * step + moved + head + busy + tail + lag
+        host.append(("runner/ready", ready * MS, 0.001 * MS,
+                     {"round_first": rnd, "rounds": 1}))
+        if first_run_id is not None:
+            run_ids[start * MS] = first_run_id + 4 * i
+            # the three small programs behind it are done by then too
+            host.append(("CompleteCallbacks", (ready - lag + 0.3) * MS,
+                         0.2 * MS, {"run_id": first_run_id + 4 * i + 3}))
+        if i and rnd not in undispatched:
+            end = last_op + 1.0 if rnd in late else start - device_step + 5.0
+            host.append(("runner/dispatch", (end - 3.0) * MS, 3.0 * MS,
+                         {"round": rnd}))
+            host.append(("session/launch", (end - 2.0) * MS, 1.5 * MS,
+                         {"round": rnd}))
+            if first_run_id is not None:
+                host += [("DoEnqueueProgram", (end - 1.9) * MS, 0.03 * MS,
+                          {"run_id": first_run_id + 4 * i - 1}),
+                         ("DoEnqueueProgram", (end - 1.0) * MS, 0.03 * MS,
+                          {"run_id": first_run_id + 4 * i})]
+        last_op = first + busy
+    planes = [("/device:TPU:0", [("XLA Modules", modules), ("XLA Ops", ops)])]
+    return planes, host, run_ids
+
+
+def test_launch_summary_pairs_executions_with_ready_marks():
+    """Six executions 34 ms apart on both clocks, 30 ms busy each: the 4 ms
+    a round are tail + between + head and no drift. The pair whose second
+    dispatch came late does not count (it alone is 10 ms further apart), nor
+    the one whose second dispatch is outside the capture; what the edges cut
+    is counted: operations before the first module event, a small program's
+    module beside the round program, the kept round's execution that no
+    ready mark follows. Every hole between two executions is put down to
+    the runtime's events in it, threads and nesting counted once."""
+    from commefficient_tpu.obs import profiler
+
+    planes, host, _ = _launch_capture(
+        range(10, 16), 34.0, 34.0, late={13}, undispatched={11},
+        shift={13: 10.0})
+    mods, ops = planes[0][1][0][1], planes[0][1][1][1]
+    ops += [("%fusion.2", -20.0 * MS, 15.0 * MS, ""),  # cut at the start
+            ("%shift", 31.0 * MS, 0.1 * MS, "")]  # another program's
+    mods += [("jit__threefry_split(2)", 31.0 * MS, 0.1 * MS, ""),
+             ("jit_step(1)", 6 * 34.0 * MS + 10.0 * MS, 30.3 * MS, "")]
+    ops.append(("%fusion.1", (6 * 34.0 + 10.1) * MS, 30.0 * MS, ""))
+    host += [("PjitFunction(step)", 60.0 * MS, 2.0 * MS, {}),
+             ("Linearize", 64.5 * MS, 3.0 * MS, {}),  # in (11, 12)'s hole
+             ("Transpose", 65.0 * MS, 1.0 * MS, {}),  # inside it
+             ("Transpose", 65.5 * MS, 1.5 * MS, {}),  # on another thread
+             ("federated/prepare_round", 64.0 * MS, 3.9 * MS, {"round": 14})]
+    got = profiler.summarize_launches(planes, host)
+    assert got["round_program"] == "jit_step(1)" and got["by"] == "order"
+    assert (got["executions"], got["paired"]) == (7, 6)
+    assert (got["pairs"], got["starved"], got["unknown"]) == (3, 1, 1)
+    assert got["dropped"] == {
+        "ops_before_first_module": 1, "ops_after_last_module": 0,
+        "modules_before_first_ready": 0, "modules_after_last_ready": 1}
+    assert not got["first_cut"]
+    want = {"gap": 4.0, "between": 3.7, "head": 0.1, "tail": 0.2,
+            "inside": 0.0, "drift": 0.0, "ready_lag": 0.5, "call": 1.5,
+            "busy": 30.0}
+    for part, ms in want.items():
+        assert got[f"{part}_ms"] == pytest.approx(ms, abs=1e-6), part
+    # the hole between round 11's last operation (64.1) and round 12's
+    # first (68.1) is one of five: the runtime's events, not the program's
+    assert got["between_hosts"] == [("Linearize", pytest.approx(3.0 / 5)),
+                                    ("Transpose", pytest.approx(2.0 / 5))]
+
+    reg = obreg.Registry()
+    profiler.publish_launches(got, reg)
+    shown = reg.snapshot()
+    assert shown["profile_launch_pairs"]["value"] == 3
+    assert shown["profile_launch_gap_ms"]["value"] == pytest.approx(4.0)
+    assert shown["profile_launch_busy_ms"]["value"] == pytest.approx(30.0)
+    assert shown["profile_launch_drift_ms"]["value"] == pytest.approx(0.0, abs=1e-6)
+    line = profiler.format_launches(got)
+    assert line.startswith("launch ms/round: gap 4.000 = drift 0.000 + tail 0.200")
+    assert "3 queued pairs (1 starved, 1 handed over before the capture)" in line
+    assert "6 paired by order of 7 executions" in line and "NO READING" not in line
+    assert "Linearize 0.600, Transpose 0.400" in line
+
+
+def test_launch_summary_reads_a_compressed_device_line_as_drift():
+    """The same rounds, 34 ms apart by the host's ready marks, on a device
+    line that lays the executions end to end (30.3 ms apart): the gap is
+    still 4 ms a round, and all of it but the head and the tail is drift,
+    not a hole between the modules."""
+    from commefficient_tpu.obs import profiler
+
+    planes, host, _ = _launch_capture(range(20, 26), 34.0, 30.3)
+    got = profiler.summarize_launches(planes, host)
+    assert (got["pairs"], got["starved"], got["unknown"]) == (5, 0, 0)
+    assert got["gap_ms"] == pytest.approx(4.0)
+    assert got["between_ms"] == pytest.approx(0.0, abs=1e-6)
+    assert got["drift_ms"] == pytest.approx(3.7)
+    assert got["gap_ms"] == pytest.approx(sum(
+        got[f"{p}_ms"] for p in ("drift", "tail", "between", "head", "inside")))
+
+
+def test_launch_summary_takes_rounds_and_the_queue_from_the_runtimes_ids():
+    """Where module events and the host's events carry the runtime's
+    run_id, an execution's ready mark is the one that follows the host's
+    completion of its id (the first execution was launched before the
+    capture; its module event begins with the capture: cut), and a pair is
+    queued by the ENQUEUE of its second program, not by the return of its
+    dispatch: round 32's batch reached the device late, so its program was
+    enqueued after round 31 had ended."""
+    from commefficient_tpu.obs import profiler
+
+    planes, host, run_ids = _launch_capture(
+        range(30, 35), 34.0, 34.0, first_run_id=388, shift={32: 20.0})
+    late = 2 * 34.0 + 20.0 - 0.5  # half a ms before its module starts
+    host = [e for e in host if e[:1] + (e[3].get("run_id"),) != (
+        "DoEnqueueProgram", 396)] + [
+        ("DoEnqueueProgram", late * MS, 0.03 * MS, {"run_id": 396})]
+    got = profiler.summarize_launches(planes, host, run_ids)
+    assert got["by"] == "run_id" and got["first_cut"]
+    assert (got["executions"], got["paired"]) == (5, 5)
+    assert (got["pairs"], got["starved"], got["unknown"]) == (3, 1, 0)
+    assert got["gap_ms"] == pytest.approx(4.0)
+    assert "the first module event" in profiler.format_launches(got)
+    # with no ids the dispatch's return decides, and the starved pair counts
+    plain = profiler.summarize_launches(
+        planes, [e for e in host if "run_id" not in e[3]])
+    assert plain["by"] == "order" and (plain["pairs"], plain["starved"]) == (4, 0)
+    assert plain["gap_ms"] == pytest.approx(4.0 + 20.0 / 4)
+
+
+def test_launch_summary_under_three_pairs_is_no_reading(capsys):
+    """Every second dispatch late: no queued pair, the line says so and the
+    readers' threshold (3 pairs) is not met; a capture with no ready mark at
+    all pairs nothing and drops every execution at the end edge."""
+    from commefficient_tpu.obs import profiler
+
+    planes, host, _ = _launch_capture(range(4), 40.0, 40.0, late={1, 2, 3})
+    got = profiler.summarize_launches(planes, host)
+    assert (got["pairs"], got["starved"]) == (0, 3)
+    assert got["gap_ms"] == 0.0
+    assert "NO READING" in profiler.format_launches(got)
+    bare = profiler.summarize_launches(planes, [])
+    assert (bare["paired"], bare["pairs"]) == (0, 0)
+    assert bare["dropped"]["modules_after_last_ready"] == 4
+    with pytest.raises(ValueError):
+        profiler.summarize_launches([("/device:TPU:0", [("XLA Ops", [])])], [])
+
+
+def test_load_capture_keeps_annotations_runtime_events_and_run_ids(tmp_path):
+    """The host plane as summarize_launches takes it: the program's
+    annotations with their round arguments, the runtime's events of 10 us or
+    longer with their run_id, every thread's in one list; the Python
+    tracer's frames are dropped by their metadata and a flood of
+    microsecond events by its length. The device's module events give their
+    run_id by their start."""
+    from commefficient_tpu.obs import profiler
+
+    space = profiler._xplane_pb2().XSpace()
+    host = space.planes.add(name="/host:CPU")
+    for i, name in ((1, "round_first"), (2, "rounds"), (3, "run_id"), (4, "size")):
+        host.stat_metadata[i].name = name
+    for i, name in ((1, "runner/ready"), (2, "$loop.py:527 drain"),
+                    (3, "Transpose"), (4, "DoEnqueueProgram")):
+        host.event_metadata[i].name = name
+    main = host.lines.add(name="python", timestamp_ns=1000)
+    ready = main.events.add(metadata_id=1, offset_ps=2_000_000, duration_ps=1000)
+    ready.stats.add(metadata_id=1, int64_value=7)
+    ready.stats.add(metadata_id=2, uint64_value=2)
+    ready.stats.add(metadata_id=4, uint64_value=512)  # not a round argument
+    main.events.add(metadata_id=2, offset_ps=0, duration_ps=9_000_000)
+    task = host.lines.add(name="pjrt-tpu-tasks/1", timestamp_ns=2000)
+    task.events.add(metadata_id=3, offset_ps=0, duration_ps=50_000_000)
+    for i in range(5):  # the flood inside it: a microsecond each
+        task.events.add(metadata_id=3, offset_ps=i * 2_000_000, duration_ps=1_000_000)
+    enqueue = task.events.add(metadata_id=4, offset_ps=60_000_000, duration_ps=30_000_000)
+    enqueue.stats.add(metadata_id=3, uint64_value=392)
+    enqueue.stats.add(metadata_id=4, uint64_value=16384)
+    dev = space.planes.add(name="/device:TPU:0")
+    dev.stat_metadata[1].name = "run_id"
+    dev.event_metadata[1].name = "jit_step(1)"
+    dev.lines.add(name="XLA Modules", timestamp_ns=500).events.add(
+        metadata_id=1, offset_ps=1_000_000, duration_ps=8_000_000).stats.add(
+        metadata_id=1, uint64_value=392)
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space.SerializeToString())
+    want = [("runner/ready", 3000.0, 1.0, {"round_first": 7, "rounds": 2}),
+            ("Transpose", 2000.0, 50000.0, {}),
+            ("DoEnqueueProgram", 62000.0, 30000.0, {"run_id": 392})]
+    planes, events, run_ids = profiler.load_capture(str(path))
+    assert events == want and run_ids == {1500.0: 392}
+    assert planes == [("/device:TPU:0", [
+        ("XLA Modules", [("jit_step(1)", 1500.0, 8000.0, "")])])]
+    assert planes == profiler.load_device_planes(str(path))
+
+
+def test_profile_window_zeroes_the_launch_gauges_at_a_captures_start(
+        tmp_path, monkeypatch):
+    """profile_launch_pairs is zeroed when a capture starts, as
+    profile_traced_rounds is: where this capture's launch summary fails, the
+    readers see no reading, not the last capture's."""
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    pairs = obreg.default().gauge("profile_launch_pairs")
+    pairs.set(9)  # what an earlier capture of this process published
+    obreg.default().gauge("profile_launch_gap_ms").set(4.0)
+    pw = ProfileWindow.parse("0:1", str(tmp_path))
+    pw.on_dispatch(0)
+    assert pairs.value == 0
+    pw.on_committed(2)
+    assert pairs.value == 0
+
+
+def test_capture_options_leave_the_python_tracer_off(tmp_path, monkeypatch):
+    """The window hands start_trace its options: the Python tracer's frames
+    have no reader, so a capture does not record them."""
+    from commefficient_tpu.obs import profiler
+
+    seen = {}
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, profiler_options=None: seen.update(o=profiler_options))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    pw = ProfileWindow.parse("0:0", str(tmp_path))
+    pw.on_dispatch(0)
+    pw.close()
+    assert seen["o"].python_tracer_level == profiler.PYTHON_TRACER_LEVEL == 0
+    assert seen["o"].host_tracer_level == profiler.HOST_TRACER_LEVEL
 
 
 # --------------------------------------------- crash-safe JSONL logging

@@ -176,12 +176,12 @@ def test_capture_is_reduced_a_second_time_by_block(tmp_path, monkeypatch, capsys
     unscoped = [("/device:TPU:0", [
         ("XLA Modules", [("jit_step(1)", 0, 10 * US, "")]),
         ("XLA Ops", [("%f", 0, 10 * US, "jit(step)/client_grad/conv")])])]
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     monkeypatch.setattr(profiler, "newest_capture", lambda d: d)
     reg = obreg.default()
     for planes, want in ((scoped, 2), (unscoped, 0)):
-        monkeypatch.setattr(profiler, "load_device_planes", lambda path, planes=planes: planes)
+        monkeypatch.setattr(profiler, "load_capture", lambda path, planes=planes: (planes, [], {}))
         pw = profiler.ProfileWindow.parse("0:1", str(tmp_path), phases=("client_grad", "server_topk"))
         pw.on_dispatch(0)
         assert reg.gauge("profile_block_traced_rounds").value == 0
