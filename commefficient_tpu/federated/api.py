@@ -29,6 +29,7 @@ from ..obs import registry as obreg
 from ..obs import trace as obtrace
 from ..parallel import mesh as meshlib
 from ..resilience import retry as rtry
+from ..sketch import csvec
 from ..utils.comm import round_comm_mb
 from . import engine
 
@@ -487,6 +488,17 @@ class FederatedSession:
                  and engine.cohort_backward_fused(self.cfg))
         self.cohort_backward = "fused" if fused else "per-client"
         obreg.default().gauge("engine_cohort_backward_fused").set(int(fused))
+        # likewise static: how many partial maxima the round's approximate
+        # top-k over d picks its k from by selection (csvec.topk_abs); 0
+        # with exact top-k, with no top-k, or where they are too few for
+        # the selection to pay and lax.approx_max_k aggregates them itself
+        mcfg = self.cfg.mode
+        self.topk_partial_maxima = (
+            csvec.approx_select_size(mcfg.d, mcfg.k, mcfg.topk_recall)
+            if mcfg.topk_impl == "approx"
+            and mcfg.mode in ("sketch", "true_topk", "local_topk") else 0)
+        obreg.default().gauge("sketch_topk_partial_maxima").set(
+            self.topk_partial_maxima)
         self._eval = jax.jit(engine.make_eval_step(eval_loss_fn))
         if self.client_state is not None:
             gather = lambda st, ids: jax.tree.map(lambda a: a[ids], st)  # noqa: E731

@@ -37,9 +37,12 @@ class ModeConfig:
     # (lax.top_k's result; from csvec.TOPK_SELECT_MIN_N elements on by a
     # counted threshold and a compaction, csvec.select_topk_abs, not by
     # the full sort the TPU lowers lax.top_k to), "approx"
-    # (lax.approx_max_k, TPU PartialReduce lowering at topk_recall; exact
-    # elsewhere), or "oversample" (approx preselect of 4k candidates +
-    # exact refine — near-exact at PartialReduce speed; csvec.topk_abs).
+    # (lax.approx_max_k, TPU PartialReduce lowering at topk_recall, then
+    # the exact k largest of the partial maxima, by csvec's selection
+    # where there are enough of them and by approx_max_k's own sort below
+    # that; exact elsewhere), or "oversample" (approx preselect of 4k
+    # candidates + exact refine — near-exact at PartialReduce speed;
+    # csvec.topk_abs).
     # Accuracy impact of approx: the paper-scale 2x2 seed
     # replication put exact-vs-approx@0.99 within seed variance
     # (single-seed orderings inverted across seeds — results/README.md),

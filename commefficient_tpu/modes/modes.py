@@ -45,8 +45,11 @@ def topk_dense(
     """(idx[k], vals[k]) of the k largest-|.| coordinates of dense v.
 
     impl="approx" uses `lax.approx_max_k` (TPU PartialReduce lowering at
-    `recall`; exact on backends without the lowering). impl="exact" no
-    longer sorts all of v at d in the millions (csvec.select_topk_abs).
+    `recall`; exact on backends without the lowering), and where the
+    reduction leaves enough partial maxima picks the exact k largest of
+    them by selection, not by approx_max_k's sort of them all
+    (csvec.topk_abs). impl="exact" no longer sorts all of v at d in the
+    millions (csvec.select_topk_abs).
     The paper-scale 2x2 seed replication found exact-vs-approx@0.99
     accuracy differences within seed variance (results/README.md);
     ModeConfig.topk_recall exposes the dial.

@@ -411,10 +411,12 @@ _THRESHOLD_BITS = 2
 
 
 def _kth_largest_key(keys: jnp.ndarray, k: int) -> jnp.ndarray:
-    """The k-th largest of non-negative int32 `keys`, by counting: the
-    value's 31 bits from the top, _THRESHOLD_BITS a pass; a pass counts the
-    keys at or above every candidate prefix in one read (sibling reductions
-    of one fusion) and keeps the largest that still has k."""
+    """The k-th largest of int32 `keys`, of which at least k are
+    non-negative (a negative key is at or above no candidate, so it counts
+    for nothing), by counting: the value's 31 bits from the top,
+    _THRESHOLD_BITS a pass; a pass counts the keys at or above every
+    candidate prefix in one read (sibling reductions of one fusion) and
+    keeps the largest that still has k."""
     t = jnp.int32(0)
     hi = 31
     while hi > 0:
@@ -453,6 +455,14 @@ def _slot_rows(incl: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     return row, slot[:, 0] - start
 
 
+def _magnitude_keys(mag: jnp.ndarray) -> jnp.ndarray:
+    """A non-negative float's int32 bit pattern, which orders as the value
+    does: +0.0 is 0 and a NaN sorts above inf, as in lax.top_k's total
+    order. Anything with the sign bit set (a reduction's -inf or lowest
+    float in a slot it never filled) is a negative key."""
+    return jax.lax.bitcast_convert_type(mag.astype(jnp.float32), jnp.int32)
+
+
 def select_topk_abs(x: jnp.ndarray, k: int) -> jnp.ndarray:
     """`jax.lax.top_k(jnp.abs(x), k)[1]` element for element (ties and
     non-finite values included) without sorting x: find the k-th largest
@@ -464,24 +474,31 @@ def select_topk_abs(x: jnp.ndarray, k: int) -> jnp.ndarray:
     elementwise pass or a row reduction; the rest is k-sized (k x 128 keys
     at the widest) or n/128-sized. One-dimensional x (callers vmap).
 
-    1. Keys: |x| as its int32 bit pattern, which orders as the value does
-       for non-negative floats; -0.0 becomes +0.0 and a NaN sorts above inf,
-       as in lax.top_k's total order.
-    2. Threshold t: the k-th largest key (_kth_largest_key).
-    3. Exactly k with lax.top_k's tie rule (its sort is stable: value
+    Keys: |x| as its int32 bit pattern (_magnitude_keys; -0.0 becomes
+    +0.0); the rest works on keys alone (_select_topk_keys)."""
+    return _select_topk_keys(_magnitude_keys(jnp.abs(x)), k)
+
+
+def _select_topk_keys(keys: jnp.ndarray, k: int) -> jnp.ndarray:
+    """Positions of the k largest of one-dimensional int32 `keys`, largest
+    first, ties by lowest position (lax.top_k's order). A key below 0 is
+    never selected, so at least k keys must be non-negative: a caller
+    marks slots that hold nothing with any negative key, as the rows'
+    own padding is.
+
+    1. Threshold t: the k-th largest key (_kth_largest_key).
+    2. Exactly k with lax.top_k's tie rule (its sort is stable: value
        descending, then index ascending): every key > t and the first
        k - #(key > t) keys == t in index order, i.e. those at or before the
        flat index `cut`.
-    4. Compaction over rows of 128: per-row counts, one prefix over the
+    3. Compaction over rows of 128: per-row counts, one prefix over the
        n/128 rows, the row and in-row rank of each output slot
        (_slot_rows), a gather of those k rows, the lane from an in-row
        prefix (a product with a triangle of ones: exact, the counts are at
        most 128).
-    5. lax.top_k's order: sort the k selected by key descending, index
+    4. lax.top_k's order: sort the k selected by key descending, index
        ascending."""
-    (n,) = x.shape
-    keys = jax.lax.bitcast_convert_type(
-        jnp.abs(x).astype(jnp.float32), jnp.int32)
+    (n,) = keys.shape
     t = _kth_largest_key(keys, k)
     # padding keys are -1: below every real key, so never selected
     rows = jnp.pad(keys, (0, -n % _LANES), constant_values=-1).reshape(
@@ -519,6 +536,25 @@ def select_topk_abs(x: jnp.ndarray, k: int) -> jnp.ndarray:
     return idx
 
 
+def _selection_pays(n: int, k: int) -> bool:
+    return n >= max(TOPK_SELECT_MIN_N, TOPK_SELECT_MIN_N_PER_K * k)
+
+
+def approx_select_size(n: int, k: int, recall: float) -> int:
+    """The number m of partial maxima that `topk_abs(impl="approx")` picks
+    its k from by selection at a vector of n, or 0 where m is too small for
+    the selection to pay and `lax.approx_max_k` aggregates them itself. m
+    is static and the same on every platform (the shape rule of the TPU's
+    PartialReduce: whole tiles of 1024, so fewer than 1024 slots can be
+    empty)."""
+    vals, _ = jax.eval_shape(
+        lambda v: jax.lax.approx_max_k(v, k, recall_target=recall,
+                                       aggregate_to_topk=False),
+        jax.ShapeDtypeStruct((n,), jnp.float32))
+    m = vals.shape[0]
+    return m if _selection_pays(m, k) else 0
+
+
 def topk_abs(
     x: jnp.ndarray, k: int, approx: bool = False, recall: float = 0.95,
     impl: str | None = None,
@@ -533,11 +569,22 @@ def topk_abs(
       v5e), below it by `lax.top_k` itself, which the TPU lowers to a full
       sort of x (13.0 ms there; 442 ms at d = 124M, r5 server_split). Both
       return the same array.
-    - "approx": `lax.approx_max_k` (TPU PartialReduce at `recall`; exact
-      lowering elsewhere). Accuracy impact at paper scale is within seed
-      variance for recall 0.99 (2x2 seed replication inverted the
-      single-seed ordering — results/README.md); any cost is below that
-      study's resolution.
+    - "approx": `lax.approx_max_k` at `recall`. On the TPU that is a
+      PartialReduce, which keeps one maximum of every bucket of 2^j
+      coordinates (m partial maxima; which coordinate of a bucket
+      survives is all the approximation there is), and then the k largest
+      of those m. From m >= max(TOPK_SELECT_MIN_N,
+      TOPK_SELECT_MIN_N_PER_K * k) on (`approx_select_size`) the partial
+      maxima are taken as they are (`aggregate_to_topk=False`) and their k
+      largest found by `_select_topk_keys`; below it `approx_max_k`
+      aggregates them itself, by a full sort of the m pairs (10.3 ms at
+      m = 7.78M, GPT-2's d / 16, and 15.5 ms at GLM's 9.24M on a v5e:
+      PERF.md section 6, PR 36). Both return the same array but where
+      partial maxima tie exactly at the k-th place. Elsewhere the
+      lowering is exact (the m largest, sorted). Accuracy impact at paper
+      scale is within seed variance for recall 0.99 (2x2 seed replication
+      inverted the single-seed ordering — results/README.md); any cost is
+      below that study's resolution.
     - "oversample": approx preselect of TOPK_OVERSAMPLE*k candidates +
       exact top_k over them — near-exact selection at PartialReduce
       speed by construction (the exact refine sorts only 4k elements),
@@ -556,10 +603,21 @@ def topk_abs(
             cand = topk_abs(x, kk, impl="approx", recall=recall)
             sub = topk_abs(x[cand], k, impl="exact")
             return cand[sub]
+    keyed = x.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)
     if impl == "approx":
-        _, idx = jax.lax.approx_max_k(jnp.abs(x), k, recall_target=recall)
-    elif (x.shape[0] >= max(TOPK_SELECT_MIN_N, TOPK_SELECT_MIN_N_PER_K * k)
-          and x.dtype in (jnp.float32, jnp.bfloat16, jnp.float16)):
+        if keyed and approx_select_size(x.shape[0], k, recall):
+            # The partial maxima as the reduction leaves them. No second
+            # abs: each is a magnitude (key >= 0), and a slot that no
+            # coordinate reached holds the reduction's initial value (-inf,
+            # a negative key, with an index that means nothing), which the
+            # selection never takes while k real maxima exist; there are
+            # fewer such slots than one tile of 1024 and m >= 7k.
+            vals, pos = jax.lax.approx_max_k(
+                jnp.abs(x), k, recall_target=recall, aggregate_to_topk=False)
+            idx = pos[_select_topk_keys(_magnitude_keys(vals), k)]
+        else:
+            _, idx = jax.lax.approx_max_k(jnp.abs(x), k, recall_target=recall)
+    elif keyed and _selection_pays(x.shape[0], k):
         idx = select_topk_abs(x, k)  # the same array, without the sort of x
     else:
         _, idx = jax.lax.top_k(jnp.abs(x), k)

@@ -33,7 +33,9 @@ def make_parser(task: str = "cv") -> argparse.ArgumentParser:
                    choices=["exact", "approx", "oversample"],
                    help="top-k selection: exact (lax.top_k's result, by "
                         "threshold and compaction where d is large), approx "
-                        "(lax.approx_max_k, TPU-fast at --topk_recall; "
+                        "(lax.approx_max_k's partial maxima at "
+                        "--topk_recall, then the exact k largest of them, "
+                        "by selection where they are many; TPU-fast; "
                         "paper-scale accuracy impact within seed variance "
                         "at recall 0.99 — results/README.md), or oversample "
                         "(approx 4k-candidate preselect + exact refine: "

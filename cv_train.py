@@ -145,6 +145,8 @@ def build(args, fault_plan=None, retry_policy=None):
                           and not args.no_emergency_checkpoint),
     )
     print(f"cohort backward: {session.cohort_backward}", flush=True)
+    print(f"approx top-k partial maxima: {session.topk_partial_maxima}",
+          flush=True)
     return session, test_set
 
 

@@ -256,6 +256,8 @@ def build(args, fault_plan=None, retry_policy=None):
             "and the device count"
         )
     print(f"cohort backward: {session.cohort_backward}", flush=True)
+    print(f"approx top-k partial maxima: {session.topk_partial_maxima}",
+          flush=True)
     return session, valid_set, {"model": model, "tok": tok}
 
 

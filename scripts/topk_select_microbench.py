@@ -10,6 +10,17 @@ capture, and timed by the capture's own "XLA Modules" events: a device time,
 no host clock in it. Writes chiprun_out/topk_select_<tag>.json. Chip only:
 on another backend it checks equality and prints no time (`--rehearse`),
 or exits 2. PERF.md section 6 (PR 32) has the table this produced.
+
+    python scripts/topk_select_microbench.py --approx 124443648:50000,... [tag]
+
+times `csvec.topk_abs(impl="approx", recall=0.99)` against plain
+`lax.approx_max_k` (which aggregates its partial maxima by a full sort of
+them: what topk_abs was until PR 36) the same way, on one normal vector
+made on the device from the seed n % 1000 + k; it prints the number m of
+partial maxima and checks that the two index SETS are equal but where
+partial maxima tie exactly at the k-th place (the selected magnitudes are
+the same, and an index that one side has alone holds the smallest of them),
+and lie in [0, n). PERF.md section 6 (PR 36) has that table.
 """
 import collections
 import glob
@@ -64,9 +75,81 @@ def _device_ms(trace_dir, names):
     return {name: sum(v) / len(v) for name, v in out.items()}
 
 
+def _time(fns, x):
+    """device ms of each jitted function on x, RUNS executions in one capture."""
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for fn in fns.values():
+            for _ in range(RUNS):
+                out = fn(x)
+            out.block_until_ready()
+        jax.profiler.stop_trace()
+        return _device_ms(trace_dir, set(fns))
+
+
+def exact_cell(n, k, device):
+    def sort_all(v):
+        return jax.lax.top_k(jnp.abs(v), k)[1]
+
+    def select(v):
+        return csvec.select_topk_abs(v, k)
+
+    fns = {"sort_all": jax.jit(sort_all), "select": jax.jit(select)}
+    cell = {"equal": {}, "top_k_is_numpy_order": {}}
+    inputs = _inputs(n, k)
+    for name, x in inputs.items():
+        want = np.asarray(fns["sort_all"](jnp.asarray(x)))
+        cell["top_k_is_numpy_order"][name] = bool(
+            np.array_equal(want, _numpy_order(x, k)))
+        cell["equal"][name] = bool(
+            np.array_equal(np.asarray(fns["select"](jnp.asarray(x))), want))
+    if device.platform == "tpu":
+        cell["device_ms"] = _time(fns, jnp.asarray(inputs["normal"]))
+    return cell
+
+
+def approx_cell(n, k, device):
+    def aggregated(v):
+        return jax.lax.approx_max_k(jnp.abs(v), k, recall_target=0.99)[1]
+
+    def selected(v):
+        return csvec.topk_abs(v, k, impl="approx", recall=0.99)
+
+    fns = {"aggregated": jax.jit(aggregated), "selected": jax.jit(selected)}
+    # jax.random.normal alone has 2^-23 steps of the uniform it is made
+    # from, 8e-5 wide at 3.4 sigma: the k largest of 1e8 would share a few
+    # thousand magnitudes and tie at the k-th place for certain. A second
+    # draw, scaled down, spreads them over the float32 values in between.
+    key, fine = jax.random.split(jax.random.PRNGKey(n % 1000 + k))
+    x = (jax.random.normal(key, (n,), jnp.float32)
+         + 2.0 ** -10 * jax.random.normal(fine, (n,), jnp.float32))
+    want, got = (np.sort(np.asarray(fn(x))) for fn in fns.values())
+    # the two may differ only where partial maxima tie at the k-th place:
+    # the same magnitudes, and every index one side has alone holds the
+    # smallest of them
+    def mag(idx):
+        return np.abs(np.asarray(x[jnp.asarray(idx)]))
+
+    alone = np.setxor1d(got, want)
+    mag_got, mag_want = np.sort(mag(got)), np.sort(mag(want))
+    cell = {"partial_maxima": csvec.approx_select_size(n, k, 0.99),
+            "index_sets_equal": alone.size == 0,
+            "indices_one_side_alone": int(alone.size),
+            "equal": {
+                "in_range": bool(0 <= got[0] and got[-1] < n
+                                 and np.unique(got).size == k),
+                "magnitudes": bool(np.array_equal(mag_got, mag_want)),
+                "alone_only_at_the_kth": bool(
+                    (mag(alone) == mag_want[0]).all())}}
+    if device.platform == "tpu":
+        cell["device_ms"] = _time(fns, x)
+    return cell
+
+
 def main(argv):
     rehearse = "--rehearse" in argv
-    argv = [a for a in argv if a != "--rehearse"]
+    approx = "--approx" in argv
+    argv = [a for a in argv if a not in ("--rehearse", "--approx")]
     sizes = [tuple(int(v) for v in s.split(":")) for s in argv[0].split(",")]
     tag = argv[1] if len(argv) > 1 else "run"
     device = jax.devices()[0]
@@ -76,31 +159,7 @@ def main(argv):
         return 2
     results = {"device": f"{device.platform} {device.device_kind}", "cells": {}}
     for n, k in sizes:
-        def sort_all(v):
-            return jax.lax.top_k(jnp.abs(v), k)[1]
-
-        def select(v):
-            return csvec.select_topk_abs(v, k)
-
-        fns = {"sort_all": jax.jit(sort_all), "select": jax.jit(select)}
-        cell = {"equal": {}, "top_k_is_numpy_order": {}}
-        inputs = _inputs(n, k)
-        for name, x in inputs.items():
-            want = np.asarray(fns["sort_all"](jnp.asarray(x)))
-            cell["top_k_is_numpy_order"][name] = bool(
-                np.array_equal(want, _numpy_order(x, k)))
-            cell["equal"][name] = bool(
-                np.array_equal(np.asarray(fns["select"](jnp.asarray(x))), want))
-        if device.platform == "tpu":
-            x = jnp.asarray(inputs["normal"])
-            with tempfile.TemporaryDirectory() as trace_dir:
-                jax.profiler.start_trace(trace_dir)
-                for fn in fns.values():
-                    for _ in range(RUNS):
-                        out = fn(x)
-                    out.block_until_ready()
-                jax.profiler.stop_trace()
-                cell["device_ms"] = _device_ms(trace_dir, set(fns))
+        cell = approx_cell(n, k, device) if approx else exact_cell(n, k, device)
         results["cells"][f"{n}:{k}"] = cell
         print(f"n={n} k={k} {json.dumps(cell)}", flush=True)
     os.makedirs("chiprun_out", exist_ok=True)

@@ -435,17 +435,92 @@ def test_exact_topk_lowers_to_no_sort_scan_or_scatter_over_n():
             assert not any(s in line for s in n_sized), line
 
 
-def test_approx_topk_lowers_as_before():
-    """The bypass: impl="approx" is lax.approx_max_k's own lowering, word for
-    word, and holds nothing of the selection (no key bitcast, no sort of the
-    k selected, no row gather, no prefix product)."""
-    n, k = csvec_mod.TOPK_SELECT_MIN_N + 77, 1000
-    text, _ = _lowered(
-        lambda v: csvec_mod.topk_abs(v, k, impl="approx", recall=0.99), n)
-    plain, _ = _lowered(
-        lambda v: jax.lax.approx_max_k(
-            jnp.abs(v), k, recall_target=0.99)[1].astype(jnp.int32), n)
-    assert "@ApproxTopK" in text and text == plain
-    for op in ("bitcast_convert", "sort", "gather", "dot_general",
-               "reduce_window"):
-        assert f"stablehlo.{op}" not in text, op
+def _approx(k):
+    return lambda v: csvec_mod.topk_abs(v, k, impl="approx", recall=0.99)
+
+
+def _plain_approx(k):
+    return lambda v: jax.lax.approx_max_k(
+        jnp.abs(v), k, recall_target=0.99)[1].astype(jnp.int32)
+
+
+# (n, k) on either side of topk_abs's rule for impl="approx": m = 175,104
+# partial maxima at recall 0.99 against the constant's 350,000, and 500,736
+_APPROX_BELOW = (csvec_mod.TOPK_SELECT_MIN_N + 77, 1000)
+_APPROX_ABOVE = (1_000_003, 5000)
+
+
+@pytest.mark.parametrize("n,k,m", [_APPROX_BELOW + (0,),
+                                   _APPROX_ABOVE + (500_736,)],
+                         ids=["lowers_as_before_below_the_constant",
+                              "selects_above_it"])
+def test_approx_topk_lowering(n, k, m):
+    """Where the partial maxima are too few for the selection to pay,
+    impl="approx" is lax.approx_max_k's own lowering, word for word, and
+    holds nothing of the selection (no key bitcast, no sort of the k
+    selected, no row gather, no prefix product). Above the constant it
+    takes the m partial maxima unaggregated and sorts k pairs, nothing
+    else: the guard that keeps the aggregation's sort of m pairs from
+    coming back (tests/test_tpu_compile.py asks the TPU's compiler the
+    same)."""
+    assert csvec_mod.approx_select_size(n, k, 0.99) == m
+    text, _ = _lowered(_approx(k), n)
+    assert text.count("@ApproxTopK") == 1
+    sorts = re.findall(r'"stablehlo\.sort"\(.*?\) -> \((.*?)\)', text, re.S)
+    if not m:
+        assert text == _lowered(_plain_approx(k), n)[0]
+        assert not sorts
+        for op in ("bitcast_convert", "gather", "dot_general", "reduce_window"):
+            assert f"stablehlo.{op}" not in text, op
+    else:
+        assert "aggregate_to_topk = false" in text
+        assert f"-> (tensor<{m}xf32>, tensor<{m}xi32>)" in text
+        assert "chlo.top_k" not in text and "stablehlo.scatter" not in text
+        assert sorts == [f"tensor<{k}xi32>, tensor<{k}xi32>"], sorts
+
+
+def test_approx_topk_above_the_constant_equals_the_aggregated_call():
+    """The k largest of the partial maxima, by selection or by
+    approx_max_k's own sort: the same indices in the same order on a
+    vector with no tied magnitudes (the CPU's fallback keeps the m
+    largest, so the approximation itself is the same on both sides)."""
+    n, k = _APPROX_ABOVE
+    rng = np.random.RandomState(14)
+    x = ((1.0 + rng.permutation(n) / n) * rng.choice([-1, 1], n)).astype(
+        np.float32)
+    assert np.unique(np.abs(x)).size == n  # no tied magnitudes
+    got = jax.jit(_approx(k))(jnp.asarray(x))
+    want = _plain_approx(k)(jnp.asarray(x))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert got.dtype == jnp.int32 and got.shape == (k,)
+
+
+def test_select_topk_keys_never_takes_a_negative_key():
+    """What the TPU's PartialReduce leaves in a slot no coordinate reached
+    is its initial value, -inf or the lowest float: as int32 both are
+    negative, and a negative key is never selected, wherever it lies."""
+    rng = np.random.RandomState(15)
+    n, k = 5000, 300
+    mags = np.abs(rng.randn(n)).astype(np.float32)
+    empty = np.concatenate([rng.choice(n, 700, replace=False), [0, n - 1]])
+    mags[empty[::2]] = -np.inf
+    mags[empty[1::2]] = np.finfo(np.float32).min
+    keys = jnp.asarray(mags.view(np.int32))
+    assert int(jnp.sum(keys < 0)) == empty.size
+    got = np.asarray(jax.jit(
+        lambda v: csvec_mod._select_topk_keys(v, k))(keys))
+    assert not np.isin(got, empty).any()
+    real = np.where(mags >= 0, mags, 0.0)
+    np.testing.assert_array_equal(got, _topk_ref(jnp.asarray(real), k))
+
+
+def test_approx_topk_under_vmap_rows_with_different_thresholds():
+    """local_topk with --topk_impl approx runs it per client under vmap:
+    approx_max_k batches over the leading axis, the selection is vmapped."""
+    n, k = _APPROX_ABOVE
+    rng = np.random.RandomState(16)
+    xs = jnp.asarray(rng.randn(3, n).astype(np.float32) * np.array(
+        [[1.0], [1e-3], [1e4]], np.float32))
+    got = jax.jit(jax.vmap(_approx(k)))(xs)
+    want = jax.vmap(_plain_approx(k))(xs)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

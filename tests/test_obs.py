@@ -821,6 +821,40 @@ def test_start_up_line_and_gauge_say_which_cohort_backward(tiny_cv, capsys,
     assert obreg.default().gauge("engine_cohort_backward_fused").value == int(fused)
 
 
+_APPROX_SKETCH = ("--mode", "sketch", "--k", "100", "--num_cols", "2048",
+                  "--num_rows", "3", "--topk_impl", "approx",
+                  "--topk_recall", "0.99")
+
+
+@pytest.mark.parametrize("extra,min_n,selects", [
+    ((), None, False),
+    (_APPROX_SKETCH[:-4], None, False),
+    (_APPROX_SKETCH, None, False),
+    (_APPROX_SKETCH, 1000, True),
+], ids=["no_topk", "exact", "approx_too_few_maxima", "approx_constant_down"])
+def test_start_up_line_and_gauge_say_how_many_partial_maxima(
+        tiny_cv, capsys, monkeypatch, extra, min_n, selects):
+    """Whether the approximate top-k picks its k from the partial maxima by
+    selection is decided from static shapes when the session builds its
+    round program: the trainer's start-up line and the gauge
+    `sketch_topk_partial_maxima` say how many there are, or 0."""
+    from commefficient_tpu.sketch import csvec
+    from commefficient_tpu.utils.config import make_parser, resolve_defaults
+
+    if min_n is not None:
+        monkeypatch.setattr(csvec, "TOPK_SELECT_MIN_N", min_n)
+    args = resolve_defaults(make_parser("cv").parse_args(_argv(extra)))
+    session, _ = cv_train.build(args)
+    d, k = session.cfg.mode.d, session.cfg.mode.k
+    want = csvec.approx_select_size(d, k, 0.99) if selects else 0
+    assert want == 0 or 7 * k <= want < d
+    assert (want > 0) == selects
+    assert session.topk_partial_maxima == want
+    assert (f"approx top-k partial maxima: {want}\n"
+            in capsys.readouterr().out)
+    assert obreg.default().gauge("sketch_topk_partial_maxima").value == want
+
+
 def test_capture_summary_by_phase():
     """Hand-built device planes in the shape load_device_planes gives:
     nesting counts once (a while's body goes to its own phases, the rest of

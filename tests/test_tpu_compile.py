@@ -155,6 +155,37 @@ def test_sharded_round_partitions_kernels_on_four_chips(topo, monkeypatch):
     assert "all-gather" in hlo
 
 
+def test_approx_topk_compiles_for_v5e_to_a_partial_reduce_and_no_sort_of_its_output(
+        one_chip):
+    """impl="approx" above topk_abs's rule: the TPU's PartialReduce leaves m
+    partial maxima, of which the last 1,019 slots hold no coordinate at this
+    n (its last group of 16 tiles is 5 elements long), and the only sort in
+    the compiled program is the one over the k selected. The aggregated
+    call beside it shows what that guards against: a sort of all m pairs."""
+    import re
+
+    from commefficient_tpu.sketch import csvec
+
+    n, k = 16 * 1024 * 400 + 5, 3000
+    m = csvec.approx_select_size(n, k, 0.99)
+    assert m == 401 * 1024
+    v = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)
+
+    def sorted_shapes(fn):
+        text = jax.jit(fn).lower(v).compile().as_text()
+        assert text.count('custom_call_target="PartialReduce"') == 1
+        assert f"(f32[{m}]" in text
+        return [re.search(r"= \(?(\w+\[\d+\])", line).group(1)
+                for line in text.splitlines() if re.search(r" sort\(", line)]
+
+    assert sorted_shapes(
+        lambda x: csvec.topk_abs(x, k, impl="approx", recall=0.99)
+    ) == [f"s32[{k}]"]
+    assert sorted_shapes(
+        lambda x: jax.lax.approx_max_k(jnp.abs(x), k, recall_target=0.99)[1]
+    ) == [f"f32[{m}]"]
+
+
 # --- Qwen3-Next's two new operations at the published widths (PR 27) ---
 
 
