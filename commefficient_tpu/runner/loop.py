@@ -248,7 +248,11 @@ def make_save_ckpt(session: FederatedSession, checkpoint_dir: str):
     storage and every host holds the same replicated state, so only process
     0 writes — two hosts saving the same round would build the identical
     staging dir name and clobber each other's half-written trees. Non-zero
-    processes return None (callers treat it as 'nothing written here')."""
+    processes return None (callers treat it as 'nothing written here').
+
+    Imports orbax here, before round 0: an emergency save under a
+    preemption notice has no 35 s to spare for it."""
+    ckpt.load_orbax()
     lock = threading.Lock()
 
     # graftlint: drain-point — checkpoint writes ARE sanctioned blocking

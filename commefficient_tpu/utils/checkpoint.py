@@ -29,13 +29,15 @@ import json
 import os
 import shutil
 import sys
+import threading
+import time
 from typing import Any
 
 import numpy as np
 
 import jax
-import orbax.checkpoint as ocp
 
+from ..obs import registry as obreg
 from ..resilience import retry as rtry
 
 MANIFEST = "manifest.json"
@@ -47,6 +49,32 @@ _TMP_PREFIX = ".tmp_round_"
 # post-mortem — bounded by _gc_damaged (newest KEEP_DAMAGED survive).
 _DAMAGED_SUFFIX = ".damaged"
 KEEP_DAMAGED = 2
+
+
+# orbax and the cloud stack it pulls in cost 35-37 s of a process start on a
+# TPU host, and a run without a checkpoint directory never writes or reads a
+# checkpoint: the module is imported on first use, not with this one
+_OCP = None
+_OCP_LOCK = threading.Lock()
+
+
+def load_orbax():
+    """`orbax.checkpoint`, imported on the first call. What the import took
+    is the gauge `checkpoint_orbax_import_s` and one start-up line; a
+    process that never checkpoints never sets either. runner/loop.py's
+    make_save_ckpt calls this when it builds the save closure, so no
+    periodic, writer-thread or emergency save pays the import mid-run."""
+    global _OCP
+    with _OCP_LOCK:
+        if _OCP is None:
+            t0 = time.perf_counter()
+            import orbax.checkpoint as ocp
+
+            took = time.perf_counter() - t0
+            obreg.default().gauge("checkpoint_orbax_import_s").set(took)
+            print(f"checkpoints: orbax loaded in {took:.1f} s", flush=True)
+            _OCP = ocp
+    return _OCP
 
 
 def _round_dirs(ckpt_dir: str) -> list[str]:
@@ -191,7 +219,7 @@ def save(ckpt_dir: str, session, keep: int = 3, fault_plan=None,
             fault_plan.fire_transient("ckpt_fail", rnd)
         if os.path.isdir(staging):
             shutil.rmtree(staging)
-        ocp.PyTreeCheckpointer().save(staging, payload, force=True)
+        load_orbax().PyTreeCheckpointer().save(staging, payload, force=True)
         # host-side sampling RNG, so resumed runs replay the same client
         # sequence
         np.save(os.path.join(staging, "host_rng.npy"),
@@ -272,7 +300,7 @@ def latest(ckpt_dir: str) -> str | None:
 
 
 def restore(path: str, session) -> None:
-    ckpt = ocp.PyTreeCheckpointer()
+    ckpt = load_orbax().PyTreeCheckpointer()
     template: dict[str, Any] = {
         "state": jax.device_get(session.state),
         "round": 0,

@@ -225,3 +225,97 @@ def test_cifar100_build_path_round(small_session, tmp_path):
     s, _ = cv_train.build(args)
     m = s.run_round(0.05)
     assert np.isfinite(m["loss_sum"]) and m["count"] > 0
+
+
+# ---------------------------------------- orbax is imported on first use
+
+_NO_ORBAX_PROBE = """
+import json, sys
+for name in sys.argv[1:]:
+    __import__(name)
+from commefficient_tpu.obs import registry
+print(json.dumps({
+    "orbax": sorted(m for m in sys.modules if m.startswith("orbax")),
+    "cloud_logging": sorted(m for m in sys.modules
+                            if m.startswith("google.cloud.logging")),
+    "gauge": "checkpoint_orbax_import_s" in registry.default().snapshot(),
+}))
+"""
+
+
+@pytest.mark.parametrize("modules", [
+    ("commefficient_tpu.runner", "commefficient_tpu.utils.checkpoint",
+     "commefficient_tpu.federated.api"),
+    ("cv_train", "gpt2_train"),
+], ids=["run_loop", "trainers"])
+def test_import_loads_no_orbax(modules):
+    """Importing the run loop, the checkpoint module or the trainers loads no
+    orbax (nor the cloud logging stack it pulls in): a run that never
+    checkpoints never pays that import, 35-37 s of a process start on a TPU
+    host. Fresh process, so no earlier test's import can hide one."""
+    import json
+    import subprocess
+    import sys
+
+    from conftest import hermetic_subprocess_env, repo_root
+
+    out = subprocess.run(
+        [sys.executable, "-c", _NO_ORBAX_PROBE, *modules],
+        capture_output=True, text=True, timeout=120,
+        env=hermetic_subprocess_env(), cwd=repo_root(),
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    seen = json.loads(out.stdout.strip().splitlines()[-1])
+    assert seen == {"orbax": [], "cloud_logging": [], "gauge": False}, seen
+
+
+def test_make_save_ckpt_loads_orbax_before_round_0(small_session, tmp_path,
+                                                   monkeypatch, capsys):
+    """Building the save closure imports orbax and records what that took,
+    so no save (periodic, writer-thread, emergency) pays the import mid-run;
+    a save and restore_latest through the loader reproduce the state."""
+    import sys
+
+    import jax
+
+    from commefficient_tpu.obs import registry
+    from commefficient_tpu.runner.loop import make_save_ckpt
+
+    monkeypatch.setattr(ckpt, "_OCP", None)  # as in a fresh process
+    s, _ = cv_train.build(_args(tmp_path))
+    capsys.readouterr()
+    save = make_save_ckpt(s, str(tmp_path / "ck"))
+    assert "orbax.checkpoint" in sys.modules
+    assert ckpt._OCP is sys.modules["orbax.checkpoint"]
+    assert registry.default().gauge("checkpoint_orbax_import_s").value > 0
+    assert "checkpoints: orbax loaded in" in capsys.readouterr().out
+
+    for _ in range(2):
+        s.run_round(0.05)
+    path = save()
+    assert ckpt.verify(path) is True
+    s2, _ = cv_train.build(_args(tmp_path))
+    assert ckpt.restore_latest(str(tmp_path / "ck"), s2) == path
+    assert s2.round == s.round == 2
+    for a, b in zip(jax.tree.leaves(s.state), jax.tree.leaves(s2.state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # loaded once: the restore printed no second start-up line
+    assert "orbax loaded" not in capsys.readouterr().out
+
+
+def test_trainer_with_checkpoint_dir_prints_orbax_line(small_session, tmp_path,
+                                                       monkeypatch, capsys):
+    """cv_train --checkpoint_dir loads orbax in set-up, before the first
+    round's row, and says what it took on one start-up line."""
+    monkeypatch.setattr(ckpt, "_OCP", None)
+    ck = str(tmp_path / "ck")
+    argv = ["--dataset", "cifar10", "--mode", "sketch", "--num_clients", "8",
+            "--num_workers", "2", "--local_batch_size", "4", "--k", "100",
+            "--num_cols", "2000", "--num_rows", "3", "--lr_scale", "0.05",
+            "--data_root", "/nonexistent", "--num_rounds", "2",
+            "--checkpoint_dir", ck, "--checkpoint_every", "1"]
+    cv_train.main(argv)
+    out = capsys.readouterr().out
+    assert out.count("checkpoints: orbax loaded in") == 1, out[-2000:]
+    assert out.index("orbax loaded") < out.index("train_loss")  # the header
+    assert ckpt.latest(ck).endswith("round_00000002")
