@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import moe
+from ..ops.attention import causal_attention
 from .qwen3_next import _weight, rotary
 
 # the blocks this model names with `jax.named_scope` (obs/profiler.BLOCK_SCOPES
@@ -137,18 +138,15 @@ class LatentAttention(nn.Module):
         q_rope = rotary(q[..., nope:], cfg.rope_theta, rope)
         k_rope = rotary(kv_a[..., None, rkv:], cfg.rope_theta, rope)[:, :, 0]
 
-        # q . [k_nope | k_rope] as two products, so that the shared head is
-        # never copied H times; unfused T x T scores, recomputed in the
-        # backward pass (two copies of them are kept otherwise)
-        @jax.checkpoint
-        def attend(q_nope, q_rope, k_nope, k_rope, v):
-            att = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
-                   + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope)) * (nope + rope) ** -0.5
-            causal = jnp.tril(jnp.ones((T, T), bool))
-            att = jax.nn.softmax(jnp.where(causal, att, jnp.finfo(att.dtype).min), axis=-1)
-            return jnp.einsum("bhqk,bkhd->bqhd", att, v)
-
-        y = attend(q[..., :nope], q_rope, kv[..., :nope], k_rope, kv[..., nope:])
+        # every query head over its own key/value head (n = 1) of
+        # [k_nope | k_rope], the one rotary key head broadcast beside each
+        # head's latent keys: ops/attention.causal_attention, the Pallas
+        # kernel pair on a TPU (nothing of T x T in HBM) and the T x T jnp
+        # form, recomputed in the backward pass, elsewhere
+        q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)[:, :, :, None]
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+            k_rope[:, :, None], (B, T, H, rope))], axis=-1)
+        y = causal_attention(q, k, kv[..., nope:], scale=(nope + rope) ** -0.5)
         return y.reshape(B, T, H * dv) @ _weight(self, "o_proj", (H * dv, C))
 
 

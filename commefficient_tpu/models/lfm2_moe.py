@@ -34,8 +34,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.attention import causal_attention
 from .glm4_moe_lite import biased_experts, rms_norm, swiglu
-from .qwen3_next import _weight, grouped_causal_attention, rotary
+from .qwen3_next import _weight, rotary
 
 # the blocks this model names with `jax.named_scope` (obs/profiler.BLOCK_SCOPES
 # holds every model's)
@@ -159,7 +160,7 @@ class GroupedQueryAttention(nn.Module):
         k = rms_norm(k, _weight(self, "k_norm", (hd,), ones), cfg.norm_eps)
         q = rotary(q, cfg.rope_theta, hd).reshape(B, T, KV, H // KV, hd)
         k = rotary(k, cfg.rope_theta, hd)
-        y = grouped_causal_attention(q, k, v).reshape(B, T, H * hd)
+        y = causal_attention(q, k, v, scale=hd ** -0.5).reshape(B, T, H * hd)
         return y @ _weight(self, "o_proj", (H * hd, C))
 
 

@@ -42,8 +42,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import ssd
+from ..ops.attention import causal_attention
 from .glm4_moe_lite import biased_experts, rms_norm
-from .qwen3_next import _weight, grouped_causal_attention
+from .qwen3_next import _weight
 
 # the blocks this model names with `jax.named_scope` (obs/profiler.BLOCK_SCOPES
 # holds every model's)
@@ -186,7 +187,7 @@ class Attention(nn.Module):
         q = (x @ _weight(self, "q_proj", (C, H * hd))).reshape(B, T, KV, H // KV, hd)
         k = (x @ _weight(self, "k_proj", (C, KV * hd))).reshape(B, T, KV, hd)
         v = (x @ _weight(self, "v_proj", (C, KV * hd))).reshape(B, T, KV, hd)
-        y = grouped_causal_attention(q, k, v).reshape(B, T, H * hd)
+        y = causal_attention(q, k, v, scale=hd ** -0.5).reshape(B, T, H * hd)
         return y @ _weight(self, "o_proj", (H * hd, C))
 
 

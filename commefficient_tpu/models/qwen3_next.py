@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import gated_delta, moe
+from ..ops.attention import causal_attention
 
 # the blocks this model names with `jax.named_scope` (obs/profiler.BLOCK_SCOPES
 # holds every model's)
@@ -171,18 +172,6 @@ class GatedDeltaNet(nn.Module):
         return o.reshape(B, T, value_dim) @ w_out
 
 
-@jax.checkpoint
-def grouped_causal_attention(q, k, v):
-    """Causal softmax attention of q [B, T, KV, n, hd] over k, v [B, T, KV, hd]:
-    each key/value head serves its n query heads. Unfused T x T scores,
-    recomputed in the backward pass (two copies of them are kept otherwise)."""
-    T, hd = q.shape[1], q.shape[-1]
-    att = jnp.einsum("bqgnd,bkgd->bgnqk", q, k) * hd ** -0.5
-    causal = jnp.tril(jnp.ones((T, T), bool))
-    att = jax.nn.softmax(jnp.where(causal, att, jnp.finfo(att.dtype).min), axis=-1)
-    return jnp.einsum("bgnqk,bkgd->bqgnd", att, v)
-
-
 class GatedAttention(nn.Module):
     cfg: Qwen3NextConfig
 
@@ -201,7 +190,7 @@ class GatedAttention(nn.Module):
         q = rotary(q, cfg.rope_theta, cfg.rotary_dim).reshape(B, T, KV, H // KV, hd)
         k = rotary(k, cfg.rope_theta, cfg.rotary_dim)
 
-        y = grouped_causal_attention(q, k, v).reshape(B, T, H, hd)
+        y = causal_attention(q, k, v, scale=hd ** -0.5).reshape(B, T, H, hd)
         y = (y * jax.nn.sigmoid(gate)).reshape(B, T, H * hd)
         return y @ _weight(self, "o_proj", (H * hd, C))
 

@@ -101,8 +101,10 @@ def test_one_rotary_key_head_serves_all_query_heads(block):
 
 
 def test_scores_are_recomputed_in_the_backward_pass(block):
-    """The T x T scores sit under jax.checkpoint: the gradient's jaxpr holds a
-    remat of the attention, and the gradient equals that of the plain
+    """On the jnp path (this backend's: ops/attention.causal_attention takes
+    the Pallas kernel pair on a TPU only) the T x T scores sit under
+    jax.checkpoint: the gradient's jaxpr holds a remat of the attention, and
+    the gradient equals that of the plain
     reference's block (full T x T softmax, the key head broadcast and
     concatenated, nothing recomputed)."""
     from benchmark.reference import glm4_moe_lite as ref

@@ -15,6 +15,8 @@ this file is the one that loads it), and the persistent compile cache is off
 around the compiles (a described-topology executable cannot be read back).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from commefficient_tpu.federated import engine
 from commefficient_tpu.modes.config import ModeConfig
+from commefficient_tpu.ops import attention
 from commefficient_tpu.parallel import mesh as meshlib
 from commefficient_tpu.sketch import CSVecSpec
 from commefficient_tpu.sketch import pallas_kernels as pk
@@ -241,13 +244,15 @@ def _glm_block_shapes(module, x, one_chip):
     return on({k: v for k, v in variables.items() if k in ("params", "buffers")}), on(x)
 
 
-def test_latent_attention_compiles_for_v5e_at_the_published_widths(one_chip):
+def test_latent_attention_compiles_for_v5e_at_the_published_widths(one_chip, monkeypatch):
     """One client's sequence of 2,048 tokens through the latent-attention
     block (latents 768 and 512, 20 heads of 192 + 64 and 256), forward and
-    backward: the T x T scores of 20 heads are 336 MB a copy, and with them
-    recomputed the gradient keeps under four copies alive."""
+    backward: the T x T scores of 20 heads would be 336 MB a copy, and with
+    the attention kernel pair (steered to it: this backend is the CPU) the
+    gradient's temporaries hold less than one copy."""
     from commefficient_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig, LatentAttention
 
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     cfg = Glm4MoeLiteConfig()
     block = LatentAttention(cfg)
     variables, x = _glm_block_shapes(block, jax.ShapeDtypeStruct((1, 2048, 2048), jnp.float32),
@@ -256,7 +261,8 @@ def test_latent_attention_compiles_for_v5e_at_the_published_widths(one_chip):
     grad = jax.grad(lambda v, x: block.apply(v, x).sum(), argnums=(0, 1))
     compiled = jax.jit(grad).lower(variables, x).compile()
     scores = 20 * 2048 * 2048 * 4
-    assert compiled.memory_analysis().temp_size_in_bytes < 4 * scores
+    assert compiled.memory_analysis().temp_size_in_bytes < scores
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
 def test_glm_expert_layer_compiles_to_the_ragged_dot_kernel_under_the_sigmoid_rule(one_chip):
@@ -291,3 +297,42 @@ def test_glm_expert_layer_compiles_to_the_ragged_dot_kernel_under_the_sigmoid_ru
     router = [line for line in text.splitlines()
               if "operand_precision={highest,highest}" in line and " f32[2048,64]" in line]
     assert len(router) == 1 and "moe_route" in router[0], router
+
+
+# --- the attention blocks of the four expert cells, on the kernel pair ---
+
+# a T x T array: four dimensions or more, the last two T (the activations
+# [1, T, 2048] of a hidden width of 2,048 have three)
+SQUARE = re.compile(r"\[(\d+,){2,}2048,2048\]")
+
+
+def _attention_block(family):
+    from commefficient_tpu.models import glm4_moe_lite, lfm2_moe, nemotron_h, qwen3_next
+
+    if family == "glm4_moe_lite":
+        return glm4_moe_lite.LatentAttention(glm4_moe_lite.Glm4MoeLiteConfig()), 2048
+    if family == "lfm2_moe":
+        return lfm2_moe.GroupedQueryAttention(lfm2_moe.Lfm2MoeConfig()), 2048
+    if family == "nemotron_h":
+        return nemotron_h.Attention(nemotron_h.NemotronHConfig()), 2688
+    return qwen3_next.GatedAttention(qwen3_next.Qwen3NextConfig()), 2048
+
+
+@pytest.mark.parametrize("family", ["glm4_moe_lite", "lfm2_moe", "nemotron_h", "qwen3_next"])
+def test_attention_block_compiles_to_the_kernel_pair_at_its_cells_shapes(
+        one_chip, monkeypatch, family):
+    """One client's 2,048 tokens through the cell's attention block at the
+    published widths, forward and backward, under the engine's vmap over
+    clients: the compiled text holds the kernels' custom calls and no T x T
+    array (this backend is the CPU, so the choice is steered to the TPU's)."""
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    block, hidden = _attention_block(family)
+    variables, x = _glm_block_shapes(block, jax.ShapeDtypeStruct((1, 2048, hidden), jnp.float32),
+                                     one_chip)
+
+    def client_grad(x, variables):
+        return jax.grad(lambda v, x: block.apply(v, x[None]).sum(), argnums=(0, 1))(variables, x)
+
+    text = jax.jit(jax.vmap(client_grad, in_axes=(0, None))).lower(x, variables).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert not SQUARE.search(text), SQUARE.search(text).group(0)
