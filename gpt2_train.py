@@ -144,6 +144,11 @@ def build(args, fault_plan=None, retry_policy=None):
     named = type(model).__name__ if args.model_config else f"GPT2({args.model_size})"
     print(f"model: {named}  d={d:,}  vocab={cfg.vocab_size}  "
           f"clients={train_set.num_clients}  mode={args.mode}{init_note}", flush=True)
+    if args.model_config and hasattr(cfg, "gdn_chunk"):
+        # Qwen3-Next's Gated DeltaNet mixers: what inverts their chunk matrices
+        from commefficient_tpu.ops.gated_delta import inverse_implementation
+
+        print(f"delta-rule inverse: {inverse_implementation(cfg.gdn_chunk)}", flush=True)
 
     if args.attn_impl == "ring" and args.seq_parallel <= 1:
         raise SystemExit(

@@ -206,6 +206,7 @@ def test_gpt2_train_builds_the_model_from_a_configuration_file(tmp_path, capsys)
     path.write_text(json.dumps({"model": block}))
     reg = obreg.default()
     before = reg.counter("model_moe_assignments_total").value
+    kernels = reg.counter("model_delta_inverse_kernel_total").value
     session = gpt2_train.main([
         "--model_config", str(path), "--num_clients", "8", "--num_workers", "4",
         "--client_chunk", "2", "--num_rounds", "2", "--eval_every", "2", "--mode", "uncompressed",
@@ -213,6 +214,9 @@ def test_gpt2_train_builds_the_model_from_a_configuration_file(tmp_path, capsys)
         "--local_batch_size", "1", "--lr_scale", "0.05", "--data_root", "/nonexistent"])
     out = capsys.readouterr().out
     assert "model: Qwen3Next" in out and session.round == 2
+    # the CPU inverts the delta rule's chunks by the triangular solve
+    assert "delta-rule inverse: solve" in out
+    assert reg.counter("model_delta_inverse_kernel_total").value == kernels
     counted = reg.counter("model_moe_assignments_total").value - before
     per_token = TWO.num_hidden_layers * TWO.num_experts_per_tok
     assert counted >= 2 * 4 * 24 * per_token and counted % (24 * per_token) == 0

@@ -233,6 +233,28 @@ def test_chunked_delta_rule_compiles_for_v5e_at_the_published_heads(one_chip):
     assert need < 2 * 1024 ** 3, need
 
 
+def test_chunked_delta_rule_compiles_for_v5e_with_the_inverse_kernel(one_chip, monkeypatch):
+    """The same gradient with the chunks inverted by the Pallas kernel (steered
+    to it: this backend is the CPU): one kernel call, no triangular solve left,
+    and the temporaries under the same 2 GB."""
+    import re
+
+    from commefficient_tpu.ops import gated_delta
+
+    monkeypatch.setattr(gated_delta, "_on_tpu", lambda: True)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    q = f32(1, 2048, 32, 128)
+    grad = jax.grad(lambda *a: gated_delta.chunk_gated_delta_rule(*a).sum(), argnums=(0, 1, 2, 3, 4))
+    compiled = jax.jit(grad).lower(q, q, q, f32(1, 2048, 32), f32(1, 2048, 32)).compile()
+    text = compiled.as_text()
+    # the TPU's solve is a custom call that inverts the diagonal blocks
+    assert not re.findall(r"triangular-solve\(|TriangularSolve|InvertDiagBlocks", text)
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1, calls
+    need = compiled.memory_analysis().temp_size_in_bytes
+    assert need < 2 * 1024 ** 3, need
+
+
 # --- GLM-4.7-Flash's two blocks at the published widths (PR 31) ---
 
 
