@@ -251,8 +251,9 @@ def _accumulate(
 ) -> jnp.ndarray:
     """Scatter (idx, vals) masked by `valid` into a fresh [r, c] table.
 
-    Single scatter path shared by dense-block and sparse sketching, so the two
-    can never diverge (and a future Pallas kernel swaps in at one place)."""
+    The scatter shared by dense-block and sparse sketching, so the two can
+    never diverge. The masking tail (`mask_transmitted`) scatters by rows
+    of its own, pinned bit for bit against this one in tests/test_csvec.py."""
     buckets, signs = _block_hashes(spec, idx, vals.dtype)
     contrib = signs * (vals * valid.astype(vals.dtype))[None, :]  # [r, n]
     return jax.vmap(
@@ -316,28 +317,83 @@ def query(spec: CSVecSpec, table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return jnp.sort(per_row, axis=0)[(spec.r - 1) // 2]
 
 
+# a lane tile: a v5e gathers whole rows of 128 lanes at 1.4 ns a row, and
+# scalars at 4.6-6.6 ns each (PERF.md section 6)
+_LANES = 128
+
+
 def mask_transmitted(
     spec: CSVecSpec, V: jnp.ndarray, E: jnp.ndarray,
     idx: jnp.ndarray, vals: jnp.ndarray,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """FetchSGD's sketch-space masking tail in one call: E -= sketch(vals at
-    idx); V -= sketch(query(V, idx) at idx). Bit-identical to the unfused
-    two-`sketch_sparse`-plus-`query` sequence (same clipped-index hashing as
-    sketch_sparse; invalid idx < 0 / >= d entries contribute exactly 0 to
-    both scatters, as before — the query value at an invalid index was
+    idx); V -= sketch(query(V, idx) at idx), by 128-lane rows, for any c.
+    Bit-identical to the unfused two-`sketch_sparse`-plus-`query` sequence
+    wherever V is finite: one clipped-index hash evaluation serves all
+    three, and invalid idx < 0 / >= d entries contribute a zero to both
+    scatters, as sketch_sparse's do (the query value at an invalid index is
     unused garbage in the unfused form too). Pinned in tests/test_csvec.py.
 
-    Note on cost: expressing this as one call changes nothing measured —
-    inside one jitted program XLA already CSE's the three ops' identical
-    (r, k) hash evaluations (the isolated algebra cost is the
-    scatter/gather/sort itself, the round's `server_algebra`
-    phase). So this is a plain composition of the shared
-    primitives, preserving _accumulate's single-scatter-path invariant;
-    the value is the single call site and the documented semantics."""
-    E = E - sketch_sparse(spec, idx, vals)
-    vvals = query(spec, V, idx)
-    V = V - sketch_sparse(spec, idx, vvals)
-    return V, E
+    The chip pays a scatter by the update, 8-10 ns each whether it writes a
+    scalar or a row of 128 lanes, and a gather of rows 1.4 ns a row against
+    4.6-6.6 a scalar (v5e; PERF.md section 6). So, with the tables viewed
+    flat (position j * c + bucket):
+
+    - V is queried by rows (`_query_rows`);
+    - V's and E's contributions at one position travel in ONE update row of
+      a [ceil(r * c / 64), 128] zero table: V's in lanes 0-63, E's in lanes
+      64-127, -0.0 in the other 126. One scatter of r * k rows in place of
+      two of r * k scalars;
+    - the contributions are sorted once by position, stably, so the
+      scatter is told its indices are sorted.
+
+    Each table entry still receives its contributions in `segment_sum`'s
+    order (the stable sort keeps the k order; -0.0 leaves every partial
+    sum as it was), into zero, and the sum is then subtracted, which
+    rounds as `E - sketch_sparse` does."""
+    half = _LANES // 2
+    n = spec.r * spec.c
+    valid = ((idx >= 0) & (idx < spec.d)).astype(V.dtype)
+    buckets, signs = _block_hashes(spec, jnp.clip(idx, 0, spec.d - 1), V.dtype)
+    pos = jnp.arange(spec.r, dtype=jnp.int32)[:, None] * spec.c + buckets
+    vvals = _query_rows(spec, V, pos, signs)
+    pos, cV, cE = jax.lax.sort(
+        (pos.reshape(-1), (signs * (vvals * valid)[None, :]).reshape(-1),
+         (signs * (vals * valid)[None, :]).reshape(-1)),
+        num_keys=1, is_stable=True)
+    lane = jax.lax.iota(jnp.int32, _LANES)[None, :]
+    at = (pos % half)[:, None]
+    rows = jnp.where(lane == at, cV[:, None],
+                     jnp.where(lane == at + half, cE[:, None],
+                               jnp.array(-0.0, V.dtype)))
+    T = jnp.zeros((-(-n // half), _LANES), V.dtype).at[pos // half].add(
+        rows, indices_are_sorted=True, mode="promise_in_bounds")
+
+    def table(t):  # back to [r, c], less the padding where c needs one
+        return t.reshape(-1)[:n].reshape(spec.table_shape)
+
+    return V - table(T[:, :half]), E - table(T[:, half:])
+
+
+def _query_rows(spec: CSVecSpec, V: jnp.ndarray, pos: jnp.ndarray,
+                signs: jnp.ndarray) -> jnp.ndarray:
+    """`query` at flat table positions pos[r, k] (j * c + bucket) by rows:
+    gather row pos // 128 of V viewed as rows of 128 lanes and pick lane
+    pos % 128 by a select with -0.0 elsewhere, summed from -0.0. x + (-0.0)
+    == x for every x, so the pick is exact in any order of the adds. One
+    table row j at a time (lax.map), so k rows of 128 lanes are in flight
+    and not r * k."""
+    neg0 = jnp.array(-0.0, V.dtype)
+    n = spec.r * spec.c  # V flat, padded to whole rows where c needs it
+    table = jnp.pad(V.reshape(-1), (0, -n % _LANES)).reshape(-1, _LANES)
+    lane = jax.lax.iota(jnp.int32, _LANES)[None, :]
+
+    def pick(p):
+        rows = jnp.where(lane == (p % _LANES)[:, None], table[p // _LANES], neg0)
+        return jax.lax.reduce(rows, neg0, jax.lax.add, (1,))
+
+    per_row = signs * jax.lax.map(pick, pos)
+    return jnp.sort(per_row, axis=0)[(spec.r - 1) // 2]
 
 
 def merge_tables(spec: CSVecSpec, tables: jnp.ndarray) -> jnp.ndarray:
@@ -402,7 +458,6 @@ TOPK_OVERSAMPLE = 4
 TOPK_SELECT_MIN_N = 350_000
 TOPK_SELECT_MIN_N_PER_K = 7
 
-_LANES = 128  # a row of the selection's two-level prefix: one lane tile
 # value bits a counting pass settles (2^bits - 1 counts a pass): on a v5e at
 # n = 6,573,130 the whole selection takes 0.79 / 0.69 / 0.76 / 0.88 ms at
 # 1 / 2 / 3 / 4 bits (a pass is 11 us at 2 bits once x sits in VMEM, 52 us at

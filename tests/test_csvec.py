@@ -269,28 +269,49 @@ def test_unsketch_single_shot_matches_chunked_scan(monkeypatch):
             rtol=1e-6)
 
 
-def test_mask_transmitted_matches_unfused():
-    """The fused masking tail (one hash evaluation) must be BIT-IDENTICAL to
-    the unfused sequence E -= sketch_sparse(vals); vvals = query(V);
-    V -= sketch_sparse(vvals) — including idx = -1 padding entries, whose
-    contribution is exactly zero on both paths."""
-    for family in ("rotation", "random"):
-        spec = CSVecSpec(d=4096, c=512, r=5, seed=9, family=family)
-        rng = np.random.RandomState(2)
-        V = jnp.asarray(rng.randn(spec.r, spec.c).astype(np.float32))
-        E = jnp.asarray(rng.randn(spec.r, spec.c).astype(np.float32))
-        idx = jnp.asarray(
-            np.concatenate([rng.choice(spec.d, 30, replace=False),
-                            [-1, -1]]).astype(np.int32))
-        vals = jnp.asarray(rng.randn(32).astype(np.float32))
+# (d, c, k): d = 100,000 / 20,000 at c = 1,024 and k = 5,000 collide in most
+# buckets; r * c = 2,500 is no whole number of 64- or 128-lane rows (both
+# flat views padded), 2,880 of 128-lane rows only (V's view padded)
+_MASK_CASES = {
+    "c512": (4096, 512, 30),
+    "c1024_collisions": (100_000, 1024, 5000),
+    "c1024_dense_collisions": (20_000, 1024, 5000),
+    "c500_padded": (5000, 500, 300),
+    "c576_padded_query": (5000, 576, 300),
+}
 
-        E_ref = E - sketch_sparse(spec, idx, vals)
-        vvals = query(spec, V, idx)
-        V_ref = V - sketch_sparse(spec, idx, vvals)
 
-        V_f, E_f = csvec_mod.mask_transmitted(spec, V, E, idx, vals)
-        np.testing.assert_array_equal(np.asarray(V_ref), np.asarray(V_f), err_msg=family)
-        np.testing.assert_array_equal(np.asarray(E_ref), np.asarray(E_f), err_msg=family)
+@pytest.mark.parametrize("family", ["rotation", "random"])
+@pytest.mark.parametrize("case", list(_MASK_CASES))
+def test_mask_transmitted_matches_unfused(case, family):
+    """The masking tail by rows must be BIT-IDENTICAL to the unfused
+    sequence E -= sketch_sparse(vals); vvals = query(V);
+    V -= sketch_sparse(vvals), at every c: including idx = -1 padding and an
+    idx >= d entry, whose contributions are zeros on both paths, +-0.0
+    among the values and -0.0 in V (the pick of a lane adds -0.0 to it)."""
+    d, c, k = _MASK_CASES[case]
+    spec = CSVecSpec(d=d, c=c, r=5, seed=9, family=family)
+    rng = np.random.RandomState(2)
+    V = rng.randn(spec.r, spec.c).astype(np.float32)
+    V[0, :7] = -0.0
+    V[1, 3:9] = 0.0
+    E = rng.randn(spec.r, spec.c).astype(np.float32)
+    idx = np.concatenate([rng.choice(d, k - 3, replace=False),
+                          [-1, d + 5, -1]]).astype(np.int32)
+    vals = rng.randn(k).astype(np.float32)
+    vals[:5] = 0.0
+    vals[5:10] = -0.0
+    V, E, idx, vals = map(jnp.asarray, (V, E, idx, vals))
+
+    E_ref = E - sketch_sparse(spec, idx, vals)
+    vvals = query(spec, V, idx)
+    V_ref = V - sketch_sparse(spec, idx, vvals)
+
+    V_f, E_f = jax.jit(lambda *a: csvec_mod.mask_transmitted(spec, *a))(
+        V, E, idx, vals)
+    for ref, got in ((V_ref, V_f), (E_ref, E_f)):
+        np.testing.assert_array_equal(np.asarray(ref).view(np.int32),
+                                      np.asarray(got).view(np.int32))
 
 
 # --- exact top-k by threshold and compaction (select_topk_abs) -------------

@@ -73,6 +73,34 @@ def test_kernels_compile_for_v5e(one_chip, name):
     assert qry.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("d,c", [LAYOUTS["gpt2"][:2], (6_573_130, 500_000)],
+                         ids=["gpt2", "c500000"])
+def test_masking_tail_compiles_for_v5e_to_one_scatter_of_rows(one_chip, d, c):
+    """The masking tail after the top-k, at the language-model cells' c and
+    at the trainers' default c = 500,000 (r * c no whole number of rows):
+    V is read by a gather of 128-lane rows, and ONE scatter of rows writes
+    both tables' updates (a scatter costs the chip by the update). At
+    k = 500, not the cells' 50,000: the shapes are the same, and the TPU
+    compiler takes 30-40 s of a CPU host for a sort of 25,000 positions or
+    more, 1-2 s for the 2,500 here."""
+    from commefficient_tpu.sketch import csvec
+
+    r, k = 5, 500
+    spec = CSVecSpec(d=d, c=c, r=r, seed=42, family="rotation")
+    t = jax.ShapeDtypeStruct((r, c), jnp.float32, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((k,), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((k,), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda *a: csvec.mask_transmitted(spec, *a)).lower(
+        t, t, idx, vals).compile().as_text()
+
+    def shapes(op):
+        return [s.split("{")[0] for s in re.findall(rf"= (\S+) {op}\(", text)
+                if s.startswith("f32")]
+
+    assert shapes("scatter") == [f"f32[{-(-r * c // 64)},128]"]
+    assert shapes("gather") == [f"f32[{k},128]"]
+
+
 def _mlp_loss(params, net_state, batch, rng):
     h = jnp.tanh(batch["x"] @ params["w1"] + params["b1"])
     logp = jax.nn.log_softmax(h @ params["w2"] + params["b2"])
