@@ -250,7 +250,7 @@ _BLOCKING_CALLS = {
 # the package-level call graph from these roots (serve_round/submit are the
 # serving layer's dispatch-path entries)
 _ROOT_NAMES = {"run_loop", "next", "prepare_round", "dispatch_round",
-               "dispatch_block", "serve_round", "submit"}
+               "serve_round", "submit"}
 
 # cross-module traversal bound: hops of `from .helper import fn` / `mod.fn()`
 # indirection followed before giving up (a sleep buried deeper than this

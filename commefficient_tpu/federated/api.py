@@ -76,33 +76,37 @@ class PreparedRound:
 
 @dataclasses.dataclass
 class InFlightRound:
-    """A dispatched-but-uncommitted round (or fused block of rounds): the
-    device-side result futures plus everything commit_round needs to publish
-    it. `metrics` stays a DEVICE tree until commit, so the runner can defer
-    the host sync to an eval/log boundary instead of blocking every
-    dispatch."""
+    """A dispatched-but-uncommitted round: the device-side result futures
+    plus everything commit_round needs to publish it. `metrics` stays a
+    DEVICE tree until commit, so the runner can defer the host sync to an
+    eval/log boundary instead of blocking every dispatch."""
 
     new_state: Any
     new_client_state: Any
     metrics: Any
-    lrs: list
+    lr: float
     snapshot: tuple
-    stacked: bool  # block dispatch: metrics leaves carry a leading [K] axis
-    # per-round host-side degradation counters (aligned with lrs) + the
-    # newest prep's re-queue snapshot, published at commit
-    masked: list = dataclasses.field(default_factory=list)
-    requeue_depths: list = dataclasses.field(default_factory=list)
+    # host-side degradation counters + the prep's re-queue snapshot,
+    # published at commit
+    masked: int = 0
+    requeue_depth: int = 0
     requeue: tuple = ()
     requeue_ages: tuple = ()
-    # round-ledger / health bookkeeping (aligned with lrs): each round's
-    # invited cohort ids and whether its health cadence was armed — the
-    # host-side context commit hands to the obs sinks (ledger, monitor)
-    cohorts: list = dataclasses.field(default_factory=list)
-    health_on: list = dataclasses.field(default_factory=list)
+    # round-ledger / health bookkeeping: the round's invited cohort ids and
+    # whether its health cadence was armed — the host-side context commit
+    # hands to the obs sinks (ledger, monitor)
+    cohort: Any = None
+    health_on: bool = False
 
-    @property
-    def num_rounds(self) -> int:
-        return len(self.lrs)
+    @classmethod
+    def of(cls, prep: PreparedRound, lr: float, new_state, new_client_state,
+           metrics) -> "InFlightRound":
+        """The dispatch of `prep` at `lr`, with the prep's host-side
+        bookkeeping carried to commit."""
+        return cls(new_state, new_client_state, metrics, lr, prep.snapshot,
+                   masked=prep.masked, requeue_depth=prep.requeue_depth,
+                   requeue=prep.requeue, requeue_ages=prep.requeue_ages,
+                   cohort=prep.ids, health_on=prep.health_on)
 
     def release_state(self):
         """Drop the server-state references. The runner calls this once two
@@ -400,18 +404,14 @@ class FederatedSession:
         # uncommitted dispatches threads device-side while emergency
         # checkpoints keep reading a consistent committed view. Main-thread
         # only — dispatch and commit both run on the caller's thread.
-        # _inflight counts dispatch UNITS (a fused block is one);
-        # _inflight_rounds counts ROUNDS (a block is len(lrs)).
+        # _inflight counts the dispatched-uncommitted rounds.
         self._inflight = 0
-        self._inflight_rounds = 0
         self._head_state = None
         self._head_client_state = None
 
         self.state = engine.init_server_state(self.cfg, params, net_state)
         self.client_state = modes.init_client_state(mode_cfg, train_set.num_clients)
 
-        self._train_loss_fn = train_loss_fn
-        self._multi = None  # lazy: jitted by the first run_rounds block
         self._payload_client = None
         self._payload_merge = None
         self._payload_merge_stale = None
@@ -668,7 +668,7 @@ class FederatedSession:
         only when the round COMMITS, so an emergency checkpoint taken while
         later rounds are already prepared still resumes bit-identically."""
         if rnd is None:
-            rnd = self.round + self._inflight_rounds
+            rnd = self.round + self._inflight
         return self._assemble_round(rnd, self.sample_cohort(rnd))
 
     def prepare_served_round(self, rnd: int, ids,
@@ -997,15 +997,7 @@ class FederatedSession:
                 noise_rng, lnorms, *extra, **kw)
         self._head_state = new_state
         self._inflight += 1
-        self._inflight_rounds += 1
-        return InFlightRound(new_state, None, metrics, [lr],
-                             prep.snapshot, stacked=False,
-                             masked=[prep.masked],
-                             requeue_depths=[prep.requeue_depth],
-                             requeue=prep.requeue,
-                             requeue_ages=prep.requeue_ages,
-                             cohorts=[prep.ids],
-                             health_on=[prep.health_on])
+        return InFlightRound.of(prep, lr, new_state, None, metrics)
 
     def dispatch_round(self, prep: PreparedRound, lr: float) -> InFlightRound:
         """Enqueue one round on the device WITHOUT a host sync. Chains on the
@@ -1038,64 +1030,11 @@ class FederatedSession:
             self._head_client_state = new_cstate
         self._head_state = new_state
         self._inflight += 1
-        self._inflight_rounds += 1
-        return InFlightRound(new_state, new_cstate, metrics, [lr],
-                             prep.snapshot, stacked=False,
-                             masked=[prep.masked],
-                             requeue_depths=[prep.requeue_depth],
-                             requeue=prep.requeue,
-                             requeue_ages=prep.requeue_ages,
-                             cohorts=[prep.ids],
-                             health_on=[prep.health_on])
-
-    def dispatch_block(self, preps: list[PreparedRound], lrs) -> InFlightRound:
-        """Enqueue a K-round fused block (ONE device dispatch, lax.scan over
-        the round step) without a host sync. Stateless modes only — see
-        supports_block_dispatch."""
-        lrs = list(lrs)
-        if self._multi is None:
-            # make_multi_round_step routes to the SPMD sharded body itself
-            # when the cfg/mesh say so — blocks stay data-parallel
-            self._multi = jax.jit(
-                engine.make_multi_round_step(self._train_loss_fn, self.cfg,
-                                             self.mesh),
-                donate_argnums=self._state_donation(),
-            )
-        # stack on the HOST: jnp.stack would commit the full [K, W, ...]
-        # block to the default device before resharding — a K-round HBM
-        # spike on one chip, defeating the memory story this feature and
-        # client_chunk exist for. device transfer happens once, sharded.
-        stacked = jax.tree.map(
-            # prep batches are host numpy by construction (prepare_round
-            # assembles them on the host thread), so this asarray is host
-            # stacking, not a device sync
-            lambda *xs: np.stack([np.asarray(x) for x in xs]),  # graftlint: disable=G001
-            *[p.batch for p in preps],
-        )
-        if self.mesh is not None:
-            stacked = meshlib.shard_stacked_client_batch(self.mesh, stacked)
-        state = self._head_state if self._head_state is not None else self.state
-        with self._mesh_ctx(), obtrace.span(
-                "session", "launch", round_first=preps[0].rnd, rounds=len(lrs)):
-            new_state, ms = self._multi(
-                state, stacked, jnp.asarray(lrs, jnp.float32),
-                jnp.stack([p.sub for p in preps]),
-            )
-        self._head_state = new_state
-        self._inflight += 1
-        self._inflight_rounds += len(lrs)
-        return InFlightRound(new_state, None, ms, lrs,
-                             preps[-1].snapshot, stacked=True,
-                             masked=[p.masked for p in preps],
-                             requeue_depths=[p.requeue_depth for p in preps],
-                             requeue=preps[-1].requeue,
-                             requeue_ages=preps[-1].requeue_ages,
-                             cohorts=[p.ids for p in preps],
-                             health_on=[p.health_on for p in preps])
+        return InFlightRound.of(prep, lr, new_state, new_cstate, metrics)
 
     # graftlint: drain-point — commit IS the sanctioned per-round sync
     def commit_round(self, infl: InFlightRound, metrics_host=None) -> list[dict]:
-        """Publish one dispatched round/block: sync its metrics (unless the
+        """Publish one dispatched round: sync its metrics (unless the
         caller already fetched them), assign the state futures, run the
         host-side bookkeeping, and install the round-boundary RNG snapshot —
         all atomically w.r.t. a concurrent emergency checkpoint."""
@@ -1130,31 +1069,13 @@ class FederatedSession:
                           for k in [k for k in mh if k.startswith("health/")]}
                 fp = {k[len("ledger/"):]: mh.pop(k)
                       for k in [k for k in mh if k.startswith("ledger/")]}
-                if infl.stacked:
-                    for i, lr in enumerate(infl.lrs):
-                        m = self._finalize_metrics(
-                            {k: v[i] for k, v in mh.items()}, lr,
-                            masked=infl.masked[i],
-                            requeue_depth=infl.requeue_depths[i])
-                        out.append(m)
-                        obs_records.append((
-                            self.round - 1,
-                            infl.cohorts[i] if infl.cohorts else None, m,
-                            {k: v[i] for k, v in health.items()},
-                            {k: v[i] for k, v in fp.items()},
-                            infl.health_on[i] if infl.health_on else False))
-                else:
-                    m = self._finalize_metrics(
-                        mh, infl.lrs[0], masked=infl.masked[0],
-                        requeue_depth=infl.requeue_depths[0])
-                    out.append(m)
-                    obs_records.append((
-                        self.round - 1,
-                        infl.cohorts[0] if infl.cohorts else None, m,
-                        health, fp,
-                        infl.health_on[0] if infl.health_on else False))
+                m = self._finalize_metrics(
+                    mh, infl.lr, masked=infl.masked,
+                    requeue_depth=infl.requeue_depth)
+                out.append(m)
+                obs_records.append((self.round - 1, infl.cohort, m, health,
+                                    fp, infl.health_on))
                 self._inflight -= 1
-                self._inflight_rounds -= infl.num_rounds
             last = infls[-1]
             if last.new_state is None:
                 raise RuntimeError(
@@ -1210,7 +1131,7 @@ class FederatedSession:
 
     def _finalize_metrics(self, metrics_host: dict, lr: float,
                           masked: int = 0, requeue_depth: int = 0) -> dict:
-        """Host-side per-round bookkeeping shared by run_round/run_rounds:
+        """Host-side per-round bookkeeping of every commit:
         comm accounting (survivor-scaled uplink, measured local_topk
         down-link), cohort-degradation counters, cumulative totals, and the
         round counter."""
@@ -1248,36 +1169,6 @@ class FederatedSession:
         self.comm_mb_total += m["comm_total_mb"]
         self.round += 1
         return m
-
-    @property
-    def supports_block_dispatch(self) -> bool:
-        """Whether run_rounds can actually fuse a block into one dispatch:
-        per-client-state modes need the host gather/scatter between rounds.
-        An active fault plan also forces per-round dispatch: injection sites
-        are scheduled by round, which a K-round fused block cannot honor."""
-        return (self.client_state is None
-                and self.fault_plan is None
-                # table rounds (wire payloads / robust merge / adversarial
-                # chaos) are per-round by construction: the wire crossing —
-                # or its batch-simulated twin — is the round boundary
-                and not self._table_round)
-
-    # -- a block of rounds in one dispatch (SURVEY.md §7 hard part (d)) ------
-    def run_rounds(self, lrs) -> list[dict]:
-        """Run len(lrs) rounds with ONE device dispatch and ONE host sync —
-        a lax.scan over the round step (engine.make_multi_round_step):
-        blocks amortize the per-dispatch host work K-fold. Sampling and rng
-        streams are IDENTICAL
-        to sequential run_round calls (pinned by tests); per-client-state
-        modes fall back to per-round dispatch."""
-        lrs = list(lrs)
-        if not self.supports_block_dispatch or len(lrs) <= 1:
-            return [self.run_round(lr) for lr in lrs]
-        # same prepare path as run_round (identical host RNG order, same
-        # retry wrapper — a transient loader flake must not kill the block
-        # path long stateless runs actually take), then one fused dispatch
-        preps = [self.prepare_round(self.round + i) for i in range(len(lrs))]
-        return self.commit_round(self.dispatch_block(preps, lrs))
 
     # -- evaluation (SURVEY.md §3.4: forward-only, no compression) -----------
     # graftlint: drain-point — eval runs only at a drained boundary (checked
@@ -1339,27 +1230,6 @@ class FedModel:
     @property
     def params(self):
         return self.session.state["params"]
-
-
-def plan_block(
-    opt: "FedOptimizer", rnd: int, total_rounds: int, eval_every: int,
-    checkpoint_every: int, rounds_per_dispatch: int,
-) -> list[float]:
-    """Per-round lrs for the next dispatch block, truncated at the run end
-    and at any eval/checkpoint boundary so the logging/saving cadence is
-    block-size-invariant. Advances the optimizer schedule. Shared by both
-    training CLIs — the boundary arithmetic is subtle enough to live once."""
-    block = min(
-        max(rounds_per_dispatch, 1), total_rounds - rnd,
-        eval_every - rnd % eval_every,
-        *((checkpoint_every - rnd % checkpoint_every,)
-          if checkpoint_every else ()),
-    )
-    lrs = []
-    for _ in range(block):
-        lrs.append(opt.lr)
-        opt.step()
-    return lrs
 
 
 class FedOptimizer:

@@ -1959,53 +1959,6 @@ def make_sharded_round_step(
     return _kernels_replicated(mesh, step)
 
 
-def make_multi_round_step(
-    loss_fn: Callable, cfg: EngineConfig, mesh=None
-) -> Callable:
-    """K federated rounds as ONE compiled program — a lax.scan over the
-    single-round step:
-
-        multi(state, batches, lrs, rngs) -> (state', stacked_metrics)
-
-    with `batches` a pytree whose leaves are [K, W, ...], `lrs` [K], `rngs`
-    [K] PRNG keys. One dispatch and one host sync per K rounds instead of
-    per round (SURVEY.md §7 hard part (d): keep the host off the round
-    boundary without stalling steps). Client
-    sampling stays on the host: the caller pre-samples K cohorts and stacks
-    their batches. Modes with per-client persistent state need the host
-    gather/scatter between rounds and fall back to per-round dispatch
-    (FederatedSession.run_rounds does this automatically).
-
-    With a mesh (or cfg.client_shards > 1) and a mode in the sharded scope,
-    the scanned body is the SPMD sharded round — the K-round block stays
-    data-parallel, each round's cross-device merge is still one table
-    merge, and the queued rounds let the collectives overlap the next
-    round's client compute inside the block."""
-    if cfg.mode.needs_local_state:
-        raise ValueError(
-            "multi-round dispatch requires a mode without per-client "
-            "persistent state (the host gathers/scatters those rows between "
-            "rounds); use per-round run_round for "
-            f"mode={cfg.mode.mode!r} error_type={cfg.mode.error_type!r}"
-        )
-    sharded = supports_sharded_round(cfg.mode) and (
-        cfg.client_shards > 1
-        or (mesh is not None and _mesh_shard_info(mesh)[0] > 1)
-    )
-    step = (make_sharded_round_step(loss_fn, cfg, mesh) if sharded
-            else make_round_step(loss_fn, cfg))
-
-    def multi(state, batches, lrs, rngs):
-        def body(st, xs):
-            b, lr, rng = xs
-            st, _, m = step(st, b, {}, lr, rng)
-            return st, m
-
-        return jax.lax.scan(body, state, (batches, lrs, rngs))
-
-    return multi
-
-
 def _table_norms(tables: jnp.ndarray) -> jnp.ndarray:
     """[W] sketch-space L2 norm of each client's r x c payload table (f32
     accumulation) — the quarantine observable of the wire-payload round: the

@@ -158,13 +158,11 @@ class ProfileWindow:
     def _note(self, msg: str) -> None:
         print(f"obs: profile window — {msg}", file=sys.stderr, flush=True)
 
-    def on_dispatch(self, rnd: int, rounds: int = 1) -> None:
-        """`rnd` is the first round about to dispatch, `rounds` the size of
-        the dispatch block — the capture starts as soon as a block OVERLAPS
-        the window (a fused block cannot be split, so the capture is a
-        round-aligned superset). A window entirely behind the run (resume
-        past it) is declared dead LOUDLY instead of silently arming at the
-        wrong rounds."""
+    def on_dispatch(self, rnd: int) -> None:
+        """`rnd` is the round about to dispatch — the capture starts at the
+        window's first round. A window entirely behind the run (resume past
+        it) is declared dead LOUDLY instead of silently arming at the wrong
+        rounds."""
         if self._active or self._done:
             return
         if rnd > self.end:
@@ -174,8 +172,8 @@ class ProfileWindow:
                 "window); no capture will be taken")
             self._done = True
             return
-        if rnd + rounds <= self.start:
-            return  # block ends before the window opens
+        if rnd < self.start:
+            return
         # an earlier capture's summary is no reading of this one: the gauges
         # say so until this capture's own summary is published, and keep
         # saying so where it fails
@@ -543,7 +541,7 @@ def summarize_launches(planes, host, run_ids=None) -> dict:
     hand before k's last operation ended: by the runtime's ENQUEUE of k+1
     where the capture has it (a program is enqueued once its inputs are on
     the device), else by the return of `runner/dispatch`. Over the queued
-    pairs, in ms a round (a block of n rounds divides by n): gap =
+    pairs, in ms a round: gap =
     (ready[k+1] - ready[k]) - busy[k+1]; between = module end of k to module
     start of k+1; head, tail = module start to first operation, last
     operation to module end (of k+1, of k); inside = the holes between k+1's
@@ -592,9 +590,9 @@ def summarize_launches(planes, host, run_ids=None) -> dict:
     readies, dispatched, launched, enqueued, completed, runtime = (
         [], {}, {}, {}, [], [])
     for name, start, dur, args in host:
-        rnd = int(args.get("round", args.get("round_first", -1)))
+        rnd = int(args.get("round", -1))
         if name == "runner/ready":
-            readies.append((start, rnd, max(int(args.get("rounds", 1)), 1)))
+            readies.append((start, rnd))
         elif name == "runner/dispatch":
             dispatched[rnd] = start + dur
         elif name == "session/launch":
@@ -617,7 +615,7 @@ def summarize_launches(planes, host, run_ids=None) -> dict:
     # that comes late to a read sees two executions done before one mark).
     votes: dict = {}
     done_upto = [upto for _, upto in completed]
-    marks = [ready for ready, _, _ in readies]
+    marks = [ready for ready, _ in readies]
     for i, run in enumerate(run_of):
         if run is None:
             continue
@@ -628,7 +626,7 @@ def summarize_launches(planes, host, run_ids=None) -> dict:
                 votes[i - j] = votes.get(i - j, 0) + 1
     by = "run_id" if votes else "order"
     if not votes:
-        for k, (ready, _, _) in enumerate(readies):
+        for k, (ready, _) in enumerate(readies):
             i = min(range(len(runs)), key=lambda n: abs(runs[n][1] - ready))
             votes[i - k] = votes.get(i - k, 0) + 1
     shift = max(votes, key=votes.get) if votes else -len(runs)
@@ -637,8 +635,8 @@ def summarize_launches(planes, host, run_ids=None) -> dict:
     for i, ((m_start, m_end), seen) in enumerate(zip(runs, inside)):
         if not 0 <= i - shift < len(readies) or seen is None:
             continue
-        ready, rnd, n = readies[i - shift]
-        execs.append({"round": rnd, "rounds": n, "start": m_start,
+        ready, rnd = readies[i - shift]
+        execs.append({"round": rnd, "start": m_start,
                       "end": m_end, "first_op": seen[0], "busy": seen[1],
                       "last_op": seen[2], "ready": ready,
                       "enqueued": enqueued.get(run_of[i])})
@@ -647,7 +645,7 @@ def summarize_launches(planes, host, run_ids=None) -> dict:
     pairs = starved = unknown = timed = holes = 0
     covered: dict = {}
     for a, b in zip(execs, execs[1:]):
-        if b["round"] != a["round"] + a["rounds"]:
+        if b["round"] != a["round"] + 1:
             continue
         holes += 1  # every hole is put down to the host, queued or not
         hole: dict = {}
@@ -667,16 +665,15 @@ def summarize_launches(planes, host, run_ids=None) -> dict:
             starved += 1
             continue
         pairs += 1
-        n = b["rounds"]
         step = b["ready"] - a["ready"]
-        sums["gap"] += (step - b["busy"]) / n
-        sums["between"] += (b["start"] - a["end"]) / n
-        sums["head"] += (b["first_op"] - b["start"]) / n
-        sums["tail"] += (a["end"] - a["last_op"]) / n
-        sums["inside"] += (b["last_op"] - b["first_op"] - b["busy"]) / n
-        sums["drift"] += (step - (b["last_op"] - a["last_op"])) / n
-        sums["ready_lag"] += (b["ready"] - b["end"]) / n
-        sums["busy"] += b["busy"] / n
+        sums["gap"] += step - b["busy"]
+        sums["between"] += b["start"] - a["end"]
+        sums["head"] += b["first_op"] - b["start"]
+        sums["tail"] += a["end"] - a["last_op"]
+        sums["inside"] += b["last_op"] - b["first_op"] - b["busy"]
+        sums["drift"] += step - (b["last_op"] - a["last_op"])
+        sums["ready_lag"] += b["ready"] - b["end"]
+        sums["busy"] += b["busy"]
         if b["round"] in launched:
             sums["call"] += launched[b["round"]]
             timed += 1

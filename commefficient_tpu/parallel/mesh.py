@@ -218,9 +218,3 @@ def shard_client_batch(mesh: Mesh, tree):
     """Place every array in `tree` with its leading [W] axis sharded over the
     client mesh axes (weights/params stay replicated — see `replicated`)."""
     return jax.device_put(tree, client_sharding(mesh))
-
-
-def shard_stacked_client_batch(mesh: Mesh, tree):
-    """Multi-round variant: leaves are [K, W, ...] (K stacked rounds); the
-    round axis stays replicated and the client axis (axis 1) shards."""
-    return jax.device_put(tree, NamedSharding(mesh, P(None, client_axes(mesh))))

@@ -13,7 +13,7 @@ between `cv_train.py` and `gpt2_train.py` into one place so fixes land once.
   thread (safe to overlap: the staging-dir + rename-commit protocol means a
   torn write can never be mistaken for a checkpoint); emergency/preemption
   saves stay synchronous, and the writer is drained before exit 75.
-- `loop.run_loop` — the loop itself: per-block device dispatch with metrics
+- `loop.run_loop` — the loop itself: per-round device dispatch with metrics
   kept as DEVICE arrays until an eval/log/checkpoint boundary (JAX async
   dispatch queues rounds back-to-back; one batched `device_get` per
   boundary instead of one blocking sync per dispatch).

@@ -1,4 +1,4 @@
-"""The shared run loop: block planning, overlap, and the operational wiring
+"""The shared run loop: overlap, and the operational wiring
 (watchdog, preemption, non-finite halt, eval/checkpoint cadence) both CLIs
 previously hand-rolled and copy-pasted.
 
@@ -31,12 +31,11 @@ What stays synchronous, deliberately:
   end). The async writer is DRAINED before the preemption save and before
   exit.
 - **Non-finite halt**: evaluated from committed metrics at drain
-  boundaries — the same block granularity the old loop had with
-  `--rounds_per_dispatch > 1` (the compiled `skip` guard keeps state clean
-  for any rounds dispatched past the poisoned one).
+  boundaries (the compiled `skip` guard keeps state clean for any rounds
+  dispatched past the poisoned one).
 
 `--sync_loop` collapses all of it: inline preparation, one watchdog-wrapped
-prepare->dispatch->sync per round (or per fused block), blocking saves —
+prepare->dispatch->sync per round, blocking saves —
 the old loop, kept as the A/B baseline and escape hatch. Both paths drive
 the identical compiled programs in the identical order with the identical
 host RNG stream, which is why tests/test_runner.py can pin them
@@ -56,7 +55,7 @@ import time
 
 import jax
 
-from ..federated.api import FederatedSession, FedOptimizer, plan_block
+from ..federated.api import FederatedSession, FedOptimizer
 from ..federated.engine import ROUND_PHASES
 from ..obs import registry as obreg
 from ..obs import trace as obtrace
@@ -136,7 +135,6 @@ class RunnerConfig:
     eval_every: int
     checkpoint_every: int = 0
     checkpoint_dir: str = ""
-    rounds_per_dispatch: int = 1
     sync_loop: bool = False
     # async only: drain when this many rounds are dispatched-uncommitted,
     # even between boundaries — bounds how much work a preemption's grace
@@ -168,7 +166,6 @@ class RunnerConfig:
             eval_every=eval_every,
             checkpoint_every=args.checkpoint_every,
             checkpoint_dir=args.checkpoint_dir,
-            rounds_per_dispatch=args.rounds_per_dispatch,
             sync_loop=args.sync_loop,
             max_inflight=getattr(args, "max_inflight", 0),
             prefetch_depth=getattr(args, "prefetch_depth", 0),
@@ -342,7 +339,7 @@ def run_loop(
     # multi-host coordinated preemption: with > 1 process the LOCAL SIGTERM
     # flag must not short-circuit the SPMD schedule (the un-signalled hosts
     # would block in the next round's collectives) — every preemption
-    # decision goes through the cross-host max-reduce at block boundaries,
+    # decision goes through the cross-host max-reduce after every round,
     # where every host's collective call counts line up.
     process_count = _process_count()
 
@@ -416,7 +413,6 @@ def run_loop(
     )
 
     pending: collections.deque = collections.deque()  # in-flight dispatches
-    pending_rounds = 0
     # serving-layer hook: a pipelined ServedSource gates the NEXT round's
     # payload client compute on the previous merge being dispatched (the
     # head-state chaining the bit-parity rests on) — resolved once so the
@@ -478,8 +474,8 @@ def run_loop(
         idle_acc[0] += ms
         idle_acc[1] += 1
         idle_acc[2] = max(idle_acc[2], ms)
-    # per-dispatch (trace timestamp, first round, round count), parallel to
-    # `pending`: the deferred device-phase spans — written at the drain
+    # per-dispatch (trace timestamp, round), parallel to `pending`: the
+    # deferred device-phase spans — written at the drain
     # that commits them, from its ready stamps, never by a mid-round sync
     # (the deferred-metrics discipline)
     dispatch_marks: collections.deque = collections.deque()
@@ -508,30 +504,29 @@ def run_loop(
         in-flight depth; the FIRST interval is discarded — it carries the
         round step's jit compile (tens of seconds), which would seed the EMA
         ~1000x high and pin the depth at the floor for many drains."""
-        nonlocal pending_rounds, last_m, nonfinite_total
+        nonlocal last_m, nonfinite_total
         nonlocal eff_inflight, ema_round_ms, last_drain_t, first_drain
         if not pending:
             return
         if len(pending) == 1:
-            keep = 0  # nothing older to commit: a depth of 1, or one block
+            keep = 0  # nothing older to commit: a depth of 1
         read = list(itertools.islice(pending, len(pending) - keep))
-        committed = sum(fl.num_rounds for fl in read)
+        committed = len(read)
         first = session.round  # oldest uncommitted round index
-        # the drain legitimately waits out every dispatch it reads, so the
-        # watchdog threshold scales by their round count and the recorded
-        # time is normalized back to a per-round figure (true median)
+        # the drain legitimately waits out every round it reads, so the
+        # watchdog threshold scales by their count and the recorded time is
+        # normalized back to a per-round figure (true median)
         t_drain0 = time.perf_counter()
         hosts, stamps = [], []
         with (watchdog.round(first, rounds=committed)
               if watch else contextlib.nullcontext()):
             with tracer.span("runner", "drain", round_first=first,
                              rounds=committed):
-                for fl, (_, d_first, d_n) in zip(read, dispatch_marks):
+                for fl, (_, d_rnd) in zip(read, dispatch_marks):
                     hosts.append(jax.device_get(fl.metrics))
                     stamps.append(time.perf_counter())
                     # the stamp itself, on a running capture's clock
-                    tracer.instant("runner", "ready", round_first=d_first,
-                                   rounds=d_n)
+                    tracer.instant("runner", "ready", round=d_rnd)
         phase_hist["drain"].observe((stamps[-1] - t_drain0) * 1e3)
         # each dispatch recorded only a host timestamp; its device-phase
         # span runs from the later of that and the previous ready stamp (it
@@ -540,18 +535,18 @@ def run_loop(
         # which accumulation program the device time belongs to when
         # A/B-ing the two arms.
         prev = ready_mark[0]
-        for i, (stamp, (ts_us, d_first, d_n)) in enumerate(
+        for i, (stamp, (ts_us, d_rnd)) in enumerate(
                 zip(stamps, dispatch_marks)):
             if prev is not None:
                 (chained_hist if i else first_hist).observe(
-                    (stamp - prev) * 1e3 / d_n)
+                    (stamp - prev) * 1e3)
             if tracer.enabled:
                 if prev is not None:
                     ts_us = max(ts_us, tracer.us_at(prev))
                 tracer.complete(
-                    "device", f"rounds {d_first}..{d_first + d_n - 1}",
-                    ts_us, tracer.us_at(stamp) - ts_us, round_first=d_first,
-                    rounds=d_n, sketch_path=sketch_path)
+                    "device", f"round {d_rnd}", ts_us,
+                    tracer.us_at(stamp) - ts_us, round=d_rnd,
+                    sketch_path=sketch_path)
             prev = stamp
         ready_mark[0] = stamps[-1]
         after_drain[0] = True
@@ -601,7 +596,6 @@ def run_loop(
         for _ in read:
             pending.popleft()
             dispatch_marks.popleft()
-        pending_rounds -= committed
         reg.counter("runner_rounds_total").inc(committed)
         reg.counter("runner_drains_total").inc()
         if keep:
@@ -646,92 +640,44 @@ def run_loop(
     try:
         with PreemptionHandler() as pre:
             while rnd < cfg.total_rounds:
-                lrs = plan_block(opt, rnd, cfg.total_rounds, eval_every,
-                                 cfg.checkpoint_every, cfg.rounds_per_dispatch)
-                if len(lrs) > 1 and session.supports_block_dispatch:
-                    # a fused block cannot split, so the capture window
-                    # arms on OVERLAP (round-aligned superset); the
-                    # per-round fallback below keeps per-round precision
-                    if profile is not None:
-                        profile.on_dispatch(rnd, rounds=len(lrs))
-                    # one dispatch for the block; the watchdog times the
-                    # block (prefetch pull included — a stalled loader is a
-                    # stall the ladder should see). In async mode a dispatch
-                    # returns without a host sync in ~ms, so it must not
-                    # feed the learned round-time median (record=False) —
-                    # the boundary drain records the true per-round time.
-                    with watchdog.round(rnd, record=cfg.sync_loop):
-                        t_p0 = time.perf_counter()
-                        with tracer.span("runner", "prepare", round=rnd,
-                                         rounds=len(lrs)):
-                            preps = [src.next() for _ in lrs]
-                        phase_hist["prepare"].observe(
-                            (time.perf_counter() - t_p0) * 1e3)
-                        note_idle()
-                        t_d0 = time.perf_counter()
-                        t_mark = tracer.now_us()
-                        with tracer.span("runner", "dispatch", round=rnd,
-                                         rounds=len(lrs)):
-                            pending.append(session.dispatch_block(preps, lrs))
-                        # marked only AFTER the dispatch succeeded: a
-                        # raising dispatch must not leave a stale mark the
-                        # next drain would resolve into a phantom span
-                        dispatch_marks.append((t_mark, rnd, len(lrs)))
-                        if on_dispatched is not None:
-                            on_dispatched(rnd + len(lrs) - 1)
-                        phase_hist["dispatch"].observe(
-                            (time.perf_counter() - t_d0) * 1e3)
-                        note_dispatched()
-                        if len(pending) > 2:
-                            pending[-3].release_state()  # ends no drain now
-                        pending_rounds += len(lrs)
-                        if cfg.sync_loop:
-                            drain(watch=False)
-                    rnd += len(lrs)
-                else:
-                    # per-round dispatch (stateful/split/fault-plan
-                    # fallback): keep the watchdog per-round so a hang is
-                    # detected at round, not block, granularity
-                    for j, lr in enumerate(lrs):
-                        if profile is not None:
-                            profile.on_dispatch(rnd + j)
-                        with watchdog.round(rnd + j, record=cfg.sync_loop):
-                            t_p0 = time.perf_counter()
-                            with tracer.span("runner", "prepare",
-                                             round=rnd + j):
-                                prep = src.next()
-                            phase_hist["prepare"].observe(
-                                (time.perf_counter() - t_p0) * 1e3)
-                            note_idle()
-                            t_d0 = time.perf_counter()
-                            t_mark = tracer.now_us()
-                            with tracer.span("runner", "dispatch",
-                                             round=rnd + j):
-                                pending.append(
-                                    session.dispatch_round(prep, lr)
-                                )
-                            dispatch_marks.append((t_mark, rnd + j, 1))
-                            if on_dispatched is not None:
-                                on_dispatched(rnd + j)
-                            phase_hist["dispatch"].observe(
-                                (time.perf_counter() - t_d0) * 1e3)
-                            note_dispatched()
-                            if len(pending) > 2:
-                                pending[-3].release_state()  # as above
-                            pending_rounds += 1
-                            if cfg.sync_loop:
-                                drain(watch=False)
-                        rnd += 1
-                        if pre.triggered and process_count == 1:
-                            break  # stop inside the block: the grace window
-                            # is short. Multi-host: an early break would
-                            # desync this host's dispatch count from its
-                            # peers' (their collectives would hang), so the
-                            # flag waits for the coordinated boundary check.
-                # cross-host agreement on the preemption flag at the block
-                # boundary: every host sees "any host was signalled" and
-                # they all finish THIS round, checkpoint it, and exit 75
-                # together (single process: just the local flag)
+                lr = opt.lr
+                opt.step()
+                if profile is not None:
+                    profile.on_dispatch(rnd)
+                # the watchdog times the round (prefetch pull included — a
+                # stalled loader is a stall the ladder should see). In async
+                # mode a dispatch returns without a host sync in ~ms, so it
+                # must not feed the learned round-time median (record=False)
+                # — the drain records the true per-round time.
+                with watchdog.round(rnd, record=cfg.sync_loop):
+                    t_p0 = time.perf_counter()
+                    with tracer.span("runner", "prepare", round=rnd):
+                        prep = src.next()
+                    phase_hist["prepare"].observe(
+                        (time.perf_counter() - t_p0) * 1e3)
+                    note_idle()
+                    t_d0 = time.perf_counter()
+                    t_mark = tracer.now_us()
+                    with tracer.span("runner", "dispatch", round=rnd):
+                        pending.append(session.dispatch_round(prep, lr))
+                    # marked only AFTER the dispatch succeeded: a raising
+                    # dispatch must not leave a stale mark the next drain
+                    # would resolve into a phantom span
+                    dispatch_marks.append((t_mark, rnd))
+                    if on_dispatched is not None:
+                        on_dispatched(rnd)
+                    phase_hist["dispatch"].observe(
+                        (time.perf_counter() - t_d0) * 1e3)
+                    note_dispatched()
+                    if len(pending) > 2:
+                        pending[-3].release_state()  # ends no drain now
+                    if cfg.sync_loop:
+                        drain(watch=False)
+                rnd += 1
+                # cross-host agreement on the preemption flag at every round:
+                # every host sees "any host was signalled" and they all
+                # finish THIS round, checkpoint it, and exit 75 together
+                # (single process: just the local flag)
                 preempt_now = (pre.triggered if process_count == 1
                                else preemption.coordinated(pre.triggered))
                 # a boundary (something is about to read the committed
@@ -743,7 +689,7 @@ def run_loop(
                         or (cfg.checkpoint_every
                             and rnd % cfg.checkpoint_every == 0)):
                     drain()
-                elif pending_rounds >= eff_inflight:
+                elif len(pending) >= eff_inflight:
                     drain(keep=1)
                 if preempt_now:
                     tracer.instant("resilience", "preempt_boundary",

@@ -368,11 +368,6 @@ def make_parser(task: str = "cv") -> argparse.ArgumentParser:
                         "depth, arrival rate, quarantine/requeue counters) "
                         "on this loopback port (0 = ephemeral, printed at "
                         "startup); -1 = no endpoint")
-    p.add_argument("--rounds_per_dispatch", type=int, default=1,
-                   help="> 1 compiles this many rounds into one program "
-                        "(lax.scan) with a single host sync per block — "
-                        "amortizes the host round-trip; stateless modes only "
-                        "(others silently run per-round)")
     p.add_argument("--sync_loop", action="store_true",
                    help="run the fully synchronous round loop: inline batch "
                         "assembly, a blocking metrics sync per dispatch, and "

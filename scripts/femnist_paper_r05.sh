@@ -21,7 +21,7 @@ run_arm() {  # name, extra flags...
     timeout 4200 python -u cv_train.py \
         --dataset femnist \
         --num_clients 3550 --num_workers 36 --local_batch_size 20 \
-        --num_epochs 24 --eval_every 100 --rounds_per_dispatch 50 \
+        --num_epochs 24 --eval_every 100 \
         --checkpoint_dir "ckpt_fpaper_${name}" --checkpoint_every 200 \
         --resume \
         --pivot_epoch 4 --lr_scale "$LR" --seed 42 --dtype bfloat16 \

@@ -24,7 +24,6 @@ for lr in 0.01 0.03 0.06; do
         --dataset cifar10 --synthetic_separation 0.025 \
         --num_clients 1000 --num_workers 16 --local_batch_size 8 \
         --num_rounds 300 --num_epochs 5 --pivot_epoch 2.5 --eval_every 50 \
-        --rounds_per_dispatch 50 \
         --lr_scale "$lr" --seed 42 --dtype bfloat16 \
         --mode uncompressed \
         --log_jsonl "results/lr_sweep_${lr}.jsonl" 2>&1 \

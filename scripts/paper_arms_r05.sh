@@ -26,7 +26,7 @@ run_arm() {  # name, extra flags...
         --dataset cifar10 --synthetic_separation 0.025 \
         --synthetic_train 50000 \
         --num_clients 10000 --num_workers 100 --local_batch_size 5 \
-        --num_epochs 24 --eval_every 100 --rounds_per_dispatch 50 \
+        --num_epochs 24 --eval_every 100 \
         --client_chunk 25 \
         --checkpoint_dir "ckpt_paper_${name}" --checkpoint_every 200 \
         --resume \

@@ -5,7 +5,7 @@
 # environments). Sharded-path regressions fail here fast, off-TPU.
 #
 # Covers: mesh-vs-single-device bit parity (3 mode configs), hybrid DCN
-# mesh, K-round blocks, checkpoint+resume mid-run on the sharded path, mesh
+# mesh, queued rounds, checkpoint+resume mid-run on the sharded path, mesh
 # spec parsing, runner auto-inflight policy — plus the cohort
 # fault-tolerance slice (test_cohort_faults.py: masked-cohort bit parity on
 # the mesh path, sketch-space quarantine mesh == single-device), the

@@ -35,7 +35,6 @@ run_arm() {  # name, extra flags...
         --dataset cifar10 --synthetic_separation 0.025 \
         --num_clients 1000 --num_workers 16 --local_batch_size 8 \
         --num_rounds "$ROUNDS" --num_epochs "$EPOCHS" --eval_every 50 \
-        --rounds_per_dispatch 50 \
         --checkpoint_dir "ckpt_tradeoff2_${name}" --checkpoint_every 100 \
         --resume \
         --lr_scale "$LR" --seed 42 --dtype bfloat16 \

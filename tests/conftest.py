@@ -47,3 +47,12 @@ def hermetic_subprocess_env() -> dict:
         # (utils/compile_cache.py); the tests' stays opt-in, as above
         env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return env
+
+
+def run_queued(session, lrs) -> list[dict]:
+    """Dispatch len(lrs) rounds back to back, each chained on the one before
+    with no host sync between them, then publish them all in ONE
+    commit_rounds — the run loop's pipelined path, without the loop."""
+    infls = [session.dispatch_round(session.prepare_round(), lr) for lr in lrs]
+    return session.commit_rounds(
+        infls, jax.device_get([fl.metrics for fl in infls]))

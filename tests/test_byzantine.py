@@ -40,6 +40,7 @@ from commefficient_tpu.serve.ingest import (
     PayloadPolicy,
     validate_payload,
 )
+from conftest import run_queued
 
 
 def quad_loss(params, net_state, batch, rng):
@@ -266,14 +267,14 @@ def test_robust_policy_validation(match, build):
         build()
 
 
-def test_robust_session_falls_back_to_per_round_blocks():
-    """run_rounds on a robust session must fall back to per-round dispatch
-    (the table round has no fused multi-round program) and still equal the
-    sequential rounds bitwise."""
+def test_robust_session_queued_rounds_match_run_round():
+    """The robust table round (client program + median merge) with three
+    rounds queued and one batch commit equals sequential run_round calls
+    bitwise: each merge chains on the previous dispatch's state."""
     a = make_session(merge_policy="median")
-    assert not a.supports_block_dispatch
     b = make_session(merge_policy="median")
-    ra = a.run_rounds([0.05, 0.05, 0.05])
+    assert a._table_round
+    ra = run_queued(a, [0.05, 0.05, 0.05])
     rb = [b.run_round(0.05) for _ in range(3)]
     assert ra == rb
     np.testing.assert_array_equal(flat_params(a), flat_params(b))

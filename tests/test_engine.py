@@ -14,6 +14,7 @@ from commefficient_tpu.federated import engine
 from commefficient_tpu.modes import modes
 from commefficient_tpu.modes.config import ModeConfig
 from commefficient_tpu.parallel import mesh as meshlib
+from conftest import run_queued
 
 
 def init_mlp(key, din=10, dh=16, dout=4):
@@ -513,46 +514,10 @@ def test_negative_client_chunk_rejected():
         _make(_ucfg(), client_chunk=-2)
 
 
-def test_multi_round_dispatch_matches_sequential():
-    """engine.make_multi_round_step: K rounds in one lax.scan == K sequential
-    step calls, bit-for-bit (same rng streams via the caller)."""
-    kw = dict(mode="sketch", k=16, num_rows=3, num_cols=1024,
-              hash_family="rotation", momentum_type="virtual", error_type="virtual")
-    W, K = 4, 3
-    data = _data(jax.random.PRNGKey(1), W * 4 * K)
-    all_b = jax.tree.map(lambda a: a.reshape((K, W, 4) + a.shape[1:]), data)
-    lrs = jnp.asarray([0.1, 0.2, 0.05], jnp.float32)
-    rngs = jax.random.split(jax.random.PRNGKey(7), K)
-
-    cfg, state_s, step = _make(dict(kw), wd=5e-4)
-    _, state_m, _ = _make(dict(kw), wd=5e-4)
-    seq_metrics = []
-    for i in range(K):
-        b = jax.tree.map(lambda a: a[i], all_b)
-        state_s, _, m = step(state_s, b, {}, lrs[i], rngs[i])
-        seq_metrics.append(m)
-    multi = jax.jit(engine.make_multi_round_step(mlp_loss, cfg))
-    state_m, ms = multi(state_m, all_b, lrs, rngs)
-    for a, b in zip(jax.tree.leaves(state_s["params"]), jax.tree.leaves(state_m["params"])):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for i, m in enumerate(seq_metrics):
-        for k2, v in m.items():
-            np.testing.assert_allclose(float(v), float(ms[k2][i]), rtol=1e-6)
-
-
-def test_multi_round_rejects_local_state_modes():
-    params = init_mlp(jax.random.PRNGKey(0))
-    d = ravel_pytree(params)[0].size
-    cfg = engine.EngineConfig(mode=ModeConfig(
-        mode="local_topk", d=d, k=8, momentum_type="none", error_type="local",
-        num_clients=4))
-    with pytest.raises(ValueError, match="run_round"):
-        engine.make_multi_round_step(mlp_loss, cfg)
-
-
-def test_session_run_rounds_matches_run_round():
-    """FederatedSession.run_rounds: identical sampling/rng/metrics/comm to
-    sequential run_round calls, on the sharded mesh, one dispatch."""
+def test_session_queued_rounds_match_run_round():
+    """Four rounds dispatched back to back on the sharded mesh and published
+    by one commit_rounds: identical sampling/rng/metrics/comm to sequential
+    run_round calls."""
     from commefficient_tpu.data.fed_dataset import FedDataset, shard_iid
     from commefficient_tpu.federated.api import FederatedSession
 
@@ -577,7 +542,7 @@ def test_session_run_rounds_matches_run_round():
 
     a, b = make(), make()
     seq = [a.run_round(lr) for lr in (0.1, 0.2, 0.05, 0.1)]
-    blk = b.run_rounds([0.1, 0.2, 0.05, 0.1])
+    blk = run_queued(b, [0.1, 0.2, 0.05, 0.1])
     assert len(blk) == 4
     for ma, mb in zip(seq, blk):
         assert set(ma) == set(mb)
@@ -591,27 +556,9 @@ def test_session_run_rounds_matches_run_round():
     )
 
 
-def test_plan_block_boundaries():
-    """plan_block truncates at run end and eval/checkpoint boundaries and
-    advances the schedule exactly once per planned round."""
-    from commefficient_tpu.federated.api import FedOptimizer, plan_block
-
-    opt = FedOptimizer(lambda e: 0.1, rounds_per_epoch=4)
-    # eval boundary at 8: from rnd=6 with k=8 the block is 2
-    assert len(plan_block(opt, 6, 100, 8, 0, 8)) == 2
-    assert opt.round == 2
-    # checkpoint boundary at 3 binds tighter than eval at 8 from rnd=1
-    assert len(plan_block(opt, 1, 100, 8, 3, 8)) == 2
-    # run end binds from rnd=98
-    assert len(plan_block(opt, 98, 100, 8, 0, 8)) == 2
-    # k=1 is always a single round
-    assert len(plan_block(opt, 0, 100, 8, 0, 1)) == 1
-
-
-def test_session_run_rounds_hybrid_mesh():
-    """Block dispatch on the (slices, clients) DCN x ICI mesh: the stacked
-    [K, W, ...] batch shards its client axis over both axes and the rounds
-    match the plain-mesh session."""
+def test_session_hybrid_mesh_matches_flat_mesh():
+    """Rounds on the (slices, clients) DCN x ICI mesh: the client batch
+    shards over both axes and the rounds match the flat-mesh session."""
     from commefficient_tpu.data.fed_dataset import FedDataset, shard_iid
     from commefficient_tpu.federated.api import FederatedSession
 
@@ -635,8 +582,8 @@ def test_session_run_rounds_hybrid_mesh():
 
     a = make(meshlib.make_mesh(8))
     b = make(meshlib.make_mesh(8, num_slices=2))
-    ma = a.run_rounds([0.1, 0.2])
-    mb = b.run_rounds([0.1, 0.2])
+    ma = [a.run_round(lr) for lr in (0.1, 0.2)]
+    mb = [b.run_round(lr) for lr in (0.1, 0.2)]
     for ra, rb in zip(ma, mb):
         np.testing.assert_allclose(ra["loss_sum"], rb["loss_sum"], rtol=1e-5)
     np.testing.assert_allclose(
@@ -645,10 +592,10 @@ def test_session_run_rounds_hybrid_mesh():
     )
 
 
-def test_run_rounds_local_topk_virtual_downlink_accounting():
-    """Block dispatch with local_topk (error_type=virtual — stateless, so
-    eligible): the per-round measured down_support must fold into comm
-    accounting identically to sequential rounds."""
+def test_queued_local_topk_virtual_downlink_accounting():
+    """Queued rounds with local_topk (error_type=virtual): the per-round
+    measured down_support must fold into comm accounting at the batch
+    commit identically to sequential rounds."""
     from commefficient_tpu.data.fed_dataset import FedDataset, shard_iid
     from commefficient_tpu.federated.api import FederatedSession
 
@@ -671,11 +618,130 @@ def test_run_rounds_local_topk_virtual_downlink_accounting():
 
     a, b = make(), make()
     seq = [a.run_round(0.1) for _ in range(3)]
-    blk = b.run_rounds([0.1, 0.1, 0.1])
+    blk = run_queued(b, [0.1, 0.1, 0.1])
     for ma, mb in zip(seq, blk):
         assert "down_support" not in mb  # folded into the comm figures
         np.testing.assert_allclose(ma["comm_down_mb"], mb["comm_down_mb"], rtol=1e-6)
         np.testing.assert_allclose(ma["comm_total_mb"], mb["comm_total_mb"], rtol=1e-6)
+
+
+def _mlp_session(mode_kw, **kw):
+    """The 16-client MLP session the queued-round tests share."""
+    from commefficient_tpu.data.fed_dataset import FedDataset, shard_iid
+    from commefficient_tpu.federated.api import FederatedSession
+
+    rngd = np.random.RandomState(0)
+    n = 64
+    x = rngd.normal(size=(n, 10)).astype(np.float32)
+    y = rngd.randint(0, 4, size=n).astype(np.int32)
+    params = init_mlp(jax.random.PRNGKey(0))
+    d = ravel_pytree(params)[0].size
+    return FederatedSession(
+        train_loss_fn=mlp_loss, eval_loss_fn=mlp_loss,
+        params=jax.tree.map(jnp.copy, params), net_state={},
+        mode_cfg=ModeConfig(d=d, **mode_kw),
+        train_set=FedDataset(x, y, shard_iid(n, 16, np.random.RandomState(1))),
+        num_workers=8, local_batch_size=2, seed=7, **kw,
+    )
+
+
+_SKETCH_MLP = dict(mode="sketch", k=16, num_rows=3, num_cols=1024,
+                   hash_family="rotation", momentum_type="virtual",
+                   error_type="virtual")
+
+
+def _recording_commits(session) -> list:
+    """Every row the session's commits publish, in commit order."""
+    rows = []
+    commit = session.commit_rounds
+
+    def commit_rounds(infls, hosts):
+        got = commit(infls, hosts)
+        rows.extend(got)
+        return got
+
+    session.commit_rounds = commit_rounds
+    return rows
+
+
+def test_commit_rounds_of_three_queued_dispatches_publishes_the_third():
+    """Three dispatches in flight leave the committed view alone; one
+    commit_rounds over all three advances session.round by 3 and publishes
+    the THIRD dispatch's state and RNG snapshot, and the session is back to
+    nothing in flight."""
+    s = _mlp_session(_SKETCH_MLP)
+    state0, snap0 = s.state, s.rng_snapshot
+    infls = [s.dispatch_round(s.prepare_round(), 0.1) for _ in range(3)]
+    assert s.round == 0 and s._inflight == 3
+    assert s.state is state0 and s.rng_snapshot is snap0
+    rows = s.commit_rounds(infls, jax.device_get([f.metrics for f in infls]))
+    assert len(rows) == 3 and s.round == 3 and s._inflight == 0
+    assert s.state is infls[2].new_state
+    assert s.rng_snapshot is infls[2].snapshot
+
+
+def test_prepare_round_counts_the_rounds_in_flight():
+    """prepare_round() with no index prepares the round after every one
+    already dispatched: committed rounds plus those in flight."""
+    s = _mlp_session(_SKETCH_MLP)
+    s.run_round(0.1)
+    infls = [s.dispatch_round(s.prepare_round(), 0.1) for _ in range(2)]
+    assert s.prepare_round().rnd == 3
+    s.commit_rounds(infls, jax.device_get([f.metrics for f in infls]))
+    assert s.round == 3
+
+
+@pytest.mark.parametrize("sync_loop", [True, False], ids=["sync", "async"])
+def test_run_loop_lr_follows_the_schedule(sync_loop):
+    """Every committed round r carries lr = schedule(r / rounds_per_epoch),
+    over 7 rounds whose eval (every 3) and checkpoint (every 2) boundaries
+    fall at different rounds — the schedule advances once a round whatever
+    the drains do."""
+    from commefficient_tpu.federated.api import FedOptimizer
+    from commefficient_tpu.runner import RunnerConfig, run_loop
+
+    def schedule(epoch):
+        return 0.1 * (1.0 + epoch)
+
+    s = _mlp_session(_SKETCH_MLP)
+    rows = _recording_commits(s)
+    run_loop(s, FedOptimizer(schedule, rounds_per_epoch=3), RunnerConfig(
+        total_rounds=7, eval_every=3, checkpoint_every=2,
+        sync_loop=sync_loop, max_inflight=3, prefetch_depth=2),
+        save_ckpt=lambda: None)
+    assert s.round == 7
+    assert [m["lr"] for m in rows] == [
+        float(schedule(r / 3)) for r in range(7)]
+
+
+def test_local_state_mode_queued_loop_matches_sync_loop():
+    """A mode with per-client persistent state (local_topk with local error
+    feedback: the host gathers and scatters its rows between rounds) through
+    the async loop at depth 3 equals --sync_loop: params, client state and
+    every committed row."""
+    from commefficient_tpu.federated.api import FedOptimizer
+    from commefficient_tpu.runner import RunnerConfig, run_loop
+
+    kw = dict(mode="local_topk", k=16, momentum_type="none",
+              error_type="local", num_clients=16)
+    out = []
+    for sync_loop in (True, False):
+        s = _mlp_session(kw)
+        assert s.client_state is not None
+        rows = _recording_commits(s)
+        run_loop(s, FedOptimizer(lambda _: 0.1, 1), RunnerConfig(
+            total_rounds=6, eval_every=1 << 30, sync_loop=sync_loop,
+            max_inflight=3, prefetch_depth=2))
+        out.append((s, rows))
+    (a, rows_a), (b, rows_b) = out
+    assert a.round == b.round == 6 and len(rows_a) == 6
+    assert rows_a == rows_b
+    for x, y in zip(jax.tree.leaves(jax.device_get(a.state["params"])),
+                    jax.tree.leaves(jax.device_get(b.state["params"]))):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    for x, y in zip(jax.tree.leaves(jax.device_get(a.client_state)),
+                    jax.tree.leaves(jax.device_get(b.client_state))):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_localsgd_single_iter_matches_uncompressed():

@@ -38,6 +38,7 @@ from commefficient_tpu.modes import modes
 from commefficient_tpu.modes.config import ModeConfig
 from commefficient_tpu.parallel import mesh as meshlib
 from commefficient_tpu.sketch import csvec, layerwise
+from conftest import run_queued
 
 
 # --------------------------------------------------------------- unit layer
@@ -368,13 +369,13 @@ def _session(sketch_path="ravel", mesh=None, client_shards=0, **kw):
 
 
 def test_layerwise_session_bit_identical_to_ravel_session():
-    """Session-level acceptance: run_round + the run_rounds fused K-round
-    block on a layerwise session == the ravel session, bit for bit —
+    """Session-level acceptance: run_round + two queued rounds under one
+    batch commit on a layerwise session == the ravel session, bit for bit —
     params and EVERY logged metric row (comm accounting included)."""
     a = _session("ravel")
     b = _session("layerwise")
-    seq_a = [a.run_round(0.1), a.run_round(0.2)] + a.run_rounds([0.05, 0.1])
-    seq_b = [b.run_round(0.1), b.run_round(0.2)] + b.run_rounds([0.05, 0.1])
+    seq_a = [a.run_round(0.1), a.run_round(0.2)] + run_queued(a, [0.05, 0.1])
+    seq_b = [b.run_round(0.1), b.run_round(0.2)] + run_queued(b, [0.05, 0.1])
     for ma, mb in zip(seq_a, seq_b):
         assert ma == mb
     np.testing.assert_array_equal(

@@ -517,23 +517,22 @@ def test_profile_window_start_stop_at_round_boundaries(tmp_path, monkeypatch):
     assert len(calls) == 2
 
 
-def test_profile_window_block_overlap_and_resume_past(tmp_path, monkeypatch,
-                                                      capsys):
-    """A fused dispatch block OVERLAPPING the window starts the capture (a
-    block cannot be split, so the capture is a round-aligned superset);
-    a run that begins PAST the window declares it dead loudly instead of
-    silently arming at the wrong rounds."""
+def test_profile_window_arms_at_its_first_round_and_resume_past(
+        tmp_path, monkeypatch, capsys):
+    """The capture starts at the dispatch of the window's first round and
+    not a round before; a run that begins PAST the window declares it dead
+    loudly instead of silently arming at the wrong rounds."""
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
                         lambda d, **kw: calls.append("start"))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append("stop"))
     pw = ProfileWindow.parse("5:6", str(tmp_path))
-    pw.on_dispatch(0, rounds=4)  # block [0..3]: ends before the window
+    pw.on_dispatch(4)  # the round before the window
     assert calls == []
-    pw.on_dispatch(4, rounds=4)  # block [4..7] contains round 5 -> start
+    pw.on_dispatch(5)
     assert calls == ["start"]
-    pw.on_committed(8)
+    pw.on_committed(7)
     assert calls == ["start", "stop"]
 
     pw2 = ProfileWindow.parse("5:6", str(tmp_path))
@@ -650,7 +649,7 @@ def test_device_track_spans_neither_overlap_nor_end_together(tmp_path):
              _loop_cfg(6, max_inflight=2))
     spans = sorted((e for e in obtrace.get().events()
                     if e["cat"] == "device"), key=lambda e: e["ts"])
-    assert [e["args"]["round_first"] for e in spans] == list(range(6))
+    assert [e["args"]["round"] for e in spans] == list(range(6))
     assert all(e["args"]["sketch_path"] == "ravel" for e in spans)
     ends = [e["ts"] + e["dur"] for e in spans]
     assert len(set(ends)) == len(ends)
@@ -702,18 +701,18 @@ def test_loop_spans_are_mirrored_inside_a_profile_window(tmp_path,
     # depth 2 reads one dispatch a drain and keeps the newest queued: the
     # capture opens at round 2's dispatch (round 1 still in flight) and
     # closes at the drain that commits round 3, after round 4's dispatch
+    # one ready mark a dispatch read, inside the drain that read it
     assert [(n, kw["round"]) for n, kw in loop if "round" in kw] == [
         ("runner/prepare", 2), ("runner/dispatch", 2),
-        ("runner/commit_round", 1),
+        ("runner/ready", 1), ("runner/commit_round", 1),
         ("runner/prepare", 3), ("runner/dispatch", 3),
-        ("runner/commit_round", 2),
+        ("runner/ready", 2), ("runner/commit_round", 2),
         ("runner/prepare", 4), ("runner/dispatch", 4),
-        ("runner/commit_round", 3)]
-    # one ready mark a dispatch read, inside the drain that read it
+        ("runner/ready", 3), ("runner/commit_round", 3)]
     assert [(n, kw["round_first"], kw["rounds"]) for n, kw in loop
             if "round_first" in kw] == [
         (f"runner/{what}", rnd, 1) for rnd in (1, 2, 3)
-        for what in ("drain", "ready", "commit")]
+        for what in ("drain", "commit")]
     i = log.index(("enter", "runner/ready"))
     assert log[i - 1:i + 3] == [
         ("enter", "runner/drain"), ("enter", "runner/ready"),
@@ -731,31 +730,32 @@ def test_loop_spans_are_mirrored_inside_a_profile_window(tmp_path,
     made.clear()  # the window closed: the mirror is off again
     with obtrace.span("runner", "prepare", round=9):
         pass
-    obtrace.instant("runner", "ready", round_first=9, rounds=1)
+    obtrace.instant("runner", "ready", round=9)
     assert made == []
 
 
-def test_block_dispatch_launch_span_names_its_rounds(tmp_path, monkeypatch):
-    """A fused block's jit call is one `session/launch` with the block's
-    first round and its size, and the drain reads it back as one ready
-    mark of as many rounds."""
+def test_launch_and_ready_marks_name_one_round_each(tmp_path, monkeypatch):
+    """Every jit call of a round is one `session/launch` naming that round
+    alone, and the drain reads each back as one `runner/ready` mark of one
+    round: both series are consecutive rounds, and every launched round
+    inside the capture is read back in it (depth 4: the capture opens at
+    round 2 and closes at the drain that commits round 5)."""
     made = _RecordingAnnotation.made = []
     _RecordingAnnotation.log = []
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _RecordingAnnotation)
     monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     run_loop(_tiny_session(), FedOptimizer(lambda _: LR, 1), _loop_cfg(
-        8, max_inflight=4, rounds_per_dispatch=2, profile_rounds="2:5",
-        profile_dir=str(tmp_path)))
+        8, max_inflight=4, profile_rounds="2:5", profile_dir=str(tmp_path)))
     launches = [kw for n, kw in made if n == "session/launch"]
-    assert launches and all(kw["rounds"] == 2 for kw in launches)
-    assert [kw["round_first"] for kw in launches] == list(
-        range(launches[0]["round_first"], launches[0]["round_first"]
-              + 2 * len(launches), 2))
     readies = [kw for n, kw in made if n == "runner/ready"]
-    assert readies and all(kw["rounds"] == 2 for kw in readies)
-    assert {kw["round_first"] for kw in readies} <= {
-        kw["round_first"] for kw in launches} | {0}
+    assert launches and all(set(kw) == {"round"} for kw in launches)
+    assert readies and all(set(kw) == {"round"} for kw in readies)
+    for marks in (launches, readies):
+        got = [kw["round"] for kw in marks]
+        assert got == list(range(got[0], got[0] + len(got)))
+    assert launches[0]["round"] == 2
+    assert {2, 3, 4, 5} <= {kw["round"] for kw in readies}
 
 
 # ------------------------------------------- named phases, capture summary
@@ -1013,8 +1013,7 @@ def _launch_capture(rounds, step, device_step, *, head=0.1, busy=30.0,
                 ("%fusion.2", (first + busy / 2) * MS, busy / 2 * MS, ""),
                 ("%inner", (first + 1.0) * MS, 2.0 * MS, "")]  # nested: once
         ready = i * step + moved + head + busy + tail + lag
-        host.append(("runner/ready", ready * MS, 0.001 * MS,
-                     {"round_first": rnd, "rounds": 1}))
+        host.append(("runner/ready", ready * MS, 0.001 * MS, {"round": rnd}))
         if first_run_id is not None:
             run_ids[start * MS] = first_run_id + 4 * i
             # the three small programs behind it are done by then too

@@ -92,12 +92,11 @@ def _assert_params_equal(sa, sb):
 @pytest.mark.chaos
 def test_async_loop_bit_identical_to_sync(tiny_cv, tmp_path):
     """Multi-round run through the REAL CLI, eval cadence mid-run, mixed
-    block sizes (--rounds_per_dispatch 2 against --eval_every 3 exercises
-    BOTH the fused-block and per-round dispatch paths): the async loop's
-    final params and every logged metric row must be bit-identical to
-    --sync_loop's."""
+    drain boundaries (--checkpoint_every 2 against --eval_every 3: the
+    async loop drains after rounds 2, 3, 4 and 6): its final params and
+    every logged metric row must be bit-identical to --sync_loop's."""
     base = _argv(("--num_rounds", "6", "--eval_every", "3",
-                  "--rounds_per_dispatch", "2"))
+                  "--checkpoint_every", "2"))
     la, lb = str(tmp_path / "sync.jsonl"), str(tmp_path / "async.jsonl")
     sa = cv_train.main(base + ["--sync_loop", "--log_jsonl", la])
     sb = cv_train.main(base + ["--log_jsonl", lb])

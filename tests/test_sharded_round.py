@@ -27,6 +27,7 @@ from commefficient_tpu.federated.api import FederatedSession
 from commefficient_tpu.modes import modes
 from commefficient_tpu.modes.config import ModeConfig
 from commefficient_tpu.parallel import mesh as meshlib
+from conftest import run_queued
 
 
 def init_mlp(key, din=10, dh=16, dout=4):
@@ -170,34 +171,6 @@ def test_sharded_allclose_to_plain_round(name, mode_kw, eng_kw):
                                rtol=1e-6)
 
 
-def test_sharded_multi_round_block_matches_sequential():
-    """The K-round fused block scans the SPMD body: bitwise equal to K
-    sequential sharded dispatches."""
-    mesh = meshlib.make_mesh(8)
-    params, cfg = _cfg(dict(SKETCH_KW), {})
-    K, W = 3, 8
-    x = jax.random.normal(jax.random.PRNGKey(4), (K, W, 4, 10))
-    w_true = jax.random.normal(jax.random.PRNGKey(5), (10, 4))
-    batches = {"x": x, "y": (x @ w_true).argmax(-1),
-               "mask": jnp.ones((K, W, 4))}
-    lrs = jnp.asarray([0.1, 0.2, 0.05], jnp.float32)
-    rngs = jax.random.split(jax.random.PRNGKey(6), K)
-
-    step = jax.jit(engine.make_sharded_round_step(mlp_loss, cfg, mesh))
-    st = engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {})
-    for i in range(K):
-        b = meshlib.shard_client_batch(
-            mesh, jax.tree.map(lambda a: a[i], batches))
-        st, _, _ = step(st, b, {}, lrs[i], rngs[i])
-
-    multi = jax.jit(engine.make_multi_round_step(mlp_loss, cfg, mesh))
-    stm, ms = multi(
-        engine.init_server_state(cfg, jax.tree.map(jnp.copy, params), {}),
-        meshlib.shard_stacked_client_batch(mesh, batches), lrs, rngs)
-    np.testing.assert_array_equal(_flat(st), _flat(stm))
-    assert all(np.asarray(v).shape[0] == K for v in ms.values())
-
-
 def test_sharded_scope_rejected_loudly():
     params = init_mlp(jax.random.PRNGKey(0))
     d = ravel_pytree(params)[0].size
@@ -248,15 +221,15 @@ def _session(mesh=None, client_shards=0, **kw):
 
 
 def test_session_mesh_bit_identical_to_reference_session():
-    """Session-level acceptance: run_round + the run_rounds fused block on
-    the 8-way mesh session == the client_shards=8 single-device reference
-    session, bit for bit — params, mode state, and every logged metric
-    (comm accounting included)."""
+    """Session-level acceptance: run_round + two queued rounds under one
+    batch commit on the 8-way mesh session == the client_shards=8
+    single-device reference session, bit for bit — params, mode state, and
+    every logged metric (comm accounting included)."""
     a = _session(mesh=meshlib.make_mesh(8))
     b = _session(client_shards=8)
     assert a.cfg.client_shards == b.cfg.client_shards == 8
-    seq_a = [a.run_round(0.1), a.run_round(0.2)] + a.run_rounds([0.05, 0.1])
-    seq_b = [b.run_round(0.1), b.run_round(0.2)] + b.run_rounds([0.05, 0.1])
+    seq_a = [a.run_round(0.1), a.run_round(0.2)] + run_queued(a, [0.05, 0.1])
+    seq_b = [b.run_round(0.1), b.run_round(0.2)] + run_queued(b, [0.05, 0.1])
     for ma, mb in zip(seq_a, seq_b):
         assert ma == mb
     np.testing.assert_array_equal(
@@ -264,6 +237,21 @@ def test_session_mesh_bit_identical_to_reference_session():
         np.asarray(ravel_pytree(b.state["params"])[0]),
     )
     assert a.comm_mb_total == b.comm_mb_total
+
+
+def test_sharded_session_three_in_flight_matches_per_round_sync():
+    """The SPMD round on the 8-way mesh with three rounds in flight (each
+    chained on the previous dispatch's state, no host sync between them)
+    equals the same session synced after every round: every row and the
+    params bitwise."""
+    a = _session(mesh=meshlib.make_mesh(8))
+    b = _session(mesh=meshlib.make_mesh(8))
+    lrs = [0.1, 0.2, 0.05]
+    assert run_queued(a, lrs) == [b.run_round(lr) for lr in lrs]
+    assert a.round == b.round == 3
+    np.testing.assert_array_equal(
+        np.asarray(ravel_pytree(a.state["params"])[0]),
+        np.asarray(ravel_pytree(b.state["params"])[0]))
 
 
 def test_session_hybrid_mesh_bit_identical_to_plain_mesh():

@@ -47,6 +47,7 @@ from commefficient_tpu.obs import registry as obreg
 from commefficient_tpu.resilience import EXIT_RESUMABLE
 from commefficient_tpu.runner import RunnerConfig, run_loop
 from commefficient_tpu.sketch import csvec
+from conftest import run_queued
 
 LR = 0.05
 
@@ -155,17 +156,19 @@ def test_health_cadence_and_registry_gauges():
     assert len(block["leaf_norms"]) == 2  # w + b leaves
 
 
-def test_health_in_fused_block_dispatch():
-    """A K-round fused block (run_rounds -> lax.scan) carries the health
-    leaf through the scan: one block per round, correct cadence."""
+def test_health_in_batch_commit_of_queued_rounds():
+    """Four rounds queued and published by one commit_rounds: each round
+    hands its own health block to the monitor at the commit, at the right
+    cadence (rounds 0 and 2 of health_every=2), and the params equal an
+    unmonitored session's."""
     s = _session(health_every=2)
     mon = _CapturingMonitor(mode_cfg=s.cfg.mode, num_workers=s.num_workers,
                             health_every=2)
     s.health_monitor = mon
-    s.run_rounds([LR] * 4)
+    run_queued(s, [LR] * 4)
     assert [r for r, _ in mon.calls] == [0, 2]
     ref = _session()
-    ref.run_rounds([LR] * 4)
+    run_queued(ref, [LR] * 4)
     _assert_params_equal(s, ref)
 
 
